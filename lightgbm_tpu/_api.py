@@ -11,37 +11,26 @@ import os as _os
 def _enable_persistent_compile_cache() -> None:
     """Persistent XLA compilation cache (VERDICT r3 weak #4: bench/CLI paid a
     ~116 s cold compile every run while only tests wired the cache). Applied at
-    import so every entry point (CLI, bench.py, python API) benefits. Opt out
-    with LGBM_TPU_NO_COMPILE_CACHE=1; override dir with LGBM_TPU_JAX_CACHE."""
+    import so every entry point (CLI, bench.py, python API) benefits.
+
+    Placement is the standard ``JAX_COMPILATION_CACHE_DIR``: when it is set
+    jax reads it by itself and no directory is set in code; otherwise the
+    cache lives in ``<checkout>/.jax_cache`` (a fixed path — the path is part
+    of the cache key, so a directory that moves never hits). Opt out with
+    LGBM_TPU_NO_COMPILE_CACHE=1."""
     if _os.environ.get("LGBM_TPU_NO_COMPILE_CACHE"):
         return
-    cache = _os.environ.get("LGBM_TPU_JAX_CACHE")
-    if not cache:
-        # prefer a repo-local dir (survives with the checkout across rounds),
-        # fall back to the user cache dir
+    import jax
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         repo_root = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
-        cand = _os.path.join(repo_root, ".jax_cache")
-        try:
-            _os.makedirs(cand, exist_ok=True)
-            cache = cand
-        except OSError:
-            try:
-                cache = _os.path.join(_os.path.expanduser("~"), ".cache",
-                                      "lightgbm_tpu_jax")
-                _os.makedirs(cache, exist_ok=True)
-            except OSError:
-                return   # nowhere writable: run without the cache
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache)
-        # default 1.0 s skips tiny programs; the test suite lowers this via
-        # the env knob so its many sub-second predict/eval programs persist
-        # across runs instead of recompiling every session
-        min_s = float(_os.environ.get("LGBM_TPU_JAX_CACHE_MIN_COMPILE_S", "1.0"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:  # pragma: no cover - cache is an optimization only
-        pass
+        jax.config.update("jax_compilation_cache_dir",
+                          _os.path.join(repo_root, ".jax_cache"))
+    # default 1.0 s skips tiny programs; the test suite lowers this via
+    # the env knob so its many sub-second predict/eval programs persist
+    # across runs instead of recompiling every session
+    min_s = float(_os.environ.get("LGBM_TPU_JAX_CACHE_MIN_COMPILE_S", "1.0"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
 
 
 _enable_persistent_compile_cache()

@@ -66,26 +66,26 @@ def measure() -> dict:
     with jtu.count_jit_and_pmap_lowerings() as n:
         train_set = lgb.Dataset(X, label=y, params=params)
         train_set.construct()
-    counts["dataset_construct"] = int(n[0])
+    counts["dataset_construct"] = int(n())
 
     with jtu.count_jit_and_pmap_lowerings() as n:
         booster = lgb.train(params, train_set, num_boost_round=3)
-    counts["train_3_iters"] = int(n[0])
+    counts["train_3_iters"] = int(n())
 
     with jtu.count_jit_and_pmap_lowerings() as n:
         booster.predict(X)
-    counts["predict_cold"] = int(n[0])
+    counts["predict_cold"] = int(n())
 
     with jtu.count_jit_and_pmap_lowerings() as n:
         for _ in range(3):
             booster.predict(X)
-    counts["predict_warm_repeat"] = int(n[0])
+    counts["predict_warm_repeat"] = int(n())
 
     # leaf-wise grower: a different step program than the depthwise default
     with jtu.count_jit_and_pmap_lowerings() as n:
         lgb.train({**params, "grow_policy": "lossguide"}, train_set,
                   num_boost_round=3)
-    counts["train_3_iters_lossguide"] = int(n[0])
+    counts["train_3_iters_lossguide"] = int(n())
 
     # warmed non-gbdt boosters: 3 warmup iterations, then two extra
     # update() calls must lower NOTHING (budget 0). skip_drop=0 makes every
@@ -99,7 +99,7 @@ def measure() -> dict:
         with jtu.count_jit_and_pmap_lowerings() as n:
             bst.update()
             bst.update()
-        counts[f"train_warm_extra2_{boosting}"] = int(n[0])
+        counts[f"train_warm_extra2_{boosting}"] = int(n())
 
     # serving path: predicts at row counts whose buckets warmup()
     # pre-compiled must reuse the warmed executables (budget 0)
@@ -109,7 +109,7 @@ def measure() -> dict:
     with jtu.count_jit_and_pmap_lowerings() as n:
         engine.predict(X[:1])
         engine.predict(X[:100])
-    counts["predict_engine_warm"] = int(n[0])
+    counts["predict_engine_warm"] = int(n())
 
     # packed/2-channel q8 surface (ISSUE 20): forced-pallas quantized
     # training on a regression (const-hessian) workload. At 512 rows the
@@ -125,14 +125,14 @@ def measure() -> dict:
     with jtu.count_jit_and_pmap_lowerings() as n:
         bstq = lgb.train({**q8, "hist_packed": "true"}, dsq,
                          num_boost_round=3)
-    counts["train_3_iters_q8_packed"] = int(n[0])
+    counts["train_3_iters_q8_packed"] = int(n())
     with jtu.count_jit_and_pmap_lowerings() as n:
         bstq.update()
         bstq.update()
-    counts["train_warm_extra2_q8_packed"] = int(n[0])
+    counts["train_warm_extra2_q8_packed"] = int(n())
     with jtu.count_jit_and_pmap_lowerings() as n:
         lgb.train({**q8, "hist_packed": "false"}, dsq, num_boost_round=3)
-    counts["train_3_iters_q8_2ch"] = int(n[0])
+    counts["train_3_iters_q8_2ch"] = int(n())
 
     return counts
 
@@ -171,11 +171,11 @@ def measure_multihost() -> dict:
     ds2d.construct()
     with jtu.count_jit_and_pmap_lowerings() as n:
         bst2d = lgb.train(params2d, ds2d, num_boost_round=3)
-    counts["train_3_iters_pod2d"] = int(n[0])
+    counts["train_3_iters_pod2d"] = int(n())
     with jtu.count_jit_and_pmap_lowerings() as n:
         bst2d.update()
         bst2d.update()
-    counts["train_warm_extra2_pod2d"] = int(n[0])
+    counts["train_warm_extra2_pod2d"] = int(n())
 
     # voting-parallel: local top-k election + elected-column psum
     paramsv = {**base, "num_shards": 4, "voting_parallel": 1, "top_k": 3}
@@ -183,11 +183,11 @@ def measure_multihost() -> dict:
     dsv.construct()
     with jtu.count_jit_and_pmap_lowerings() as n:
         bstv = lgb.train(paramsv, dsv, num_boost_round=3)
-    counts["train_3_iters_voting"] = int(n[0])
+    counts["train_3_iters_voting"] = int(n())
     with jtu.count_jit_and_pmap_lowerings() as n:
         bstv.update()
         bstv.update()
-    counts["train_warm_extra2_voting"] = int(n[0])
+    counts["train_warm_extra2_voting"] = int(n())
 
     return counts
 

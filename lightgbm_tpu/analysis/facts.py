@@ -35,7 +35,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 _LOCK_FACTORIES = {"Lock", "RLock", "allocate_lock"}
 _COLLECTIVES = {"psum", "pmean", "pmax", "pmin", "all_gather", "all_to_all",
                 "ppermute", "psum_scatter", "axis_index"}
-_SHARD_MAP_NAMES = {"shard_map", "shard_map_compat"}
+_SHARD_MAP_NAMES = {"shard_map"}
 
 # Cross-process (DCN / host-level) collectives plus the product wrappers
 # that issue them. Every rank MUST enter each of these or the pod hangs:

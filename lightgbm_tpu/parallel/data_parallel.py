@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.grow import GrowParams, TreeArrays, grow_tree
-from .mesh import DATA_AXIS, shard_map_compat
+from .mesh import DATA_AXIS
 
 
 def grow_tree_dp(bins, g, h, c, num_bins, na_bin, feature_mask,
@@ -47,7 +47,7 @@ def grow_tree_dp(bins, g, h, c, num_bins, na_bin, feature_mask,
         def _fn(b_, g_, h_, c_, nb_, na_, fm_, qs_):
             return grow_fn(b_, g_, h_, c_, nb_, na_, fm_, gp=gp_dp,
                            bundle=bundle, qseed=qs_)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             _fn, mesh=mesh,
             in_specs=(P(axis, None), P(axis), P(axis), P(axis), P(), P(),
                       P(), P()),
@@ -57,7 +57,7 @@ def grow_tree_dp(bins, g, h, c, num_bins, na_bin, feature_mask,
         )
         seed = jnp.int32(0) if qseed is None else qseed
         return fn(bins, g, h, c, num_bins, na_bin, feature_mask, seed)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(grow_fn, gp=gp_dp, bundle=bundle),
         mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(axis), P(axis), P(), P(), P()),

@@ -99,7 +99,6 @@ def grow_tree_fp(bins, g, h, c, num_bins, na_bin, feature_mask,
     c = jax.device_put(c, rep)
     feature_mask = jax.device_put(feature_mask, vec)
 
-    from .mesh import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         return grow_tree_depthwise(bins, g, h, c, num_bins, na_bin,
                                    feature_mask, gp, bundle=bundle)

@@ -153,30 +153,6 @@ def plan_row_sharding(n_rows: int, num_shards: int,
                         feature_shards=feature_shards)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``shard_map`` across jax versions: newer jax exposes ``jax.shard_map``
-    with a ``check_vma=`` kwarg; older releases only ship
-    ``jax.experimental.shard_map.shard_map`` where the same switch is spelled
-    ``check_rep=``. Resolve whichever exists and translate the kwarg."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
-def mesh_context(mesh: Mesh):
-    """Ambient-mesh activation across jax versions: ``jax.set_mesh`` where it
-    exists; older jax makes the ``Mesh`` object itself the context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def make_mesh(num_devices: Optional[int] = None, axis_name: str = DATA_AXIS,
               devices: Optional[Sequence] = None,
               feature_shards: int = 1,

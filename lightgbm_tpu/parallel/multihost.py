@@ -85,12 +85,7 @@ def replicate_global(x: np.ndarray, mesh) -> "object":
 
     x = np.ascontiguousarray(x)
     sharding = NamedSharding(mesh, P())
-    maker = getattr(jax, "make_array_from_process_local_data", None)
-    if maker is not None:
-        return maker(sharding, x)
-    from jax.experimental import multihost_utils
-    return multihost_utils.host_local_array_to_global_array(
-        x, mesh, P())
+    return jax.make_array_from_process_local_data(sharding, x)
 
 
 def verify_pod_plan(plan) -> None:

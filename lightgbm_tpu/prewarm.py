@@ -44,7 +44,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from . import obs
-from .utils import log
+from .utils import faults, log
 
 # config fields that shape the traced step program beyond what the
 # structural fields (gp, k, n, f, flags) already capture — objective family
@@ -237,7 +237,6 @@ def maybe_start(conf, dataset) -> Optional[PrewarmHandle]:
         try:
             # chaos point: a failed background compile must degrade to
             # compile-at-dispatch (adoption miss), never break training
-            from .utils import faults
             faults.fault_point("prewarm_compile")
             # lazy import: basic imports this module lazily from construct,
             # so there is no cycle at import time
@@ -285,6 +284,10 @@ def adopt(handle: PrewarmHandle, gbdt, custom: bool = False):
     tele = obs.enabled()
     err = handle.result.get("error")
     if err is not None:
+        if faults.is_compile_oom(err) and handle.spec == step_spec(gbdt):
+            # the identical program would fail identically at dispatch:
+            # surface it now instead of compiling it a second time
+            raise err
         if tele:
             obs.emit("aot_prewarm", phase="miss",
                      reason=f"background compile failed: {str(err)[:160]}")

@@ -140,10 +140,12 @@ class SimulatedOomError(RuntimeError):
 
 
 def _xla_runtime_error_type():
+    """The runtime's error class (``jax.errors.JaxRuntimeError``, the
+    former ``XlaRuntimeError``); None only where jax is not importable."""
     try:
-        from jaxlib.xla_extension import XlaRuntimeError
-        return XlaRuntimeError
-    except Exception:
+        from jax.errors import JaxRuntimeError
+        return JaxRuntimeError
+    except ImportError:
         return None
 
 
@@ -174,14 +176,28 @@ def is_resource_exhausted(exc: BaseException) -> bool:
     return "RESOURCE_EXHAUSTED" in str(exc)
 
 
+def is_compile_oom(exc: BaseException) -> bool:
+    """True for a RESOURCE_EXHAUSTED raised by the COMPILER rather than the
+    allocator: the program as compiled cannot fit — a Mosaic kernel over the
+    scoped-VMEM limit, or buffers beyond HBM ("Ran out of memory in memory
+    space vmem|hbm ..."; the allocator's runtime failures read "Error
+    allocating device buffer"). It is deterministic: the same program fails
+    the same way on every retry, so it is not a device fault."""
+    return (is_resource_exhausted(exc)
+            and "Ran out of memory in memory space" in str(exc))
+
+
 def is_device_fault(exc: BaseException) -> bool:
     """Classify an exception as a device-level fault: a real (or injected)
-    XLA RESOURCE_EXHAUSTED, or a :class:`FaultInjected` from one of the
-    device chaos points. This is the predicate the ``on_device_fault``
-    recovery policies key on (ingest.py, models/gbdt.py)."""
+    XLA RESOURCE_EXHAUSTED from the allocator, or a :class:`FaultInjected`
+    from one of the device chaos points. This is the predicate the
+    ``on_device_fault`` recovery policies key on (ingest.py,
+    models/gbdt.py); a compile-time out-of-memory (:func:`is_compile_oom`)
+    is excluded because no retry, smaller chunk or re-plan of the same
+    program can cure it."""
     if isinstance(exc, FaultInjected):
         return exc.point in DEVICE_FAULT_POINTS
-    return is_resource_exhausted(exc)
+    return is_resource_exhausted(exc) and not is_compile_oom(exc)
 
 
 def classify_point(exc: BaseException, default: str = "device") -> str:

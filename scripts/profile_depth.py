@@ -10,8 +10,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_lgbm_tpu")
-
 from bench import synth_higgs
 import lightgbm_tpu as lgb
 from lightgbm_tpu.ops.grow import GrowParams

@@ -14,8 +14,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_lgbm_tpu")
-
 from lightgbm_tpu.ops.grow import GrowParams
 from lightgbm_tpu.ops.split import SplitParams
 from lightgbm_tpu.ops.grow_depthwise import grow_tree_depthwise

@@ -4,8 +4,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_lgbm_tpu")
-
 from lightgbm_tpu.ops import histogram as H
 from lightgbm_tpu.ops.grow import GrowParams
 from lightgbm_tpu.ops.split import SplitParams, best_split

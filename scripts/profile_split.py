@@ -6,8 +6,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_lgbm_tpu")
-
 from lightgbm_tpu.ops.split import SplitParams, best_split, leaf_split_gain, NEG_INF
 
 L, F, B = 255, 28, 64

@@ -1,7 +1,16 @@
-"""Compiled (non-interpret) Pallas kernel equivalence checks, run on a REAL
-TPU backend by tests/test_tpu_kernels.py via subprocess (the main suite pins
-the CPU backend in conftest; Mosaic-specific miscompiles only show up
-compiled). Exit codes: 0 = pass, 3 = no TPU available."""
+"""Compiled (non-interpret) Pallas kernel equivalence checks for a REAL TPU
+backend (the main suite pins the CPU backend in conftest; Mosaic-specific
+miscompiles only show up compiled).
+
+Each ``check_*`` function raises on a mismatch. Two callers:
+tests/test_tpu_kernels.py runs this file as a child process (exit codes:
+0 = pass, 3 = no TPU available), and chip_smoke.py imports it and calls
+``run_all()`` in-process — a child cannot have the chip while the smoke
+holds it.
+
+The checks from ``check_front`` down run at the HIGGS width the product
+trains at (F=28, B=64, L=255)."""
+import os
 import sys
 
 import numpy as np
@@ -9,23 +18,33 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from lightgbm_tpu.ops import histogram as H  # noqa: E402
+from lightgbm_tpu.ops import pallas_hist as PH  # noqa: E402
+
+# HIGGS width; the row count is not a multiple of any kernel chunk, so every
+# check also exercises the padded tail
+F, B, L = 28, 64, 255
+N = 50_000
 
 
-def main():
-    if jax.default_backend() not in ("tpu",):
-        print(f"NO_TPU backend={jax.default_backend()}")
-        return 3
+def _route_tables(rng, l, f, b, s):
+    """Random per-leaf split tables; ~1/8 of the leaves do not split."""
+    feat = rng.randint(0, f, size=l).astype(np.int32)
+    feat[rng.rand(l) < 0.125] = -1
+    return H.RouteTables(
+        feat=jnp.asarray(feat),
+        thr=jnp.asarray(rng.randint(0, b, size=l).astype(np.int32)),
+        dleft=jnp.asarray(rng.randint(0, 2, size=l).astype(np.int32)),
+        new_leaf=jnp.asarray(rng.permutation(l).astype(np.int32)),
+        slot_left=jnp.asarray(rng.randint(0, s + 1, size=l).astype(np.int32)),
+        slot_right=jnp.asarray(rng.randint(0, s + 1, size=l).astype(np.int32)))
 
-    from lightgbm_tpu.ops import histogram as H
-    from lightgbm_tpu.ops.pallas_hist import (hist_pallas, hist_pallas_q8,
-                                              leaf_sums_pallas,
-                                              route_level_pallas,
-                                              take_small_pallas)
 
+def check_hist_pallas():
+    """bf16 hi/lo slot-routed histogram vs the scatter reference."""
     rng = np.random.RandomState(0)
-
-    # ---- slot-routed histogram vs scatter reference ----
     n, f, b, s = 20000, 12, 64, 6
     bins = rng.randint(0, b, size=(n, f)).astype(np.uint8)
     g = rng.randn(n).astype(np.float32)
@@ -36,116 +55,242 @@ def main():
     ref = np.asarray(H.hist_per_leaf_scatter(
         jnp.asarray(bins), jnp.asarray(g * keep), jnp.asarray(h * keep),
         jnp.asarray(c * keep), jnp.asarray(np.where(keep, slot, s)), s, b))
-    out = np.asarray(hist_pallas(jnp.asarray(bins.T.copy()), jnp.asarray(g),
-                                 jnp.asarray(h), jnp.asarray(c),
-                                 jnp.asarray(slot), s, b))
+    out = np.asarray(PH.hist_pallas(
+        jnp.asarray(bins.T.copy()), jnp.asarray(g), jnp.asarray(h),
+        jnp.asarray(c), jnp.asarray(slot), s, b))
     np.testing.assert_allclose(out, ref, rtol=1e-3, atol=1e-2)
-    print("hist_pallas OK")
 
-    # ---- int8 quantized histogram: exact integer accumulation ----
-    # scale 127.0 makes the dequantization factor exactly 1.0, so the output
-    # must equal the raw integer sums bit-for-bit (count channel exact)
+
+def check_hist_pallas_q8():
+    """int8 histogram: exact integer accumulation, and the 2-channel
+    constant-hessian form against the 3-channel one."""
+    rng = np.random.RandomState(1)
+    n, f, b, s = 20000, 12, 64, 6
+    bins = rng.randint(0, b, size=(n, f)).astype(np.uint8)
+    bins_T = jnp.asarray(bins.T.copy())
+    slot = rng.randint(0, s + 2, size=n).astype(np.int32)
+    keep = slot < s
     gq = rng.randint(-127, 128, size=n).astype(np.int8)
     hq = rng.randint(0, 128, size=n).astype(np.int8)
     cq = np.ones(n, np.int8)
-    outq = np.asarray(hist_pallas_q8(
-        jnp.asarray(bins.T.copy()), jnp.asarray(gq), jnp.asarray(hq),
-        jnp.asarray(cq), jnp.asarray(slot), s, b,
-        jnp.float32(127.0), jnp.float32(127.0)))
+    # scale 127.0 makes the dequantization factor exactly 1.0, so the output
+    # must equal the raw integer sums bit-for-bit (count channel exact)
+    outq = np.asarray(PH.hist_pallas_q8(
+        bins_T, jnp.asarray(gq), jnp.asarray(hq), jnp.asarray(cq),
+        jnp.asarray(slot), s, b, jnp.float32(127.0), jnp.float32(127.0)))
     refq = np.zeros((s, 3, f, b), np.float64)
     for j in range(f):
-        np.add.at(refq[:, 0, j, :], (np.where(keep, slot, 0), bins[:, j]),
-                  np.where(keep, gq, 0))
-        np.add.at(refq[:, 1, j, :], (np.where(keep, slot, 0), bins[:, j]),
-                  np.where(keep, hq, 0))
-        np.add.at(refq[:, 2, j, :], (np.where(keep, slot, 0), bins[:, j]),
-                  np.where(keep, 1.0, 0.0))
+        for ch, w in enumerate((gq, hq, np.ones(n))):
+            np.add.at(refq[:, ch, j, :], (np.where(keep, slot, 0), bins[:, j]),
+                      np.where(keep, w, 0))
     np.testing.assert_allclose(outq, refq, rtol=0, atol=0.5)
-    print("hist_pallas_q8 OK")
 
-    # ---- constant-hessian elision: 2-channel kernel must equal the
-    # 3-channel kernel run with hq = cq (the exact quantization of a
-    # constant hessian; GrowParams.const_hess docstring) ----
+    # constant-hessian elision: the 2-channel kernel must equal the 3-channel
+    # kernel run with hq = cq (the exact quantization of a constant hessian;
+    # GrowParams.const_hess docstring)
     h_const = 0.37
-    out3 = np.asarray(hist_pallas_q8(
-        jnp.asarray(bins.T.copy()), jnp.asarray(gq), jnp.asarray(cq),
-        jnp.asarray(cq), jnp.asarray(slot), s, b,
-        jnp.float32(127.0), jnp.float32(127.0 * h_const)))
-    out2 = np.asarray(hist_pallas_q8(
-        jnp.asarray(bins.T.copy()), jnp.asarray(gq), jnp.asarray(cq),
-        jnp.asarray(cq), jnp.asarray(slot), s, b,
-        jnp.float32(127.0), jnp.float32(127.0 * h_const), const_hess=True))
+    args = (bins_T, jnp.asarray(gq), jnp.asarray(cq), jnp.asarray(cq),
+            jnp.asarray(slot), s, b, jnp.float32(127.0),
+            jnp.float32(127.0 * h_const))
+    out3 = np.asarray(PH.hist_pallas_q8(*args))
+    out2 = np.asarray(PH.hist_pallas_q8(*args, const_hess=True))
     np.testing.assert_allclose(out2, out3, rtol=1e-6, atol=1e-4)
-    print("hist_pallas_q8 const_hess OK")
 
-    # same for the fused route+hist kernel
-    from lightgbm_tpu.ops.pallas_hist import hist_routed_fused_q8
-    L0, S0 = 8, 4
-    tabs0 = H.RouteTables(
-        feat=jnp.asarray(np.array([0, -1, 2, 4, 1, -1, 3, 0], np.int32)),
-        thr=jnp.asarray(rng.randint(0, b, size=L0).astype(np.int32)),
-        dleft=jnp.asarray(rng.randint(0, 2, size=L0).astype(np.int32)),
-        new_leaf=jnp.asarray((np.arange(L0) + L0).astype(np.int32)),
-        slot_left=jnp.asarray(rng.randint(0, S0 + 1, size=L0).astype(np.int32)),
-        slot_right=jnp.asarray(rng.randint(0, S0 + 1, size=L0).astype(np.int32)))
-    lid0 = jnp.asarray(rng.randint(0, L0, size=n).astype(np.int32))
-    nab0 = jnp.full(f, 256, jnp.int32)
-    f3, l3 = hist_routed_fused_q8(
-        jnp.asarray(bins.T.copy()), jnp.asarray(gq), jnp.asarray(cq),
-        jnp.asarray(cq), lid0, tabs0, nab0, S0, b,
-        jnp.float32(127.0), jnp.float32(127.0 * h_const), L0)
-    f2_, l2_ = hist_routed_fused_q8(
-        jnp.asarray(bins.T.copy()), jnp.asarray(gq), jnp.asarray(cq),
-        jnp.asarray(cq), lid0, tabs0, nab0, S0, b,
-        jnp.float32(127.0), jnp.float32(127.0 * h_const), L0,
-        const_hess=True)
-    np.testing.assert_allclose(np.asarray(f2_), np.asarray(f3),
-                               rtol=1e-6, atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(l2_), np.asarray(l3))
-    print("hist_routed_fused_q8 const_hess OK")
 
-    # ---- fused route pass vs XLA reference ----
-    L, S = 8, 4
-    n2, f2, b2 = 30000, 5, 16
-    bins2 = rng.randint(0, b2, size=(n2, f2)).astype(np.uint8)
-    leaf_id = rng.randint(0, L, size=n2).astype(np.int32)
-    na_bin = np.array([3, 256, 256, 7, 256], dtype=np.int32)
-    tables = H.RouteTables(
-        feat=jnp.asarray(np.array([0, -1, 2, 4, 1, -1, 3, 0], np.int32)),
-        thr=jnp.asarray(rng.randint(0, b2, size=L).astype(np.int32)),
-        dleft=jnp.asarray(rng.randint(0, 2, size=L).astype(np.int32)),
-        new_leaf=jnp.asarray((np.arange(L) + L).astype(np.int32)),
-        slot_left=jnp.asarray(rng.randint(0, S + 1, size=L).astype(np.int32)),
-        slot_right=jnp.asarray(rng.randint(0, S + 1, size=L).astype(np.int32)))
-    ref_slot, ref_lid = H.route_level(jnp.asarray(bins2),
-                                      jnp.asarray(leaf_id), tables,
-                                      jnp.asarray(na_bin), S)
-    out_slot, out_lid = route_level_pallas(
-        jnp.asarray(bins2.T.copy()), jnp.asarray(leaf_id), tables,
-        jnp.asarray(na_bin), S, L)
+def check_route_level():
+    """Standalone route kernel vs the XLA gather route."""
+    rng = np.random.RandomState(2)
+    l, s = 8, 4
+    n, f, b = 30000, 5, 16
+    bins = rng.randint(0, b, size=(n, f)).astype(np.uint8)
+    leaf_id = jnp.asarray(rng.randint(0, l, size=n).astype(np.int32))
+    na_bin = jnp.asarray(np.array([3, 256, 256, 7, 256], dtype=np.int32))
+    tables = _route_tables(rng, l, f, b, s)
+    ref_slot, ref_lid = H.route_level(jnp.asarray(bins), leaf_id, tables,
+                                      na_bin, s)
+    out_slot, out_lid = PH.route_level_pallas(
+        jnp.asarray(bins.T.copy()), leaf_id, tables, na_bin, s, l)
     np.testing.assert_array_equal(np.asarray(ref_lid), np.asarray(out_lid))
-    np.testing.assert_array_equal(np.minimum(np.asarray(ref_slot), S),
-                                  np.minimum(np.asarray(out_slot), S))
-    print("route_level_pallas OK")
+    np.testing.assert_array_equal(np.minimum(np.asarray(ref_slot), s),
+                                  np.minimum(np.asarray(out_slot), s))
 
-    # ---- small-table gather ----
-    table = rng.randn(255).astype(np.float32)
-    idx = rng.randint(0, 255, size=100000).astype(np.int32)
-    outg = np.asarray(take_small_pallas(jnp.asarray(table), jnp.asarray(idx)))
-    np.testing.assert_allclose(outg, table[idx], rtol=1e-6)
-    print("take_small_pallas OK")
 
-    # ---- per-leaf exact sums ----
-    sums = np.asarray(leaf_sums_pallas(jnp.asarray(g), jnp.asarray(h),
-                                       jnp.asarray(c),
-                                       jnp.asarray(slot % s), s))
-    refs = np.zeros((3, s))
-    for ch, arr in enumerate((g, h, c)):
-        for sl in range(s):
-            refs[ch, sl] = arr[(slot % s) == sl].sum()
-    np.testing.assert_allclose(sums, refs, rtol=1e-3, atol=1e-2)
-    print("leaf_sums_pallas OK")
+def check_take_small():
+    rng = np.random.RandomState(3)
+    table = rng.randn(L).astype(np.float32)
+    idx = rng.randint(0, L, size=100000).astype(np.int32)
+    out = np.asarray(PH.take_small_pallas(jnp.asarray(table),
+                                          jnp.asarray(idx)))
+    np.testing.assert_allclose(out, table[idx], rtol=1e-6)
 
+
+def _leaf_sums_ref(g, h, c, lid):
+    return np.stack([np.bincount(lid, weights=w.astype(np.float64),
+                                 minlength=L) for w in (g, h, c)])
+
+
+def check_leaf_sums():
+    rng = np.random.RandomState(4)
+    g = rng.randn(N).astype(np.float32)
+    h = rng.rand(N).astype(np.float32)
+    c = np.ones(N, np.float32)
+    lid = rng.randint(0, L, size=N).astype(np.int32)
+    sums = np.asarray(PH.leaf_sums_pallas(
+        jnp.asarray(g), jnp.asarray(h), jnp.asarray(c), jnp.asarray(lid), L))
+    np.testing.assert_allclose(sums, _leaf_sums_ref(g, h, c, lid),
+                               rtol=1e-3, atol=1e-2)
+
+
+# the two objective families the fused front serves: (spec, const_hess)
+_FRONT_SPECS = ((("logloss", 1.0, 1.0, 1.0), False), (("l2",), True))
+
+
+def _front_inputs(rng, spec):
+    bins = rng.randint(0, B - 1, size=(N, F)).astype(np.uint8)
+    score = rng.randn(N).astype(np.float32)
+    aux = ((rng.rand(N) < 0.5).astype(np.float32) if spec[0] == "logloss"
+           else rng.randn(N).astype(np.float32))
+    bag = (rng.rand(N) < 0.8).astype(np.float32)
+    return bins, score, aux, bag
+
+
+def check_front():
+    """Fused gradient + quantise + root histogram vs the unfused chain
+    (XLA gradients -> make_quant -> compiled q8 root pass).
+
+    L2 has no transcendental, so every output must match bit for bit.
+    Logloss goes through exp, which compiled Mosaic and XLA may round
+    differently in the last ulp: gq/hq may then differ by one quantum on
+    rows that sit on a rounding boundary, and hist0 by at most one quantum
+    per such row."""
+    for spec, const_hess in _FRONT_SPECS:
+        rng = np.random.RandomState(5)
+        bins, score, aux, bag = (jnp.asarray(a)
+                                 for a in _front_inputs(rng, spec))
+        bins_T = bins.T
+        seed = jnp.int32(7)
+        fq, fhist = jax.jit(
+            lambda b_, bt, s_, a_, g_: H.grad_quant_hist0(
+                b_, s_, a_, g_, seed, spec, B, const_hess=const_hess,
+                impl="pallas", bins_T=bt))(bins, bins_T, score, aux, bag)
+
+        def chain(b_, bt, s_, a_, g_):
+            grad, hess = PH._grad_rows(spec, s_, a_)
+            g, h = grad * g_, hess * g_
+            c = (g_ > 0).astype(jnp.float32)
+            q = H.make_quant(g, h, c, seed, const_hess=const_hess)
+            return q, H.hist_leaf(b_, g, h, c, B, impl="pallas", bins_T=bt,
+                                  quant=q)
+        rq, rhist = jax.jit(chain)(bins, bins_T, score, aux, bag)
+
+        np.testing.assert_array_equal(np.asarray(fq.cq), np.asarray(rq.cq))
+        assert (fq.hq is None) == const_hess
+        fhist, rhist = np.asarray(fhist), np.asarray(rhist)
+        assert fhist.shape == (3, F, B) and np.isfinite(fhist).all()
+        if spec[0] == "l2":
+            np.testing.assert_array_equal(np.asarray(fq.gq),
+                                          np.asarray(rq.gq))
+            for a, b in ((fq.scale_g, rq.scale_g), (fq.scale_h, rq.scale_h)):
+                assert float(a) == float(b), (float(a), float(b))
+            np.testing.assert_array_equal(fhist, rhist)
+            continue
+        np.testing.assert_array_equal(fhist[2], rhist[2])   # counts: exact
+        for ch, (fa, ra, fs, rs) in enumerate((
+                (fq.gq, rq.gq, fq.scale_g, rq.scale_g),
+                (fq.hq, rq.hq, fq.scale_h, rq.scale_h))):
+            np.testing.assert_allclose(float(fs), float(rs), rtol=1e-6)
+            d = np.abs(np.asarray(fa).astype(np.int32)
+                       - np.asarray(ra).astype(np.int32))
+            assert d.max() <= 1, f"channel {ch}: {d.max()} quanta apart"
+            moved = int((d > 0).sum())
+            assert moved <= N // 100, f"channel {ch}: {moved} rows moved"
+            quantum = float(rs) / 127.0
+            np.testing.assert_allclose(fhist[ch], rhist[ch], rtol=1e-5,
+                                       atol=(moved + 1) * quantum)
+
+
+def check_leaf_sums_grad():
+    """Leaf renewal with in-register gradients vs leaf_sums_pallas on the
+    materialized rows (bit-identical for L2; exp's last ulp for logloss)
+    and vs f64 host sums."""
+    for spec, _ in _FRONT_SPECS:
+        rng = np.random.RandomState(6)
+        _, score, aux, bag = _front_inputs(rng, spec)
+        lid = rng.randint(0, L, size=N).astype(np.int32)
+        out = np.asarray(PH.leaf_sums_grad_pallas(
+            jnp.asarray(score), jnp.asarray(aux), jnp.asarray(bag),
+            jnp.asarray(lid), spec, L))
+        grad, hess = PH._grad_rows(spec, jnp.asarray(score), jnp.asarray(aux))
+        g, h = np.asarray(grad) * bag, np.asarray(hess) * bag
+        c = (bag > 0).astype(np.float32)
+        np.testing.assert_allclose(out, _leaf_sums_ref(g, h, c, lid),
+                                   rtol=1e-3, atol=1e-2)
+        mat = np.asarray(PH.leaf_sums_pallas(
+            jnp.asarray(g), jnp.asarray(h), jnp.asarray(c), jnp.asarray(lid),
+            L))
+        if spec[0] == "l2":
+            np.testing.assert_array_equal(out, mat)
+        else:
+            np.testing.assert_allclose(out, mat, rtol=1e-4, atol=1e-3)
+
+
+def check_fused_level():
+    """Fused route + int8 histogram level pass at the slot widths the
+    255-leaf grower runs (32 and the 127 cap) and the 128 master width,
+    with 3 and 2 (constant-hessian) channels, vs hist_routed_scatter."""
+    rng = np.random.RandomState(8)
+    bins = rng.randint(0, B - 1, size=(N, F)).astype(np.uint8)
+    bins_d, bins_T = jnp.asarray(bins), jnp.asarray(bins.T.copy())
+    gq = rng.randint(-127, 128, size=N).astype(np.int8)
+    hq = rng.randint(0, 128, size=N).astype(np.int8)
+    cq = (rng.rand(N) < 0.8).astype(np.int8)
+    lid = jnp.asarray(rng.randint(0, L, size=N).astype(np.int32))
+    # a few features carry a missing bin so the default-direction branch runs
+    na = np.full(F, 256, np.int32)
+    na[::5] = B - 2
+    na_bin = jnp.asarray(na)
+    h_const = 0.5   # keeps the reference's f32 hessian sums exact
+    for s in (32, 127, 128):
+        tables = _route_tables(rng, L, F, B, s)
+        for const_hess in (False, True):
+            hrow = cq if const_hess else hq
+            scale_h = 127.0 * h_const if const_hess else 127.0
+            hist, lid2 = PH.hist_routed_fused_q8(
+                bins_T, jnp.asarray(gq), jnp.asarray(hrow), jnp.asarray(cq),
+                lid, tables, na_bin, s, B, jnp.float32(127.0),
+                jnp.float32(scale_h), L, const_hess=const_hess)
+            # scale 127 -> dequantization factor exactly 1: the reference
+            # accumulates the same integers in f32 (|sums| < 2^24, exact)
+            h_ref = (cq * np.float32(h_const) if const_hess
+                     else hq.astype(np.float32))
+            rhist, rlid = H.hist_routed_scatter(
+                bins_d, jnp.asarray(gq.astype(np.float32)),
+                jnp.asarray(h_ref), jnp.asarray(cq.astype(np.float32)),
+                lid, tables, na_bin, s, B)
+            np.testing.assert_array_equal(np.asarray(lid2), np.asarray(rlid))
+            np.testing.assert_allclose(np.asarray(hist), np.asarray(rhist),
+                                       rtol=0, atol=0.25,
+                                       err_msg=f"S={s} const_hess={const_hess}")
+
+
+CHECKS = (check_hist_pallas, check_hist_pallas_q8, check_route_level,
+          check_take_small, check_leaf_sums, check_front,
+          check_leaf_sums_grad, check_fused_level)
+
+
+def run_all():
+    """Run every check on the current (TPU) backend; returns the names."""
+    for fn in CHECKS:
+        fn()
+        print(f"{fn.__name__} OK", flush=True)
+    return [fn.__name__ for fn in CHECKS]
+
+
+def main():
+    if jax.default_backend() != "tpu":
+        print(f"NO_TPU backend={jax.default_backend()}")
+        return 3
+    run_all()
     print("TPU_KERNELS_OK")
     return 0
 

@@ -128,12 +128,12 @@ def test_lowering_counter_sees_per_call_jit():
     with jtu.count_jit_and_pmap_lowerings() as n:
         for _ in range(3):
             reused(x)
-    assert n[0] == 0, "a warmed wrapper must not lower again"
+    assert n() == 0, "a warmed wrapper must not lower again"
     with jtu.count_jit_and_pmap_lowerings() as n:
         for _ in range(3):
             # the canary pattern: fresh wrapper per call
             jax.jit(lambda a: a * 2 + 1)(x)  # tpu-lint: disable=retrace-hazard
-    assert n[0] == 3, "per-call jit must lower per call"
+    assert n() == 3, "per-call jit must lower per call"
 
 
 # ---------------------------------------------------------------------------

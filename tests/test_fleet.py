@@ -334,7 +334,7 @@ def test_zero_new_lowerings_on_warmed_fleet(boosters, queries):
             [t.join() for t in ths]
             fs.publish(live)                  # v2 fan-out: same buckets
             fs.predict(queries[:4])
-        assert count[0] == 0, f"{count[0]} new lowerings on a warmed fleet"
+        assert count() == 0, f"{count()} new lowerings on a warmed fleet"
     finally:
         fs.close()
 
@@ -419,7 +419,7 @@ def test_clean_candidate_auto_promotes_via_engine_handoff(boosters, queries):
             t[0] += ro.window_s + 1.0
             assert ro.tick() == "idle"            # window elapsed: promote
             srv.predict(queries[:1])
-        assert count[0] == 0, "promote must not rebuild or re-lower"
+        assert count() == 0, "promote must not rebuild or re-lower"
         assert ro.stats["promoted"] == 1 and ro.stats["rolled_back"] == 0
         live_sm = srv.registry.current("default")
         assert live_sm.version == 2

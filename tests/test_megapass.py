@@ -185,12 +185,12 @@ def test_warm_dart_predict_and_update_zero_lowerings():
     with jtu.count_jit_and_pmap_lowerings() as n:
         p1 = bst.predict(X)
         p2 = bst.predict(X)
-    assert n[0] == 0, f"{n[0]} lowerings in warmed DART predict"
+    assert n() == 0, f"{n()} lowerings in warmed DART predict"
     np.testing.assert_array_equal(p1, p2)
     with jtu.count_jit_and_pmap_lowerings() as n:
         bst.update()
         bst.update()
-    assert n[0] == 0, f"{n[0]} lowerings in warmed DART iterations"
+    assert n() == 0, f"{n()} lowerings in warmed DART iterations"
 
 
 def test_warm_online_refit_cycle_zero_lowerings():
@@ -213,4 +213,4 @@ def test_warm_online_refit_cycle_zero_lowerings():
     tr.feed(Xb, Xb[:, 0] + Xb[:, 1])
     with jtu.count_jit_and_pmap_lowerings() as n:
         assert tr.refit_now() == 2
-    assert n[0] == 0, f"{n[0]} lowerings in warmed online refit cycle"
+    assert n() == 0, f"{n()} lowerings in warmed online refit cycle"

@@ -175,7 +175,7 @@ def test_tracing_adds_zero_lowerings_on_warmed_engine(booster, queries):
                     np.testing.assert_array_equal(
                         srv.predict(queries[:n]),
                         booster.predict(queries[:n]))
-        assert count[0] == 0, f"tracing lowered {count[0]} new programs"
+        assert count() == 0, f"tracing lowered {count()} new programs"
         assert obs_tracing.TRACES.snapshot()    # and it actually traced
     finally:
         srv.close()

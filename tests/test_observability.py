@@ -270,7 +270,7 @@ def test_zero_retrace_predict_with_telemetry(booster):
         for n in (1, 30, 100):
             bst.predict(X[:n])
             bst.predict(X[:n], raw_score=True)
-    assert count[0] == 0, f"telemetry caused {count[0]} new lowerings"
+    assert count() == 0, f"telemetry caused {count()} new lowerings"
     assert obs.METRICS.counter("predict_calls", "predict() calls").value == 6
 
 
@@ -282,8 +282,8 @@ def test_training_lowering_count_unchanged_by_telemetry(tmp_path):
     obs.reset()
     with jtu.count_jit_and_pmap_lowerings() as on:
         _train(telemetry=1, metrics_out=str(tmp_path))
-    assert on[0] == off[0], (f"telemetry changed lowering count: "
-                             f"{off[0]} -> {on[0]}")
+    assert on() == off(), (f"telemetry changed lowering count: "
+                             f"{off()} -> {on()}")
 
 
 # ---- timer satellites -------------------------------------------------------

@@ -427,8 +427,8 @@ def test_end_to_end_online_drill():
             n_now = len(results)
             while len(results) < n_now + 40 and not errs:
                 time.sleep(0.005)
-        assert count[0] == 0, \
-            f"{count[0]} new lowerings in the refit+publish+serve window"
+        assert count() == 0, \
+            f"{count()} new lowerings in the refit+publish+serve window"
         assert v4 == 4
         want[4] = r4.predict(queries)
 

@@ -132,7 +132,7 @@ def test_zero_retraces_after_warmup(boosters, queries):
                    for t in range(6)]
             [t.start() for t in ths]
             [t.join() for t in ths]
-        assert count[0] == 0, f"{count[0]} recompilations on the serve path"
+        assert count() == 0, f"{count()} recompilations on the serve path"
     finally:
         srv.close()
 

@@ -1548,19 +1548,17 @@ def reduce_rows(x, axis):
 
 CALLBACK_IN_SHARD_MAP_FIRE = """
 import jax
-from lightgbm_tpu.parallel.compat import shard_map_compat
 
 def _grow_shard(x):
     jax.debug.print("shard sees {}", x)
     return jax.lax.psum(x, "data")
 
-grow = shard_map_compat(_grow_shard, mesh=None, in_specs=None,
-                        out_specs=None)
+grow = jax.shard_map(_grow_shard, mesh=None, in_specs=None,
+                     out_specs=None)
 """
 
 CALLBACK_IN_SHARD_MAP_CLEAN = """
 import jax
-from lightgbm_tpu.parallel.compat import shard_map_compat
 
 def _grow_shard(x):
     return jax.lax.psum(x, "data")
@@ -1568,8 +1566,8 @@ def _grow_shard(x):
 def report(x):
     jax.debug.print("host-side after the boundary {}", x)
 
-grow = shard_map_compat(_grow_shard, mesh=None, in_specs=None,
-                        out_specs=None)
+grow = jax.shard_map(_grow_shard, mesh=None, in_specs=None,
+                     out_specs=None)
 """
 
 

@@ -259,6 +259,47 @@ def test_prewarm_compile_fault_is_adoption_miss(data, ref3_bytes,
     assert _model_bytes(bst) == ref3_bytes
 
 
+# the text libtpu 0.0.34 gives a Mosaic kernel over the scoped-VMEM limit
+_VMEM_OOM = ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem "
+             "while allocating on stack for %custom-call. Scoped allocation "
+             "with size 23.44M and limit 16.00M exceeded scoped vmem limit "
+             "by 7.44M.")
+
+
+@pytest.mark.faults
+def test_compile_oom_is_not_a_device_fault(data, monkeypatch):
+    """A compile-time out-of-memory is deterministic: it must surface from
+    the first dispatch, once, and never enter the retry-with-backoff path
+    (nor be logged as a transient device_fault)."""
+    err = faults._xla_runtime_error_type()(_VMEM_OOM)
+    assert faults.is_resource_exhausted(err) and faults.is_compile_oom(err)
+    assert not faults.is_device_fault(err)
+    runtime_oom = faults._oom_error("device_put_oom", 1)
+    assert faults.is_device_fault(runtime_oom)
+    assert not faults.is_compile_oom(runtime_oom)
+
+    from lightgbm_tpu.models.gbdt import GBDT
+    calls = []
+
+    def _boom(self, custom):
+        def step(*a):
+            calls.append(1)
+            raise err
+        return step
+    monkeypatch.setattr(GBDT, "_build_fused_step", _boom)
+    obs.configure(enabled=True)
+    obs.reset()
+    try:
+        with pytest.raises(type(err)) as ei:
+            _train(data, 1, 3, telemetry=True, on_device_fault="reshard")
+        assert len(calls) == 1
+        assert not _device_fault_events()
+        assert any("not retried" in n for n in ei.value.__notes__)
+    finally:
+        obs.configure(enabled=False)
+        obs.reset()
+
+
 # ---------------- mesh preflight fence ----------------
 
 def _plan_shim(**over):
