@@ -1,0 +1,11 @@
+"""Device time under the level groups narrower than the deepest one
+(``level_s<W>``, W < num_leaves // 2), per iteration."""
+from benchmark import scopes
+
+
+def read(ctx):
+    view = scopes.of(ctx)
+    if view is None:
+        return None
+    full = int(ctx.cell["cfg"]["params"]["num_leaves"]) // 2
+    return view.per_iter_ms(view.level_s(lambda w: w < full))

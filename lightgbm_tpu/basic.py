@@ -105,12 +105,15 @@ class Dataset:
                  categorical_feature: Union[str, List] = "auto",
                  params: Optional[Dict[str, Any]] = None,
                  free_raw_data: bool = True):
+        t0 = time.time()
         self.params = dict(params or {})
         self.raw_data = data
         self.label = _to_numpy_1d(label)
         self.weight = _to_numpy_1d(weight)
         self.group = None if group is None else np.asarray(group, dtype=np.int64)
         self.init_score = _to_numpy_1d(init_score)
+        # the row vectors as float64: the first of construct_phases
+        self._init_s = round(time.time() - t0, 3)
         self.reference = reference
         self.feature_name = feature_name
         self.categorical_feature = categorical_feature
@@ -183,11 +186,23 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._constructed:
             return self
-        from .utils.timer import TIMER
-        with TIMER.scope("dataset_construct"):
+        from . import obs
+        with obs.span("dataset_construct"):
             return self._construct_inner()
 
     def _construct_inner(self) -> "Dataset":
+        # construct_phases: consecutive marks from here to the return, so the
+        # named seconds add up to the call (find_bins_s, the first, holds the
+        # raw matrix's way to numpy)
+        phases = self.construct_phases = {"init_s": self._init_s}
+        t_last = time.time()
+
+        def _mark(name):
+            nonlocal t_last
+            now = time.time()
+            phases[name] = round(now - t_last, 3)
+            t_last = now
+
         conf = params_to_config(self.params)
         if conf.num_threads and conf.num_threads > 0:
             from .native import set_num_threads
@@ -221,15 +236,6 @@ class Dataset:
             self._finish_device(bins, ref._num_bins_np, ref._na_bin_raw,
                                 ref._mtypes_np, ref.max_num_bins)
             return self
-
-        phases = self.construct_phases = {}
-        t_last = time.time()
-
-        def _mark(name):
-            nonlocal t_last
-            now = time.time()
-            phases[name] = round(now - t_last, 3)
-            t_last = now
 
         sparse_in = _is_scipy_sparse(self.raw_data)
         if sparse_in:

@@ -6,6 +6,7 @@ alignment to the train set, early stopping, continued training from an init mode
 """
 from __future__ import annotations
 
+import contextlib
 import copy
 import math
 import time
@@ -57,9 +58,24 @@ def train(params: Dict[str, Any], train_set: Dataset,
     where the uninterrupted run would have (byte-identical final model
     under the same params/seed).
     """
+    # the span train_setup runs from here to the first iteration, and closes
+    # here too if set-up raises
+    with contextlib.ExitStack() as setup:
+        return _train(setup, params, train_set, num_boost_round, valid_sets,
+                      valid_names, fobj, feval, init_model, feature_name,
+                      categorical_feature, early_stopping_rounds,
+                      evals_result, verbose_eval, callbacks,
+                      resume_from_snapshot)
+
+
+def _train(setup, params, train_set, num_boost_round, valid_sets, valid_names,
+           fobj, feval, init_model, feature_name, categorical_feature,
+           early_stopping_rounds, evals_result, verbose_eval, callbacks,
+           resume_from_snapshot) -> Booster:
     params = dict(params or {})
     conf = params_to_config(params)
     obs.configure_from_config(conf)
+    setup.enter_context(obs.span("train_setup"))
     # fresh timing namespace per run: accumulations must not bleed across
     # successive train() calls in one process (the previous run's table
     # stays readable via TIMER.last_run)
@@ -177,94 +193,62 @@ def train(params: Dict[str, Any], train_set: Dataset,
     # a nested train (an online refit cycle) from stopping the outer flusher
     flush_owner = obs.start_periodic_flush(conf.metrics_flush_secs)
     t_start = time.perf_counter()
-    t_iter0 = t_start
     try:
         for i in range(begin_iteration, end_iteration):
-            if tele:
+            setup.close()
+            # one iteration = one step of the profiler's step view; its
+            # children (docs/OBSERVABILITY.md has the tree) nest by time
+            with obs.span("train_iter", step_num=i + 1) as it_rec:
                 t_iter0 = time.perf_counter()
-            # fault point for kill-and-resume tests: an armed 'tree_update'
-            # fault propagates out of train() like a crash at iteration i
-            faults.fault_point("tree_update")
-            for c in callbacks_before:
-                c(cb.CallbackEnv(model=booster, params=params, iteration=i,
-                                 begin_iteration=begin_iteration,
-                                 end_iteration=end_iteration,
-                                 evaluation_result_list=None))
-            with TIMER.scope("boosting"):
-                finished = booster.update(fobj=fobj)
-            evaluation_result_list = []
-            if booster._gbdt.valid_sets or eval_training:
-                with TIMER.scope("eval"):
-                    if eval_training:
-                        evaluation_result_list.extend(booster.eval_train())
-                    evaluation_result_list.extend(booster.eval_valid())
-                    if feval is not None:
-                        evaluation_result_list.extend(
-                            _run_feval(feval, booster, train_set, eval_training))
-                _check_eval_finite(evaluation_result_list,
-                                   conf.nonfinite_policy, nf_eval_warned, i)
-            for c in callbacks_after:
-                c(cb.CallbackEnv(model=booster, params=params, iteration=i,
-                                 begin_iteration=begin_iteration,
-                                 end_iteration=end_iteration,
-                                 evaluation_result_list=evaluation_result_list))
-            if tele:
-                # per-iteration telemetry: wall clock + throughput, plus the
-                # newest lagged leaf-count/best-gain stats (≤8 iterations old
-                # by design — reading them synchronously would stall the
-                # async dispatch pipeline)
-                dt = time.perf_counter() - t_iter0
-                fields = {"iteration": i + 1, "duration_s": dt,
-                          "rows_per_s": (train_set.num_data / dt)
-                          if dt > 0 else 0.0}
-                lag = booster._gbdt.obs_lagged_stats()
-                if lag:
-                    fields.update(lag)
-                obs.emit("train_iter", **fields)
-                obs.METRICS.counter("train_iterations",
-                                    "boosting iterations completed").inc()
-                obs.METRICS.histogram("train_iter_seconds",
-                                      "iteration wall time").observe(dt)
-                obs.memory.update_gauges(
-                    obs.METRICS,
-                    shard_of=booster._gbdt.obs_shard_devices())
-            # per-iteration wall clock (reference: gbdt.cpp:289 "%f seconds
-            # elapsed, finished iteration %d" at every metric output interval)
-            if conf.verbosity >= 1 and conf.metric_freq > 0 \
-                    and (i + 1) % conf.metric_freq == 0:
-                log.debug("%.6f seconds elapsed, finished iteration %d",
-                          time.perf_counter() - t_start, i + 1)
-            # periodic snapshots (reference: gbdt.cpp:291-295 snapshot_freq),
-            # crash-safe and rank-0-only (the reference wrote into CWD from
-            # every process): atomic model text + state sidecar + manifest
-            # with keep-last-N retention, written with backoff retries; a
-            # snapshot that still fails is WARNED, training continues
-            if conf.snapshot_freq > 0 and (i + 1) % conf.snapshot_freq == 0:
-                es_state = None
-                for c in callbacks:
-                    exp = getattr(c, "_es_export", None)
-                    if exp is not None:
-                        es_state = exp()
-                try:
-                    # rank-uniform in practice: _gbdt is None on EVERY rank
-                    # or none (boosters construct identically before the
-                    # loop), and write_snapshot enters the same
-                    # get_resume_state collective the elif arm does
-                    # tpu-lint: disable=collective-divergence
-                    if snap.is_writer_rank():
-                        path = snap.write_snapshot(
-                            booster, snapshot_dir, i + 1,
-                            keep=conf.snapshot_keep, es_state=es_state)
-                        log.info("Saved snapshot to %s", path)
-                    elif booster._gbdt is not None:
-                        # pod: get_resume_state allgathers sharded trainer
-                        # state — a COLLECTIVE every rank must enter even
-                        # though only the writer rank touches the disk
-                        booster._gbdt.get_resume_state()
-                except Exception as e:
-                    log.warning(f"snapshot at iteration {i + 1} failed after "
-                                f"retries ({type(e).__name__}: {e}); "
-                                "training continues")
+                # fault point for kill-and-resume tests: an armed
+                # 'tree_update' fault propagates out of train() like a crash
+                # at iteration i
+                faults.fault_point("tree_update")
+                if callbacks_before:
+                    with obs.span("callbacks_before"):
+                        for c in callbacks_before:
+                            c(cb.CallbackEnv(
+                                model=booster, params=params, iteration=i,
+                                begin_iteration=begin_iteration,
+                                end_iteration=end_iteration,
+                                evaluation_result_list=None))
+                with obs.span("boosting"):
+                    finished = booster.update(fobj=fobj)
+                evaluation_result_list = []
+                if booster._gbdt.valid_sets or eval_training:
+                    with obs.span("eval"):
+                        if eval_training:
+                            evaluation_result_list.extend(booster.eval_train())
+                        evaluation_result_list.extend(booster.eval_valid())
+                        if feval is not None:
+                            evaluation_result_list.extend(_run_feval(
+                                feval, booster, train_set, eval_training))
+                    _check_eval_finite(evaluation_result_list,
+                                       conf.nonfinite_policy, nf_eval_warned,
+                                       i)
+                if callbacks_after:
+                    with obs.span("callbacks"):
+                        for c in callbacks_after:
+                            c(cb.CallbackEnv(
+                                model=booster, params=params, iteration=i,
+                                begin_iteration=begin_iteration,
+                                end_iteration=end_iteration,
+                                evaluation_result_list=evaluation_result_list))
+                # per-iteration wall clock (reference: gbdt.cpp:289 "%f
+                # seconds elapsed, finished iteration %d" at every metric
+                # output interval)
+                if conf.verbosity >= 1 and conf.metric_freq > 0 \
+                        and (i + 1) % conf.metric_freq == 0:
+                    log.debug("%.6f seconds elapsed, finished iteration %d",
+                              time.perf_counter() - t_start, i + 1)
+                if conf.snapshot_freq > 0 \
+                        and (i + 1) % conf.snapshot_freq == 0:
+                    with obs.span("snapshot"):
+                        _write_snapshot(booster, callbacks, snapshot_dir,
+                                        i + 1, conf.snapshot_keep)
+                if tele:
+                    _emit_train_iter(booster, train_set, it_rec,
+                                     time.perf_counter() - t_iter0)
             if finished:
                 log.warning("Stopped training because there are no more leaves "
                             "that meet the split requirements")
@@ -280,18 +264,69 @@ def train(params: Dict[str, Any], train_set: Dataset,
     # drop trailing phantom stumps queued by the lagged finished-check
     # (reference stops without adding them, gbdt.cpp:430)
     booster._gbdt.finish_training()
-    with TIMER.scope("finalize"):
+    with obs.span("finalize"):
         booster._ensure_host_trees()
     if conf.verbosity >= 2:
         log.debug(TIMER.summary_string())
     if tele:
-        for name, rec in TIMER.snapshot().items():
-            obs.METRICS.gauge("phase_seconds", "TIMER phase wall time",
-                              phase=name).set(rec["seconds"])
         out = obs.export_all(conf.metrics_out)
         if out:
             log.info("telemetry exported to %s", out)
     return booster
+
+
+def _emit_train_iter(booster, train_set, it_rec, dt: float) -> None:
+    """Per-iteration telemetry: wall clock + throughput, the iteration's
+    span record, plus the newest lagged leaf-count/best-gain stats (≤8
+    iterations old by design — reading them synchronously would stall the
+    async dispatch pipeline)."""
+    fields = {"iteration": it_rec.step, "duration_s": dt,
+              "rows_per_s": (train_set.num_data / dt) if dt > 0 else 0.0,
+              "spans": dict(it_rec.spans),
+              "programs_loaded": it_rec.programs_loaded}
+    lag = booster._gbdt.obs_lagged_stats()
+    if lag:
+        fields.update(lag)
+    obs.emit("train_iter", **fields)
+    obs.METRICS.counter("train_iterations",
+                        "boosting iterations completed").inc()
+    obs.METRICS.histogram("train_iter_seconds",
+                          "iteration wall time").observe(dt)
+    obs.memory.update_gauges(obs.METRICS,
+                             shard_of=booster._gbdt.obs_shard_devices())
+
+
+def _write_snapshot(booster, callbacks, snapshot_dir, iteration: int,
+                    keep: int) -> None:
+    """Periodic snapshot (reference: gbdt.cpp:291-295 snapshot_freq),
+    crash-safe and rank-0-only (the reference wrote into CWD from every
+    process): atomic model text + state sidecar + manifest with keep-last-N
+    retention, written with backoff retries; a snapshot that still fails is
+    WARNED, training continues."""
+    es_state = None
+    for c in callbacks:
+        exp = getattr(c, "_es_export", None)
+        if exp is not None:
+            es_state = exp()
+    try:
+        # rank-uniform in practice: _gbdt is None on EVERY rank or none
+        # (boosters construct identically before the loop), and
+        # write_snapshot enters the same get_resume_state collective the
+        # elif arm does
+        # tpu-lint: disable=collective-divergence
+        if snap.is_writer_rank():
+            path = snap.write_snapshot(booster, snapshot_dir, iteration,
+                                       keep=keep, es_state=es_state)
+            log.info("Saved snapshot to %s", path)
+        elif booster._gbdt is not None:
+            # pod: get_resume_state allgathers sharded trainer state — a
+            # COLLECTIVE every rank must enter even though only the writer
+            # rank touches the disk
+            booster._gbdt.get_resume_state()
+    except Exception as e:
+        log.warning(f"snapshot at iteration {iteration} failed after "
+                    f"retries ({type(e).__name__}: {e}); "
+                    "training continues")
 
 
 def _check_eval_finite(results, policy: str, warned: set,

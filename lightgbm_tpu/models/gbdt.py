@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..config import Config
 from ..ops.gather import take_small
 from ..ops.grow import GrowParams, TreeArrays, grow_tree
@@ -760,7 +761,6 @@ class GBDT:
             else:
                 # guard budget exceeded at this row count: fall back to the
                 # unpacked kernels (bit-identical) and record the denial
-                from .. import obs
                 obs.emit("hist_pack_fallback", n_rows=n_rows,
                          reason="guard_budget", requested=mode,
                          const_hess=bool(gp.const_hess))
@@ -963,8 +963,10 @@ class GBDT:
             tree = tree._replace(
                 leaf_value=tree.leaf_value * shrink,
                 internal_value=tree.internal_value * shrink)
-            delta = take_rows(tree.leaf_value, leaf_id)
-            new_score = self._apply_tree_delta(new_score, delta, cls, titer)
+            with jax.named_scope("score_update"):
+                delta = take_rows(tree.leaf_value, leaf_id)
+                new_score = self._apply_tree_delta(new_score, delta, cls,
+                                                   titer)
             return tree, leaf_id, new_score, cegb_st
 
         return one_class
@@ -981,7 +983,8 @@ class GBDT:
                  shrink, qseed, titer, cegb_st, bins_t, aux):
             bt = bins_t if use_bt else None
             if not custom and fused_spec is None:
-                grad, hess = obj.get_gradients(score)
+                with jax.named_scope("front"):
+                    grad, hess = obj.get_gradients(score)
             # else fused front: the grower derives gradients from
             # (score, aux) in-register — the full-N g/h arrays are never
             # materialized (two HBM round-trips fewer per iteration)
@@ -1075,7 +1078,6 @@ class GBDT:
         host-timed probe of the SAME collective on the same mesh with the
         real histogram shape [3, F, max_bin] f32 — the cost model input for
         PERF_NOTES' psum-vs-allgather table."""
-        from .. import obs
         if not obs.enabled():
             return
         try:
@@ -1118,8 +1120,15 @@ class GBDT:
             # mismatch, so a custom-step executable never sees auto args
             from .. import prewarm as _prewarm
             handle, self._prewarm_handle = self._prewarm_handle, None
-            self._step_aot = _prewarm.adopt(handle, self, custom=custom)
+            with obs.span("prewarm_adopt"):
+                self._step_aot = _prewarm.adopt(handle, self, custom=custom)
             self._step_aot_custom = custom
+        # what the host pays between two steps: the arguments (four scalars
+        # placed on the device, each a dispatch of its own) and the call
+        with obs.span("step_dispatch"):
+            return self._dispatch_step(grad, hess, custom, key)
+
+    def _dispatch_step(self, grad, hess, custom: bool, key: str):
         ts = self.train_set
         n = ts.num_data
         if self._bag_mask is not None:
@@ -1201,7 +1210,6 @@ class GBDT:
                            "fault, and is not retried")
             if policy == "fatal" or not faults.is_device_fault(e):
                 raise
-            from .. import obs
             from ..utils.retry import call_with_backoff
             obs.emit("device_fault",
                      point=faults.classify_point(e, default="hist_allreduce"),
@@ -1256,13 +1264,11 @@ class GBDT:
                             is_leaf=lambda x: isinstance(x, jax.Array))
 
     def _obs_track_compiles(self, key: str, fn) -> None:
-        """Compile/retrace telemetry: poll the jitted step's executable-cache
-        size after dispatch — growth means trace+lower+compile happened (the
-        first call is the initial compile, any later growth is a retrace).
-        Pure host-side observation of an already-built jit wrapper; asserting
-        this counter stays flat is how tests prove telemetry adds no device
-        code."""
-        from .. import obs
+        """The ``compile`` event of the fused step: poll the jitted step's
+        executable-cache size after dispatch — growth means trace+lower+
+        compile happened. Pure host-side observation of an already-built jit
+        wrapper. Which programs are loaded where is the ``program_load``
+        event's to say (obs/__init__.py)."""
         if not obs.enabled():
             return
         try:
@@ -1276,12 +1282,6 @@ class GBDT:
         if cs > prev:
             seen[key] = cs
             obs.emit("compile", what="fused_step", key=key, cache_size=cs)
-            obs.METRICS.counter("jit_compiles",
-                                "programs traced+lowered", fn=key).inc(cs - prev)
-            if prev > 0:
-                obs.METRICS.counter("jit_retraces",
-                                    "cache growth after the first compile",
-                                    fn=key).inc(cs - prev)
 
     def _obs_note_lagged(self, it_no: int, cnts) -> None:
         """Consume one aged-out queue entry into the latest lagged per-tree
@@ -1290,7 +1290,6 @@ class GBDT:
         ≥8 iterations ago, so np.asarray here never blocks the pipeline."""
         gq = getattr(self, "_obs_gains", None)
         gains = gq.pop(it_no, None) if gq else None
-        from .. import obs
         if not obs.enabled():
             return
         try:
@@ -1330,7 +1329,6 @@ class GBDT:
                 log.warning(f"non-finite scores at iteration {self.iter_}; "
                             "discarding this iteration's tree(s) "
                             "(nonfinite_policy=warn_skip_tree)")
-                from .. import obs
                 obs.emit("nonfinite_guard", where="train_score",
                          policy=self._nf_policy, iteration=int(self.iter_),
                          action="skip_tree")
@@ -1353,51 +1351,51 @@ class GBDT:
                 self._update_valid_scores(tree_dev, cls,
                                           bias=self.init_scores[cls]
                                           if bias_active else 0.0)
-            # finished-check without stalling the pipeline: reading num_leaves
-            # of the *previous* iteration still blocks on that iteration's
-            # completion, which serializes every update into dispatch latency
-            # + device time. Instead queue the async copies and only force-read
-            # counts ≥8 iterations old (long since finished — zero blocking);
-            # stop detection lags ≤8 iters and trailing single-leaf trees are
-            # popped, matching the reference's stop-without-adding behavior
-            # (gbdt.cpp:430)
-            q = getattr(self, "_pending_leafcounts_q", None)
-            if q is None:
-                q = self._pending_leafcounts_q = []
-            cnts = [t.num_leaves for t, _ in trees]
-            for x in cnts:
-                try:
-                    x.copy_to_host_async()
-                except Exception:
-                    pass
-            # the finite flag rides the same lagged queue: zero added syncs
-            try:
-                ok.copy_to_host_async()
-            except Exception:
-                pass
-            from .. import obs
-            if obs.enabled():
-                # per-iteration split gains for telemetry ride the SAME lag
-                # discipline: async D2H copies now, host max at pop ≥8 iters
-                # later — a pure transfer, no new XLA program, no sync
-                gains = [t.split_gain for t, _ in trees]
-                for g in gains:
+            with obs.span("finished_check"):
+                # finished-check without stalling the pipeline: reading num_leaves
+                # of the *previous* iteration still blocks on that iteration's
+                # completion, which serializes every update into dispatch latency
+                # + device time. Instead queue the async copies and only force-read
+                # counts ≥8 iterations old (long since finished — zero blocking);
+                # stop detection lags ≤8 iters and trailing single-leaf trees are
+                # popped, matching the reference's stop-without-adding behavior
+                # (gbdt.cpp:430)
+                q = getattr(self, "_pending_leafcounts_q", None)
+                if q is None:
+                    q = self._pending_leafcounts_q = []
+                cnts = [t.num_leaves for t, _ in trees]
+                for x in cnts:
                     try:
-                        g.copy_to_host_async()
+                        x.copy_to_host_async()
                     except Exception:
                         pass
-                gq = getattr(self, "_obs_gains", None)
-                if gq is None:
-                    gq = self._obs_gains = {}
-                gq[self.iter_] = gains
-            q.append((self.iter_, cnts, ok))
-            if len(q) > 8:
-                it_old, old, okf = q.pop(0)
-                self._check_nf_flag(it_old, okf)
-                self._obs_note_lagged(it_old, old)
-                if all(int(x) <= 1 for x in old):
-                    self._pop_trailing_stumps()
-                    return True
+                # the finite flag rides the same lagged queue: zero added syncs
+                try:
+                    ok.copy_to_host_async()
+                except Exception:
+                    pass
+                if obs.enabled():
+                    # per-iteration split gains for telemetry ride the SAME lag
+                    # discipline: async D2H copies now, host max at pop ≥8 iters
+                    # later — a pure transfer, no new XLA program, no sync
+                    gains = [t.split_gain for t, _ in trees]
+                    for g in gains:
+                        try:
+                            g.copy_to_host_async()
+                        except Exception:
+                            pass
+                    gq = getattr(self, "_obs_gains", None)
+                    if gq is None:
+                        gq = self._obs_gains = {}
+                    gq[self.iter_] = gains
+                q.append((self.iter_, cnts, ok))
+                if len(q) > 8:
+                    it_old, old, okf = q.pop(0)
+                    self._check_nf_flag(it_old, okf)
+                    self._obs_note_lagged(it_old, old)
+                    if all(int(x) <= 1 for x in old):
+                        self._pop_trailing_stumps()
+                        return True
             # bound the in-flight dispatch queue (an unbounded run of
             # unsynced iterations queues hundreds of programs and their
             # buffers): a sync every 20th iteration keeps arbitrarily long
@@ -1450,7 +1448,6 @@ class GBDT:
         the flag is only forced once its device copy is long finished)."""
         if okf is None or bool(okf):
             return
-        from .. import obs
         obs.emit("nonfinite_guard", where="train_score",
                  policy=self._nf_policy, iteration=int(it_no))
         if self._nf_policy != "fatal":
@@ -1468,21 +1465,28 @@ class GBDT:
         """Route each valid set through the finished tree and fold the
         delta in via _apply_valid_delta (additive here; RF overrides with
         its running average)."""
+        if not self.valid_sets:
+            return
         max_steps = self.gp.num_leaves - 1 if self.gp.num_leaves > 1 else 1
-        if self._dp and self.valid_sets:
+        if self._dp:
             # the data-parallel step returns the tree replicated over the
             # mesh; validation sets are unsharded, so score them on ONE
             # device from its own replica (zero-copy) instead of on every
             # chip — where the Mosaic lookup below could not be partitioned
             tree_dev = jax.tree.map(lambda a: a.addressable_data(0), tree_dev)
-        for i, vs in enumerate(self.valid_sets):
-            leaf = P.route_bins(
-                tree_dev.split_feature, tree_dev.threshold_bin,
-                tree_dev.default_left, tree_dev.left_child, tree_dev.right_child,
-                tree_dev.num_leaves, vs.bins, vs.na_bin_dev, max_steps)
-            vdelta = take_small(tree_dev.leaf_value, leaf) - bias
-            self.valid_scores[i] = self._apply_valid_delta(
-                self.valid_scores[i], vdelta, cls)
+        # host span and device scope share the name; the walk and the lookup
+        # run as scoped programs, so nothing Booster.predict runs carries it
+        with obs.span("valid_score"):
+            for i, vs in enumerate(self.valid_sets):
+                leaf = P.route_bins(
+                    tree_dev.split_feature, tree_dev.threshold_bin,
+                    tree_dev.default_left, tree_dev.left_child,
+                    tree_dev.right_child, tree_dev.num_leaves, vs.bins,
+                    vs.na_bin_dev, max_steps, scope="valid_score")
+                vdelta = take_small(tree_dev.leaf_value, leaf,
+                                    scope="valid_score") - bias
+                self.valid_scores[i] = self._apply_valid_delta(
+                    self.valid_scores[i], vdelta, cls)
 
     def _apply_valid_delta(self, score, vdelta, cls: int):
         if self.num_tree_per_iteration == 1:
@@ -1606,17 +1610,7 @@ class GBDT:
             self.train_score = self.train_score + delta
         else:
             self.train_score = self.train_score.at[:, cls].add(delta)
-        max_steps = self.gp.num_leaves - 1 if self.gp.num_leaves > 1 else 1
-        for i, vs in enumerate(self.valid_sets):
-            leaf = P.route_bins(
-                tree_dev.split_feature, tree_dev.threshold_bin,
-                tree_dev.default_left, tree_dev.left_child, tree_dev.right_child,
-                tree_dev.num_leaves, vs.bins, vs.na_bin_dev, max_steps)
-            vdelta = take_small(tree_dev.leaf_value, leaf) - bias
-            if k == 1:
-                self.valid_scores[i] = self.valid_scores[i] + vdelta
-            else:
-                self.valid_scores[i] = self.valid_scores[i].at[:, cls].add(vdelta)
+        self._update_valid_scores(tree_dev, cls, bias)
 
     # ---- rollback (reference: GBDT::RollbackOneIter, gbdt.cpp:454) ----
     def rollback_one_iter(self) -> None:
@@ -1666,12 +1660,14 @@ class GBDT:
     # ---- evaluation (reference: GBDT::EvalAndCheckEarlyStopping, gbdt.cpp:472) ----
     def eval_one_set(self, name: str, score, data) -> List[Tuple[str, str, float, bool]]:
         out = []
-        conv = (self.objective.convert_output(score)
-                if self.objective is not None else score)
-        for m in self.metrics:
-            pred = conv if m.use_prob else score
-            val = m(data.label, pred, data.weight, data.group)
-            out.append((name, m.name, val, m.greater_is_better))
+        # one host span per evaluated set
+        with obs.span("metric"):
+            conv = (self.objective.convert_output(score)
+                    if self.objective is not None else score)
+            for m in self.metrics:
+                pred = conv if m.use_prob else score
+                val = m(data.label, pred, data.weight, data.group)
+                out.append((name, m.name, val, m.greater_is_better))
         return out
 
     def eval_train(self):
@@ -1733,7 +1729,6 @@ class GBDT:
         finite = bool(np.isfinite(grad).all() and np.isfinite(hess).all())
         if finite:
             return grad, hess, False
-        from .. import obs
         obs.emit("nonfinite_guard", where="custom_gradients",
                  policy=self._nf_policy, iteration=int(self.iter_))
         if self._nf_policy == "clip":

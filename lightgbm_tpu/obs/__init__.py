@@ -48,6 +48,7 @@ class _State:
         # configure call) start enabled; configure_from_config re-reads the
         # env anyway, so this is just the pre-configure default
         self.enabled = bool(_env_enabled())
+        self.listening = False      # the jax.monitoring listener is in place
         self.metrics_out = ""
         self.lock = threading.Lock()
 
@@ -66,6 +67,34 @@ def configure(enabled: Optional[bool] = None,
             _STATE.enabled = bool(enabled)
         if metrics_out is not None:
             _STATE.metrics_out = str(metrics_out)
+        if _STATE.enabled and not _STATE.listening:
+            # once per process (jax keeps listeners for good); silent while
+            # telemetry is off
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _STATE.listening = True
+
+
+_PROGRAM_LOAD_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_jax_duration(event: str, duration: float, **_kw: Any) -> None:
+    """One executable built, or read from the persistent cache: jax reports
+    both under this name, on the thread that asked for the program, so the
+    innermost span open there is the host code that paid for it."""
+    if event != _PROGRAM_LOAD_EVENT or not _STATE.enabled:
+        return
+    where = tracing.current_span() or "none"
+    fields: Dict[str, Any] = {"span": where, "duration_s": float(duration)}
+    it = tracing.current_iteration()
+    if it is not None:
+        it.programs_loaded += 1
+        fields["iteration"] = int(it.step)
+    emit("program_load", **fields)
+    METRICS.counter("programs_loaded",
+                    "executables built or read from the compile cache",
+                    span=where).inc()
 
 
 def configure_from_config(conf) -> None:

@@ -35,9 +35,22 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # best_gain come from the lagged async finished-check queue and therefore
     # describe iteration ``lagged_iteration`` (<= iteration), never the
     # current one — reading them synchronously would stall the device pipeline.
+    # ``spans``: self seconds of every span closed inside the iteration, by
+    # name (the span tree is in docs/OBSERVABILITY.md; a parent's entry is
+    # what its children leave, so the values add up to at most duration_s);
+    # ``programs_loaded``: program_load events of the iteration.
     "train_iter": ({"iteration": int, "duration_s": _NUM, "rows_per_s": _NUM},
                    {"leaf_count": int, "best_gain": _NUM,
-                    "lagged_iteration": int}),
+                    "lagged_iteration": int, "spans": dict,
+                    "programs_loaded": int}),
+    # a span closed outside any train_iter (dataset_construct, train_setup,
+    # finalize): the span record of a run that no profiler watches
+    "span": ({"name": str, "duration_s": _NUM}, {}),
+    # jax built an executable or read one from the persistent cache
+    # (backend_compile_duration); ``span`` is the innermost span open on the
+    # calling thread ("none" outside all), ``iteration`` the train_iter it
+    # fell into
+    "program_load": ({"span": str, "duration_s": _NUM}, {"iteration": int}),
     # a jitted program was built (host-side tracing/lowering observed via
     # the function's cache size; device code itself is unchanged)
     "compile": ({"what": str, "cache_size": int},

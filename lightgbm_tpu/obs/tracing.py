@@ -4,8 +4,12 @@ A :func:`span` scope feeds the same name to (1) the ``TIMER`` wall-clock
 registry (whose scopes already emit ``jax.profiler.TraceAnnotation`` ranges,
 so the name lines up in XLA profiler timelines), and (2) — when telemetry is
 enabled — a log2 latency histogram ``span_seconds{span=<name>}`` in the
-metrics registry.  Code that already sits inside a ``TIMER.scope`` keeps
-working unchanged; new call sites should prefer ``span``.
+metrics registry, and (3) the in-memory span record: each thread keeps the
+stack of its open spans, a span closed inside a ``train_iter`` adds its self
+time (its own seconds less its children's) to that iteration's ``spans``,
+and one closed outside any iteration emits a ``span`` event.
+:func:`current_span` is what a listener on the same thread (the
+``program_load`` counter in ``obs/__init__``) asks for the innermost name.
 
 Request tracing (serve path): :func:`mint_trace_id` stamps a process-unique
 id on each request at serve ingress; the MicroBatcher flush records the span
@@ -38,18 +42,71 @@ _xla_trace_lock = threading.Lock()
 _xla_trace_dir: Optional[str] = None
 
 
+_TL = threading.local()     # .stack: open spans, outermost first;
+                            # .iteration: the open train_iter's record
+
+
+class IterationSpans:
+    """What one ``train_iter`` span gathers for its event."""
+    __slots__ = ("step", "spans", "programs_loaded")
+
+    def __init__(self, step: int) -> None:
+        self.step = step
+        self.spans: Dict[str, float] = {}      # name -> self seconds
+        self.programs_loaded = 0
+
+
+def _stack() -> list:
+    st = getattr(_TL, "stack", None)
+    if st is None:
+        st = _TL.stack = []
+    return st
+
+
+def current_span() -> Optional[str]:
+    """Name of the innermost span open on this thread."""
+    st = getattr(_TL, "stack", None)
+    return st[-1][0] if st else None
+
+
+def current_iteration() -> Optional[IterationSpans]:
+    """The record of the ``train_iter`` span open on this thread."""
+    return getattr(_TL, "iteration", None)
+
+
 @contextlib.contextmanager
-def span(name: str, block_on=None):
-    """Timed scope: TIMER accumulation + TraceAnnotation + latency histogram
-    (histogram only when telemetry is on; the disabled path adds only a clock
-    read over a bare ``TIMER.scope``)."""
-    from . import enabled, METRICS
+def span(name: str, block_on=None, step_num=None):
+    """Timed scope: TIMER accumulation + TraceAnnotation + the thread's span
+    stack, and with telemetry on the latency histogram and the span record
+    (module docstring). ``step_num`` makes the scope one iteration of a loop:
+    a ``StepTraceAnnotation`` that yields the :class:`IterationSpans` its
+    children fill. The disabled path adds a clock read and two list
+    operations over a bare ``TIMER.scope``."""
+    from . import METRICS, emit, enabled
+    stack = _stack()
+    frame = [name, 0.0]                 # name, seconds of closed children
+    stack.append(frame)
+    outer = current_iteration()
+    if step_num is not None:
+        _TL.iteration = IterationSpans(step_num)
     t0 = time.perf_counter()
-    with TIMER.scope(name, block_on=block_on):
-        yield
-    if enabled():
-        METRICS.histogram("span_seconds", "span wall time by name",
-                          span=name).observe(time.perf_counter() - t0)
+    try:
+        with TIMER.scope(name, block_on=block_on, step_num=step_num):
+            yield _TL.iteration if step_num is not None else None
+    finally:
+        dt = time.perf_counter() - t0
+        del stack[stack.index(frame):]  # and whatever leaked above it
+        if stack:
+            stack[-1][1] += dt
+        if step_num is not None:
+            _TL.iteration = outer
+        if enabled():
+            METRICS.histogram("span_seconds", "span wall time by name",
+                              span=name).observe(dt)
+            if step_num is None and outer is not None:
+                outer.spans[name] = outer.spans.get(name, 0.0) + dt - frame[1]
+            elif step_num is None:      # an iteration's event is the loop's
+                emit("span", name=name, duration_s=dt)
 
 
 def record_span(name: str, seconds: float) -> None:

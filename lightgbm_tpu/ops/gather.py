@@ -12,10 +12,13 @@ import jax
 import jax.numpy as jnp
 
 
-def take_small(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """table [L] f32, idx [N] i32 -> [N] f32 (out-of-range -> 0)."""
+def take_small(table: jnp.ndarray, idx: jnp.ndarray,
+               scope: str = None) -> jnp.ndarray:
+    """table [L] f32, idx [N] i32 -> [N] f32 (out-of-range -> 0). ``scope``
+    names the device scope of an eager call (see take_small_pallas)."""
     if jax.default_backend() == "tpu" and table.ndim == 1 \
             and table.shape[0] <= 4096:
         from .pallas_hist import take_small_pallas
-        return take_small_pallas(table, idx).astype(table.dtype)
+        return take_small_pallas(table, idx,
+                                 scope=scope).astype(table.dtype)
     return jnp.take(table, idx, mode="fill", fill_value=0)

@@ -210,27 +210,30 @@ def _run_level_schedule(state, level, L, max_levels, n_unroll, MAX_SLOTS,
             groups.append([MAX_SLOTS, n_unroll, max_levels])
     last_sel = jnp.int32(1)
     for w, k0, k1 in groups:
-        if k1 - k0 == 1:
-            # single level at this width: cond and while_loop both trace the
-            # body exactly once; cond skips the carry plumbing
-            state, last_sel = jax.lax.cond(
-                (last_sel > 0) & (state.tree.num_leaves < L),
-                lambda st, _w=w, _k=k0: level(st, _w, jnp.int32(_k)),
-                lambda st: (st, jnp.int32(0)),
-                state)
-            continue
+        # the scope holds the loop construct too, so a trace reads the
+        # group's whole device time (guard, carry copies) under its width
+        with jax.named_scope(f"level_s{w}"):
+            if k1 - k0 == 1:
+                # single level at this width: cond and while_loop both trace
+                # the body exactly once; cond skips the carry plumbing
+                state, last_sel = jax.lax.cond(
+                    (last_sel > 0) & (state.tree.num_leaves < L),
+                    lambda st, _w=w, _k=k0: level(st, _w, jnp.int32(_k)),
+                    lambda st: (st, jnp.int32(0)),
+                    state)
+                continue
 
-        def cond(carry, _k1=k1):
-            st, lvl, last = carry
-            return (lvl < _k1) & (last > 0) & (st.tree.num_leaves < L)
+            def cond(carry, _k1=k1):
+                st, lvl, last = carry
+                return (lvl < _k1) & (last > 0) & (st.tree.num_leaves < L)
 
-        def body(carry, _w=w):
-            st, lvl, _ = carry
-            st2, num_sel = level(st, _w, lvl)
-            return st2, lvl + 1, num_sel
+            def body(carry, _w=w):
+                st, lvl, _ = carry
+                st2, num_sel = level(st, _w, lvl)
+                return st2, lvl + 1, num_sel
 
-        state, _, last_sel = jax.lax.while_loop(
-            cond, body, (state, jnp.int32(k0), last_sel))
+            state, _, last_sel = jax.lax.while_loop(
+                cond, body, (state, jnp.int32(k0), last_sel))
     return state
 
 
@@ -278,37 +281,38 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
         bins_T = None
     elif bins_T is None:
         bins_T = bins.T
-    if fused is not None:
-        # fused grad+quant+hist0 front: gradients recomputed in-register
-        # from (score, aux, bag), never materialized as [N] rows — the
-        # gradient write, two quantize reads and the root-histogram read
-        # collapse into one pass. Per-shard scales remain fine under
-        # data-parallel (histograms dequantize to f32 before the psum),
-        # exactly as with make_quant below.
-        assert gp.fused_obj is not None and gp.quant and cegb is None
-        f_score, f_aux, f_bag = fused
-        quant, hist0 = H.grad_quant_hist0(
-            bins, f_score, f_aux, f_bag, qseed, gp.fused_obj, B,
-            const_hess=gp.const_hess, impl=gp.hist_impl, bins_T=bins_T,
-            pack_k=gp.hist_packed)
-        hist0 = _hist_allreduce(hist0, gp, f_dim=1)
-    else:
-        # int8 quantized channels, built once per tree; per-shard scales are
-        # fine under data-parallel because every histogram is dequantized to
-        # f32 before the psum (each shard contributes real-valued mass)
-        quant = (H.make_quant(g, h, c, qseed, const_hess=gp.const_hess)
-                 if gp.quant else None)
-        # (The segment-packed level-pass experiment that used to live here is
-        # archived on branch `archive/packed-levels`: row compaction measured
-        # 10-24x slower on this runtime — per-level XLA gathers dominate. See
-        # docs/PERF_NOTES.md "negative results".)
-        hist0 = _hist_allreduce(
-            H.hist_leaf(bins, g, h, c, B, gp.hist_impl,
-                        bins_T=bins_T, quant=quant, pack_k=gp.hist_packed),
-            gp, f_dim=1)                                             # [3, F, B]
-    g0 = hist0[0, 0].sum()
-    h0 = hist0[1, 0].sum()
-    c0 = hist0[2, 0].sum()
+    with jax.named_scope("front"):
+        if fused is not None:
+            # fused grad+quant+hist0 front: gradients recomputed in-register
+            # from (score, aux, bag), never materialized as [N] rows — the
+            # gradient write, two quantize reads and the root-histogram read
+            # collapse into one pass. Per-shard scales remain fine under
+            # data-parallel (histograms dequantize to f32 before the psum),
+            # exactly as with make_quant below.
+            assert gp.fused_obj is not None and gp.quant and cegb is None
+            f_score, f_aux, f_bag = fused
+            quant, hist0 = H.grad_quant_hist0(
+                bins, f_score, f_aux, f_bag, qseed, gp.fused_obj, B,
+                const_hess=gp.const_hess, impl=gp.hist_impl, bins_T=bins_T,
+                pack_k=gp.hist_packed)
+            hist0 = _hist_allreduce(hist0, gp, f_dim=1)
+        else:
+            # int8 quantized channels, built once per tree; per-shard scales are
+            # fine under data-parallel because every histogram is dequantized to
+            # f32 before the psum (each shard contributes real-valued mass)
+            quant = (H.make_quant(g, h, c, qseed, const_hess=gp.const_hess)
+                     if gp.quant else None)
+            # (The segment-packed level-pass experiment that used to live here is
+            # archived on branch `archive/packed-levels`: row compaction measured
+            # 10-24x slower on this runtime — per-level XLA gathers dominate. See
+            # docs/PERF_NOTES.md "negative results".)
+            hist0 = _hist_allreduce(
+                H.hist_leaf(bins, g, h, c, B, gp.hist_impl,
+                            bins_T=bins_T, quant=quant, pack_k=gp.hist_packed),
+                gp, f_dim=1)                                             # [3, F, B]
+        g0 = hist0[0, 0].sum()
+        h0 = hist0[1, 0].sum()
+        c0 = hist0[2, 0].sum()
 
     if cegb is None:
         dummy_b = jnp.zeros(1, bool)
@@ -347,279 +351,283 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     leaves_iota = jnp.arange(L, dtype=jnp.int32)
 
     def level(st: _DWState, SLOTS: int, lvl):
-        # ---- per-node feature sampling (feature_fraction_bynode;
-        # reference samples per node, serial_tree_learner.cpp:397+ — here
-        # each frontier LEAF draws its own feature subset per level, keyed on
-        # (tree seed, level) so trees and levels decorrelate) ----
-        search_mask = feature_mask & st.vote_mask
-        if gp.ff_bynode < 1.0:
-            # Bernoulli(ff_bynode) keep within the CURRENTLY-USABLE set (the
-            # reference samples exactly k of the per-tree used features,
-            # serial_tree_learner.cpp:397+; a global top-k over all F columns
-            # would compound with feature_fraction and can zero out a leaf's
-            # search set). The best-u usable feature is always kept so no
-            # leaf ever searches nothing.
-            seed_base = qseed if qseed is not None else jnp.int32(0)
-            key = jax.random.fold_in(jax.random.PRNGKey(seed_base), lvl)
-            u = jax.random.uniform(key, (L, f))
-            u_allowed = jnp.where(search_mask, u, -1.0)
-            best = u_allowed >= u_allowed.max(axis=1, keepdims=True)
-            search_mask = search_mask & ((u < gp.ff_bynode) | best)
+        with jax.named_scope("split_search"):
+            # ---- per-node feature sampling (feature_fraction_bynode;
+            # reference samples per node, serial_tree_learner.cpp:397+ — here
+            # each frontier LEAF draws its own feature subset per level, keyed on
+            # (tree seed, level) so trees and levels decorrelate) ----
+            search_mask = feature_mask & st.vote_mask
+            if gp.ff_bynode < 1.0:
+                # Bernoulli(ff_bynode) keep within the CURRENTLY-USABLE set (the
+                # reference samples exactly k of the per-tree used features,
+                # serial_tree_learner.cpp:397+; a global top-k over all F columns
+                # would compound with feature_fraction and can zero out a leaf's
+                # search set). The best-u usable feature is always kept so no
+                # leaf ever searches nothing.
+                seed_base = qseed if qseed is not None else jnp.int32(0)
+                key = jax.random.fold_in(jax.random.PRNGKey(seed_base), lvl)
+                u = jax.random.uniform(key, (L, f))
+                u_allowed = jnp.where(search_mask, u, -1.0)
+                best = u_allowed >= u_allowed.max(axis=1, keepdims=True)
+                search_mask = search_mask & ((u < gp.ff_bynode) | best)
 
-        # ---- CEGB penalty plane (DetlaGain, cegb hpp:51-62): recomputed
-        # fresh each level from current bookkeeping, so a feature that became
-        # used at the previous level is already penalty-free here ----
-        pen = None
-        if cegb_on:
-            pen = jnp.broadcast_to(
-                jnp.float32(sp.cegb_tradeoff * sp.cegb_penalty_split)
-                * st.leaf_c[:, None], (L, f))
-            if sp.cegb_coupled:
-                pen = pen + sp.cegb_tradeoff * jnp.where(
-                    st.cegb.feature_used, 0.0, st.cegb.coupled_pen)[None, :]
-            if sp.cegb_lazy:
-                # on-demand cost: IN-BAG rows in the leaf that haven't paid
-                # for the feature yet (CalculateOndemandCosts iterates only
-                # the bagged partition — c is the in-bag channel)
-                fresh = jnp.where(st.cegb.data_used, 0.0,
-                                  st.cegb.lazy_pen[None, :])      # [N, F]
-                fresh = fresh * (c > 0)[:, None]
-                lazy_cost = _psum(
-                    jax.ops.segment_sum(fresh, st.leaf_id, num_segments=L), gp)
-                pen = pen + sp.cegb_tradeoff * lazy_cost
+            # ---- CEGB penalty plane (DetlaGain, cegb hpp:51-62): recomputed
+            # fresh each level from current bookkeeping, so a feature that became
+            # used at the previous level is already penalty-free here ----
+            pen = None
+            if cegb_on:
+                pen = jnp.broadcast_to(
+                    jnp.float32(sp.cegb_tradeoff * sp.cegb_penalty_split)
+                    * st.leaf_c[:, None], (L, f))
+                if sp.cegb_coupled:
+                    pen = pen + sp.cegb_tradeoff * jnp.where(
+                        st.cegb.feature_used, 0.0, st.cegb.coupled_pen)[None, :]
+                if sp.cegb_lazy:
+                    # on-demand cost: IN-BAG rows in the leaf that haven't paid
+                    # for the feature yet (CalculateOndemandCosts iterates only
+                    # the bagged partition — c is the in-bag channel)
+                    fresh = jnp.where(st.cegb.data_used, 0.0,
+                                      st.cegb.lazy_pen[None, :])      # [N, F]
+                    fresh = fresh * (c > 0)[:, None]
+                    lazy_cost = _psum(
+                        jax.ops.segment_sum(fresh, st.leaf_id, num_segments=L), gp)
+                    pen = pen + sp.cegb_tradeoff * lazy_cost
 
-        # ---- best split for every frontier leaf (one batched kernel) ----
-        if sp.extra_trees:
-            # one random threshold per (leaf, feature) per level, keyed on
-            # (extra_seed, tree seed, level) like the reference's per-search
-            # rand_threshold (feature_histogram.hpp:99-102)
-            et_base = qseed if qseed is not None else jnp.int32(0)
-            et_key = jax.random.fold_in(
-                jax.random.fold_in(jax.random.PRNGKey(sp.extra_seed),
-                                   et_base), lvl)
-        else:
-            et_key = None
-        res = best_split(st.hist, num_bins, na_bin, st.leaf_g, st.leaf_h,
-                         st.leaf_c, search_mask, sp, st.active,
-                         leaf_min=st.leaf_min, leaf_max=st.leaf_max,
-                         bundle=bundle, gain_penalty=pen, rand_key=et_key)
-        if forced is not None:
-            # ---- forced splits override the gain search (ForceSplits,
-            # serial_tree_learner.cpp:456-618): leaves holding a forced-node
-            # pointer split on that (feature, bin) unconditionally; left
-            # stats come from the leaf histogram's cumsum at the forced bin
-            fp = jnp.maximum(st.forced_ptr, 0)
-            has_f = (st.forced_ptr >= 0) & st.active
-            ffeat = forced.feat[fp]                         # [L]
-            fbin = forced.bin[fp]
-            iota_bf = jnp.arange(B, dtype=jnp.int32)[None, None, :]
-            na_self = iota_bf == na_bin[None, :, None]      # [1, F, B]
-            cumf = jnp.cumsum(jnp.where(na_self[:, None], 0.0, st.hist),
-                              axis=-1)                      # [L, 3, F, B]
-            lidx2 = jnp.arange(L)
-            flg = cumf[lidx2, 0, ffeat, fbin]
-            flh = cumf[lidx2, 1, ffeat, fbin]
-            flc = cumf[lidx2, 2, ffeat, fbin]
-            # validity: both sides non-empty, else stop forcing at this leaf
-            okf = has_f & (flc >= 1) & (st.leaf_c - flc >= 1)
-            big = jnp.float32(1e30)
-            res = res._replace(
-                gain=jnp.where(okf, big, res.gain),
-                feature=jnp.where(okf, ffeat, res.feature),
-                bin=jnp.where(okf, fbin, res.bin),
-                default_left=jnp.where(okf, False, res.default_left),
-                left_g=jnp.where(okf, flg, res.left_g),
-                left_h=jnp.where(okf, flh, res.left_h),
-                left_cnt=jnp.where(okf, flc, res.left_cnt),
-                is_cat=jnp.where(okf, False, res.is_cat),
-                cat_member=jnp.where(okf[:, None], False, res.cat_member))
+            # ---- best split for every frontier leaf (one batched kernel) ----
+            if sp.extra_trees:
+                # one random threshold per (leaf, feature) per level, keyed on
+                # (extra_seed, tree seed, level) like the reference's per-search
+                # rand_threshold (feature_histogram.hpp:99-102)
+                et_base = qseed if qseed is not None else jnp.int32(0)
+                et_key = jax.random.fold_in(
+                    jax.random.fold_in(jax.random.PRNGKey(sp.extra_seed),
+                                       et_base), lvl)
+            else:
+                et_key = None
+            res = best_split(st.hist, num_bins, na_bin, st.leaf_g, st.leaf_h,
+                             st.leaf_c, search_mask, sp, st.active,
+                             leaf_min=st.leaf_min, leaf_max=st.leaf_max,
+                             bundle=bundle, gain_penalty=pen, rand_key=et_key)
+            if forced is not None:
+                # ---- forced splits override the gain search (ForceSplits,
+                # serial_tree_learner.cpp:456-618): leaves holding a forced-node
+                # pointer split on that (feature, bin) unconditionally; left
+                # stats come from the leaf histogram's cumsum at the forced bin
+                fp = jnp.maximum(st.forced_ptr, 0)
+                has_f = (st.forced_ptr >= 0) & st.active
+                ffeat = forced.feat[fp]                         # [L]
+                fbin = forced.bin[fp]
+                iota_bf = jnp.arange(B, dtype=jnp.int32)[None, None, :]
+                na_self = iota_bf == na_bin[None, :, None]      # [1, F, B]
+                cumf = jnp.cumsum(jnp.where(na_self[:, None], 0.0, st.hist),
+                                  axis=-1)                      # [L, 3, F, B]
+                lidx2 = jnp.arange(L)
+                flg = cumf[lidx2, 0, ffeat, fbin]
+                flh = cumf[lidx2, 1, ffeat, fbin]
+                flc = cumf[lidx2, 2, ffeat, fbin]
+                # validity: both sides non-empty, else stop forcing at this leaf
+                okf = has_f & (flc >= 1) & (st.leaf_c - flc >= 1)
+                big = jnp.float32(1e30)
+                res = res._replace(
+                    gain=jnp.where(okf, big, res.gain),
+                    feature=jnp.where(okf, ffeat, res.feature),
+                    bin=jnp.where(okf, fbin, res.bin),
+                    default_left=jnp.where(okf, False, res.default_left),
+                    left_g=jnp.where(okf, flg, res.left_g),
+                    left_h=jnp.where(okf, flh, res.left_h),
+                    left_cnt=jnp.where(okf, flc, res.left_cnt),
+                    is_cat=jnp.where(okf, False, res.is_cat),
+                    cat_member=jnp.where(okf[:, None], False, res.cat_member))
 
-        # ---- budgeted selection (num_leaves cap): top-gain candidates win.
-        # rank by pairwise comparison count instead of argsort — an [L] sort
-        # on TPU costs milliseconds; the [L, L] compare matrix is microseconds
-        # in feature_contri mode res.gain is already the PENALIZED improvement
-        # with min_gain_to_split subtracted (split.py best_split) — gating it
-        # against min_gain again would apply the threshold twice
-        gain_gate = 0.0 if sp.has_contri \
-            else float(max(sp.min_gain_to_split, 0.0))
-        cand = st.active & (res.gain > gain_gate) & (res.gain > NEG_INF / 2)
-        budget = L - st.tree.num_leaves
-        key = jnp.where(cand, res.gain, -jnp.inf)
-        kj, ki = key[None, :], key[:, None]
-        better = (kj > ki) | ((kj == ki) & (leaves_iota[None, :] < leaves_iota[:, None]))
-        rank = jnp.sum(better, axis=1).astype(jnp.int32)   # stable desc rank
-        sel = cand & (rank < jnp.minimum(budget, SLOTS))
-        num_sel = sel.sum().astype(jnp.int32)
+            # ---- budgeted selection (num_leaves cap): top-gain candidates win.
+            # rank by pairwise comparison count instead of argsort — an [L] sort
+            # on TPU costs milliseconds; the [L, L] compare matrix is microseconds
+            # in feature_contri mode res.gain is already the PENALIZED improvement
+            # with min_gain_to_split subtracted (split.py best_split) — gating it
+            # against min_gain again would apply the threshold twice
+            gain_gate = 0.0 if sp.has_contri \
+                else float(max(sp.min_gain_to_split, 0.0))
+            cand = st.active & (res.gain > gain_gate) & (res.gain > NEG_INF / 2)
+            budget = L - st.tree.num_leaves
+            key = jnp.where(cand, res.gain, -jnp.inf)
+            kj, ki = key[None, :], key[:, None]
+            better = (kj > ki) | ((kj == ki) & (leaves_iota[None, :] < leaves_iota[:, None]))
+            rank = jnp.sum(better, axis=1).astype(jnp.int32)   # stable desc rank
+            sel = cand & (rank < jnp.minimum(budget, SLOTS))
+            num_sel = sel.sum().astype(jnp.int32)
 
-        # assignment order within the level: by leaf index
-        idx_in_lvl = (jnp.cumsum(sel.astype(jnp.int32)) - 1).astype(jnp.int32)
-        node_id = st.tree.num_leaves - 1 + idx_in_lvl      # node_cnt == n_leaves-1
-        new_leaf = st.tree.num_leaves + idx_in_lvl
+        with jax.named_scope("apply_level"):
+            # assignment order within the level: by leaf index
+            idx_in_lvl = (jnp.cumsum(sel.astype(jnp.int32)) - 1).astype(jnp.int32)
+            node_id = st.tree.num_leaves - 1 + idx_in_lvl      # node_cnt == n_leaves-1
+            new_leaf = st.tree.num_leaves + idx_in_lvl
 
-        feat, thr, dleft = res.feature, res.bin, res.default_left
-        lg, lh, lc = res.left_g, res.left_h, res.left_cnt
-        rg, rh, rc = st.leaf_g - lg, st.leaf_h - lh, st.leaf_c - lc
+            feat, thr, dleft = res.feature, res.bin, res.default_left
+            lg, lh, lc = res.left_g, res.left_h, res.left_cnt
+            rg, rh, rc = st.leaf_g - lg, st.leaf_h - lh, st.leaf_c - lc
 
-        # ---- tree arrays (masked scatters over node/leaf ids); outputs
-        # clamped by monotone bounds (CalculateSplittedLeafOutput with
-        # ConstraintEntry, feature_histogram.hpp:498) ----
-        w_l = leaf_output(lg, lh, sp)
-        w_r = leaf_output(rg, rh, sp)
-        w_p = leaf_output(st.leaf_g, st.leaf_h, sp)
-        if sp.has_monotone:
-            w_l = jnp.clip(w_l, st.leaf_min, st.leaf_max)
-            w_r = jnp.clip(w_r, st.leaf_min, st.leaf_max)
-            w_p = jnp.clip(w_p, st.leaf_min, st.leaf_max)
-        tr = _apply_level_to_tree(st.tree, st.parent_node, st.parent_right,
-                                  res, sel, node_id, new_leaf, leaves_iota,
-                                  lg, lh, lc, rg, rh, rc, w_l, w_r, w_p,
-                                  num_sel)
+            # ---- tree arrays (masked scatters over node/leaf ids); outputs
+            # clamped by monotone bounds (CalculateSplittedLeafOutput with
+            # ConstraintEntry, feature_histogram.hpp:498) ----
+            w_l = leaf_output(lg, lh, sp)
+            w_r = leaf_output(rg, rh, sp)
+            w_p = leaf_output(st.leaf_g, st.leaf_h, sp)
+            if sp.has_monotone:
+                w_l = jnp.clip(w_l, st.leaf_min, st.leaf_max)
+                w_r = jnp.clip(w_r, st.leaf_min, st.leaf_max)
+                w_p = jnp.clip(w_p, st.leaf_min, st.leaf_max)
+            tr = _apply_level_to_tree(st.tree, st.parent_node, st.parent_right,
+                                      res, sel, node_id, new_leaf, leaves_iota,
+                                      lg, lh, lc, rg, rh, rc, w_l, w_r, w_p,
+                                      num_sel)
 
-        # ---- CEGB bookkeeping (UpdateLeafBestSplits, cegb hpp:63-86):
-        # selected splits mark their feature model-used (coupled) and mark
-        # (row, feature) paid for every row in the split leaf (lazy) ----
-        cegb2 = st.cegb
-        if cegb_on and sp.cegb_coupled:
-            cegb2 = cegb2._replace(feature_used=_scatter_set(
-                cegb2.feature_used, feat, jnp.ones(L, bool), sel))
-        if cegb_on and sp.cegb_lazy:
-            feat_of_leaf = jnp.where(sel, feat, _OOB)
-            f_row = feat_of_leaf[st.leaf_id]                     # [N]
-            f_row = jnp.where(c > 0, f_row, _OOB)  # OOB rows never pay
-            cegb2 = cegb2._replace(data_used=cegb2.data_used.at[
-                jnp.arange(n), f_row].set(True, mode="drop"))
+            # ---- CEGB bookkeeping (UpdateLeafBestSplits, cegb hpp:63-86):
+            # selected splits mark their feature model-used (coupled) and mark
+            # (row, feature) paid for every row in the split leaf (lazy) ----
+            cegb2 = st.cegb
+            if cegb_on and sp.cegb_coupled:
+                cegb2 = cegb2._replace(feature_used=_scatter_set(
+                    cegb2.feature_used, feat, jnp.ones(L, bool), sel))
+            if cegb_on and sp.cegb_lazy:
+                feat_of_leaf = jnp.where(sel, feat, _OOB)
+                f_row = feat_of_leaf[st.leaf_id]                     # [N]
+                f_row = jnp.where(c > 0, f_row, _OOB)  # OOB rows never pay
+                cegb2 = cegb2._replace(data_used=cegb2.data_used.at[
+                    jnp.arange(n), f_row].set(True, mode="drop"))
 
-        # ---- fused route + child histogram pass ----
-        voting = bool(gp.axis_name) and gp.voting_top_k > 0
-        small_is_left = lc <= rc
-        leaf_of_slot = _scatter_set(jnp.full(SLOTS, _OOB, jnp.int32),
-                                    idx_in_lvl, leaves_iota, sel)
-        slot_used = leaf_of_slot < L
-        if voting:
-            # voting mode measures BOTH children fresh (no sibling
-            # subtraction): the next level's vote needs full local
-            # histograms of the whole frontier, and parent-derived entries
-            # would mix earlier elected sets (shard-divergent ->
-            # collective deadlock)
-            S_pass = 2 * SLOTS
-            slot_l_tab = jnp.where(sel, idx_in_lvl * 2, S_pass)
-            slot_r_tab = jnp.where(sel, idx_in_lvl * 2 + 1, S_pass)
-        else:
-            S_pass = SLOTS
-            # slot only for the smaller child; larger sibling = parent
-            # minus smaller
-            slot_l_tab = jnp.where(sel & small_is_left, idx_in_lvl, SLOTS)
-            slot_r_tab = jnp.where(sel & ~small_is_left, idx_in_lvl,
-                                   SLOTS)
-        tables = H.RouteTables(
-            feat=jnp.where(sel, feat, -1),
-            thr=thr,
-            dleft=dleft.astype(jnp.int32),
-            new_leaf=new_leaf,
-            slot_left=slot_l_tab,
-            slot_right=slot_r_tab,
-            is_cat=(res.is_cat & sel).astype(jnp.int32)
-            if (sp.cat_features or sp.has_bundles) else None,
-            member=(res.cat_member & sel[:, None]).astype(jnp.float32)
-            if (sp.cat_features or sp.has_bundles) else None,
-        )
-        hist_pass, leaf_id2 = H.hist_routed(
-            bins, g, h, c, st.leaf_id, tables, na_bin, S_pass, B,
-            gp.hist_impl, bins_T=bins_T, quant=quant, pack_k=gp.hist_packed)
-        if voting:
-            # ---- voting-parallel histogram exchange (PV-Tree; reference:
-            # VotingParallelTreeLearner GlobalVoting + CopyLocalHistogram,
-            # voting_parallel_tree_learner.cpp:170-366). Per-LEVEL election
-            # (the depthwise analog of the reference's per-leaf vote): each
-            # shard votes its local top-2k features by best local frontier
-            # gains, the tally is all-reduced, and only the top-k elected
-            # features' histograms are exchanged — compressing the per-level
-            # collective from F*B to k*B columns.
-            k = min(gp.voting_top_k, f)
-            k2 = min(2 * k, f)
-            lg_local = per_feature_gains(
-                hist_pass, num_bins, na_bin,
-                hist_pass[:, 0, 0].sum(-1), hist_pass[:, 1, 0].sum(-1),
-                hist_pass[:, 2, 0].sum(-1), sp)            # [S_pass, F]
-            score = jnp.where(lg_local > NEG_INF / 2, lg_local, 0.0).sum(0)
-            # local top-2k one-hot vote, tallied across shards
-            thresh2 = jax.lax.top_k(score, k2)[0][-1]
-            votes = (score >= thresh2).astype(jnp.float32)
-            votes = jax.lax.psum(votes, gp.axis_name)
-            # deterministic global election: top-k by (votes, score-sum)
-            global_score = jax.lax.psum(score, gp.axis_name)
-            elect_key = votes * 1e12 + global_score
-            elected = jax.lax.top_k(elect_key, k)[1]       # [k] feature ids
-            sub = jnp.take(hist_pass, elected, axis=2)     # [S_pass, 3, k, B]
-            sub = jax.lax.psum(sub, gp.axis_name)
-            elected_mask = jnp.zeros(f, bool).at[elected].set(True)
-            # non-elected entries must NOT keep local (shard-divergent)
-            # values: state feeds the replicated split selection and the loop
-            # predicates — divergence deadlocks the collectives. Zero them.
-            hist_pass = jnp.where(elected_mask[None, None, :, None],
-                                  hist_pass.at[:, :, elected, :].set(sub),
-                                  0.0)
-            # per-leaf coverage: only leaves whose stored histograms are
-            # REPLACED this level (split leaves + their new siblings) narrow
-            # to the new elected set; budget-deferred leaves keep the mask of
-            # the election their stored rows were measured under
-            em_rows = jnp.broadcast_to(elected_mask[None, :], (L, f))
-            vote_mask = _scatter_set(st.vote_mask, leaves_iota, em_rows, sel)
-            vote_mask = _scatter_set(vote_mask, new_leaf, em_rows, sel)
-        else:
-            hist_pass = _hist_allreduce(hist_pass, gp, f_dim=2)
-            vote_mask = None
+        with jax.named_scope("route_hist"):
+            # ---- fused route + child histogram pass ----
+            voting = bool(gp.axis_name) and gp.voting_top_k > 0
+            small_is_left = lc <= rc
+            leaf_of_slot = _scatter_set(jnp.full(SLOTS, _OOB, jnp.int32),
+                                        idx_in_lvl, leaves_iota, sel)
+            slot_used = leaf_of_slot < L
+            if voting:
+                # voting mode measures BOTH children fresh (no sibling
+                # subtraction): the next level's vote needs full local
+                # histograms of the whole frontier, and parent-derived entries
+                # would mix earlier elected sets (shard-divergent ->
+                # collective deadlock)
+                S_pass = 2 * SLOTS
+                slot_l_tab = jnp.where(sel, idx_in_lvl * 2, S_pass)
+                slot_r_tab = jnp.where(sel, idx_in_lvl * 2 + 1, S_pass)
+            else:
+                S_pass = SLOTS
+                # slot only for the smaller child; larger sibling = parent
+                # minus smaller
+                slot_l_tab = jnp.where(sel & small_is_left, idx_in_lvl, SLOTS)
+                slot_r_tab = jnp.where(sel & ~small_is_left, idx_in_lvl,
+                                       SLOTS)
+            tables = H.RouteTables(
+                feat=jnp.where(sel, feat, -1),
+                thr=thr,
+                dleft=dleft.astype(jnp.int32),
+                new_leaf=new_leaf,
+                slot_left=slot_l_tab,
+                slot_right=slot_r_tab,
+                is_cat=(res.is_cat & sel).astype(jnp.int32)
+                if (sp.cat_features or sp.has_bundles) else None,
+                member=(res.cat_member & sel[:, None]).astype(jnp.float32)
+                if (sp.cat_features or sp.has_bundles) else None,
+            )
+            hist_pass, leaf_id2 = H.hist_routed(
+                bins, g, h, c, st.leaf_id, tables, na_bin, S_pass, B,
+                gp.hist_impl, bins_T=bins_T, quant=quant, pack_k=gp.hist_packed)
+            if voting:
+                # ---- voting-parallel histogram exchange (PV-Tree; reference:
+                # VotingParallelTreeLearner GlobalVoting + CopyLocalHistogram,
+                # voting_parallel_tree_learner.cpp:170-366). Per-LEVEL election
+                # (the depthwise analog of the reference's per-leaf vote): each
+                # shard votes its local top-2k features by best local frontier
+                # gains, the tally is all-reduced, and only the top-k elected
+                # features' histograms are exchanged — compressing the per-level
+                # collective from F*B to k*B columns.
+                k = min(gp.voting_top_k, f)
+                k2 = min(2 * k, f)
+                lg_local = per_feature_gains(
+                    hist_pass, num_bins, na_bin,
+                    hist_pass[:, 0, 0].sum(-1), hist_pass[:, 1, 0].sum(-1),
+                    hist_pass[:, 2, 0].sum(-1), sp)            # [S_pass, F]
+                score = jnp.where(lg_local > NEG_INF / 2, lg_local, 0.0).sum(0)
+                # local top-2k one-hot vote, tallied across shards
+                thresh2 = jax.lax.top_k(score, k2)[0][-1]
+                votes = (score >= thresh2).astype(jnp.float32)
+                votes = jax.lax.psum(votes, gp.axis_name)
+                # deterministic global election: top-k by (votes, score-sum)
+                global_score = jax.lax.psum(score, gp.axis_name)
+                elect_key = votes * 1e12 + global_score
+                elected = jax.lax.top_k(elect_key, k)[1]       # [k] feature ids
+                sub = jnp.take(hist_pass, elected, axis=2)     # [S_pass, 3, k, B]
+                sub = jax.lax.psum(sub, gp.axis_name)
+                elected_mask = jnp.zeros(f, bool).at[elected].set(True)
+                # non-elected entries must NOT keep local (shard-divergent)
+                # values: state feeds the replicated split selection and the loop
+                # predicates — divergence deadlocks the collectives. Zero them.
+                hist_pass = jnp.where(elected_mask[None, None, :, None],
+                                      hist_pass.at[:, :, elected, :].set(sub),
+                                      0.0)
+                # per-leaf coverage: only leaves whose stored histograms are
+                # REPLACED this level (split leaves + their new siblings) narrow
+                # to the new elected set; budget-deferred leaves keep the mask of
+                # the election their stored rows were measured under
+                em_rows = jnp.broadcast_to(elected_mask[None, :], (L, f))
+                vote_mask = _scatter_set(st.vote_mask, leaves_iota, em_rows, sel)
+                vote_mask = _scatter_set(vote_mask, new_leaf, em_rows, sel)
+            else:
+                hist_pass = _hist_allreduce(hist_pass, gp, f_dim=2)
+                vote_mask = None
 
-        if voting:
-            hist_left = hist_pass[0::2][:SLOTS]
-            hist_right = hist_pass[1::2][:SLOTS]
-        else:
-            parent_hist = st.hist[jnp.minimum(leaf_of_slot, L - 1)]  # [SLOTS,..]
-            hist_sib = parent_hist - hist_pass
-            sl = small_is_left[jnp.minimum(leaf_of_slot, L - 1)][:, None, None, None]
-            hist_left = jnp.where(sl, hist_pass, hist_sib)
-            hist_right = jnp.where(sl, hist_sib, hist_pass)
-        new_leaf_of_slot = _scatter_set(jnp.full(SLOTS, _OOB, jnp.int32),
-                                        idx_in_lvl, new_leaf, sel)
-        hist2 = st.hist.at[jnp.where(slot_used, leaf_of_slot, _OOB)].set(
-            hist_left, mode="drop")
-        hist2 = hist2.at[jnp.where(slot_used, new_leaf_of_slot, _OOB)].set(
-            hist_right, mode="drop")
+            if voting:
+                hist_left = hist_pass[0::2][:SLOTS]
+                hist_right = hist_pass[1::2][:SLOTS]
+            else:
+                parent_hist = st.hist[jnp.minimum(leaf_of_slot, L - 1)]  # [SLOTS,..]
+                hist_sib = parent_hist - hist_pass
+                sl = small_is_left[jnp.minimum(leaf_of_slot, L - 1)][:, None, None, None]
+                hist_left = jnp.where(sl, hist_pass, hist_sib)
+                hist_right = jnp.where(sl, hist_sib, hist_pass)
+            new_leaf_of_slot = _scatter_set(jnp.full(SLOTS, _OOB, jnp.int32),
+                                            idx_in_lvl, new_leaf, sel)
+            hist2 = st.hist.at[jnp.where(slot_used, leaf_of_slot, _OOB)].set(
+                hist_left, mode="drop")
+            hist2 = hist2.at[jnp.where(slot_used, new_leaf_of_slot, _OOB)].set(
+                hist_right, mode="drop")
 
-        # ---- monotone bound propagation (LeafConstraints::UpdateConstraints,
-        # monotone_constraints.hpp:44-58): children inherit the parent entry;
-        # a split on a monotone feature pins the midpoint between them ----
-        if sp.has_monotone:
-            leaf_min2, leaf_max2 = _monotone_child_bounds(
-                sp, f, res, feat, sel, w_l, w_r, st.leaf_min, st.leaf_max,
-                leaves_iota, new_leaf)
-        else:
-            leaf_min2, leaf_max2 = st.leaf_min, st.leaf_max
+        with jax.named_scope("apply_level"):
+            # ---- monotone bound propagation (LeafConstraints::UpdateConstraints,
+            # monotone_constraints.hpp:44-58): children inherit the parent entry;
+            # a split on a monotone feature pins the midpoint between them ----
+            if sp.has_monotone:
+                leaf_min2, leaf_max2 = _monotone_child_bounds(
+                    sp, f, res, feat, sel, w_l, w_r, st.leaf_min, st.leaf_max,
+                    leaves_iota, new_leaf)
+            else:
+                leaf_min2, leaf_max2 = st.leaf_min, st.leaf_max
 
-        # ---- per-leaf stats / frontier update ----
-        leaf_g2 = _scatter_set(_scatter_set(st.leaf_g, leaves_iota, lg, sel),
-                               new_leaf, rg, sel)
-        leaf_h2 = _scatter_set(_scatter_set(st.leaf_h, leaves_iota, lh, sel),
-                               new_leaf, rh, sel)
-        leaf_c2 = _scatter_set(_scatter_set(st.leaf_c, leaves_iota, lc, sel),
-                               new_leaf, rc, sel)
-        active2 = _scatter_set(sel, new_leaf, jnp.ones(L, bool), sel)
-        pn2 = _scatter_set(_scatter_set(st.parent_node, leaves_iota, node_id, sel),
-                           new_leaf, node_id, sel)
-        pr2 = _scatter_set(
-            _scatter_set(st.parent_right, leaves_iota, jnp.zeros(L, bool), sel),
-            new_leaf, jnp.ones(L, bool), sel)
+            # ---- per-leaf stats / frontier update ----
+            leaf_g2 = _scatter_set(_scatter_set(st.leaf_g, leaves_iota, lg, sel),
+                                   new_leaf, rg, sel)
+            leaf_h2 = _scatter_set(_scatter_set(st.leaf_h, leaves_iota, lh, sel),
+                                   new_leaf, rh, sel)
+            leaf_c2 = _scatter_set(_scatter_set(st.leaf_c, leaves_iota, lc, sel),
+                                   new_leaf, rc, sel)
+            active2 = _scatter_set(sel, new_leaf, jnp.ones(L, bool), sel)
+            pn2 = _scatter_set(_scatter_set(st.parent_node, leaves_iota, node_id, sel),
+                               new_leaf, node_id, sel)
+            pr2 = _scatter_set(
+                _scatter_set(st.parent_right, leaves_iota, jnp.zeros(L, bool), sel),
+                new_leaf, jnp.ones(L, bool), sel)
 
-        if forced is not None:
-            fl = forced.left[fp]
-            fr = forced.right[fp]
-            fp_next = jnp.where(okf, fl, -1)
-            fptr2 = _scatter_set(
-                _scatter_set(st.forced_ptr, leaves_iota,
-                             jnp.where(sel, fp_next, st.forced_ptr), sel),
-                new_leaf, jnp.where(okf, fr, -1), sel)
-        else:
-            fptr2 = st.forced_ptr
+            if forced is not None:
+                fl = forced.left[fp]
+                fr = forced.right[fp]
+                fp_next = jnp.where(okf, fl, -1)
+                fptr2 = _scatter_set(
+                    _scatter_set(st.forced_ptr, leaves_iota,
+                                 jnp.where(sel, fp_next, st.forced_ptr), sel),
+                    new_leaf, jnp.where(okf, fr, -1), sel)
+            else:
+                fptr2 = st.forced_ptr
         return _DWState(
             leaf_id=leaf_id2,
             forced_ptr=fptr2,
@@ -660,36 +668,37 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
         # leaf renewal from EXACT sums (quantized-training paper: splits
         # tolerate int8 gains, leaf outputs should not; reference analog:
         # exact LeafSplits aggregates, leaf_splits.hpp:20)
-        from .pallas_hist import leaf_sums_grad_pallas, leaf_sums_pallas
-        # interpret only where Mosaic can't compile (CPU backend) — keying on
-        # hist_impl would run the interpreter inside the jitted tree on TPU
-        interp = jax.default_backend() == "cpu"
-        if fused is not None and use_pallas:
-            sums = _psum(leaf_sums_grad_pallas(f_score, f_aux, f_bag,
-                                               state.leaf_id, gp.fused_obj,
-                                               L, interpret=interp), gp)
-        elif fused is not None:
-            # XLA fallback: rebuild the exact rows the unfused path would
-            # have passed in (bit-identical f32 ops, see _grad_rows)
-            from .pallas_hist import _grad_rows
-            fg_, fh_ = _grad_rows(gp.fused_obj, f_score, f_aux)
-            sums = _psum(leaf_sums_pallas(fg_ * f_bag, fh_ * f_bag,
-                                          (f_bag > 0).astype(jnp.float32),
-                                          state.leaf_id, L,
-                                          interpret=interp), gp)
-        else:
-            sums = _psum(leaf_sums_pallas(g, h, c, state.leaf_id, L,
-                                          interpret=interp), gp)
-        eg, eh, ec = sums[0], sums[1], sums[2]
-        w = leaf_output(eg, eh, sp)
-        if sp.has_monotone:
-            w = jnp.clip(w, state.leaf_min, state.leaf_max)
-        tr = state.tree
-        live = jnp.arange(L) < tr.num_leaves
-        state = state._replace(tree=tr._replace(
-            leaf_value=jnp.where(live, w, tr.leaf_value),
-            leaf_weight=jnp.where(live, eh, tr.leaf_weight),
-            leaf_count=jnp.where(live, ec, tr.leaf_count)))
+        with jax.named_scope("leaf_renew"):
+            from .pallas_hist import leaf_sums_grad_pallas, leaf_sums_pallas
+            # interpret only where Mosaic can't compile (CPU backend) — keying on
+            # hist_impl would run the interpreter inside the jitted tree on TPU
+            interp = jax.default_backend() == "cpu"
+            if fused is not None and use_pallas:
+                sums = _psum(leaf_sums_grad_pallas(f_score, f_aux, f_bag,
+                                                   state.leaf_id, gp.fused_obj,
+                                                   L, interpret=interp), gp)
+            elif fused is not None:
+                # XLA fallback: rebuild the exact rows the unfused path would
+                # have passed in (bit-identical f32 ops, see _grad_rows)
+                from .pallas_hist import _grad_rows
+                fg_, fh_ = _grad_rows(gp.fused_obj, f_score, f_aux)
+                sums = _psum(leaf_sums_pallas(fg_ * f_bag, fh_ * f_bag,
+                                              (f_bag > 0).astype(jnp.float32),
+                                              state.leaf_id, L,
+                                              interpret=interp), gp)
+            else:
+                sums = _psum(leaf_sums_pallas(g, h, c, state.leaf_id, L,
+                                              interpret=interp), gp)
+            eg, eh, ec = sums[0], sums[1], sums[2]
+            w = leaf_output(eg, eh, sp)
+            if sp.has_monotone:
+                w = jnp.clip(w, state.leaf_min, state.leaf_max)
+            tr = state.tree
+            live = jnp.arange(L) < tr.num_leaves
+            state = state._replace(tree=tr._replace(
+                leaf_value=jnp.where(live, w, tr.leaf_value),
+                leaf_weight=jnp.where(live, eh, tr.leaf_weight),
+                leaf_count=jnp.where(live, ec, tr.leaf_count)))
     if cegb_on:
         return state.tree, state.leaf_id, state.cegb
     return state.tree, state.leaf_id
@@ -799,12 +808,13 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
     # quantization mirrors hist_routed exactly (histogram.py:433-436): the
     # q8 kernel on the pallas path, per-row dequantized channels elsewhere —
     # so lean and default growers see the SAME histogram numbers per impl
-    quant = (H.make_quant(g, h, c, qseed, const_hess=gp.const_hess)
-             if gp.quant else None)
-    if quant is not None and not use_pallas:
-        gm, hm, cm = H.dequant_rows(quant)
-    else:
-        gm, hm, cm = g, h, c
+    with jax.named_scope("front"):
+        quant = (H.make_quant(g, h, c, qseed, const_hess=gp.const_hess)
+                 if gp.quant else None)
+        if quant is not None and not use_pallas:
+            gm, hm, cm = H.dequant_rows(quant)
+        else:
+            gm, hm, cm = g, h, c
     interp = jax.default_backend() == "cpu"
 
     def measure_tile(slot, n_slots, lo, hi):
@@ -828,31 +838,34 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
         best = None
         for t in range(n_tiles):
             lo, hi = t * ft, min(f, (t + 1) * ft)
-            hist_t = measure_tile(slot, n_slots, lo, hi)
-            res_t = best_split(hist_t, num_bins[lo:hi], na_bin[lo:hi],
-                               sg, sh, sc, feature_mask[lo:hi],
-                               _tile_split_params(sp, lo, hi), allow,
-                               leaf_min=lmin, leaf_max=lmax,
-                               bundle=_slice_bundle(bundle, lo, hi))
-            res_t = res_t._replace(
-                feature=res_t.feature + jnp.int32(lo))
-            best = res_t if best is None else _fold_best(best, res_t)
+            with jax.named_scope("route_hist"):
+                hist_t = measure_tile(slot, n_slots, lo, hi)
+            with jax.named_scope("split_search"):
+                res_t = best_split(hist_t, num_bins[lo:hi], na_bin[lo:hi],
+                                   sg, sh, sc, feature_mask[lo:hi],
+                                   _tile_split_params(sp, lo, hi), allow,
+                                   leaf_min=lmin, leaf_max=lmax,
+                                   bundle=_slice_bundle(bundle, lo, hi))
+                res_t = res_t._replace(
+                    feature=res_t.feature + jnp.int32(lo))
+                best = res_t if best is None else _fold_best(best, res_t)
         return best
 
     # ---- root ----
-    zeros_slot = jnp.zeros(n, jnp.int32)
-    # root stats from one tiny exact pass (leaf renewal needs them anyway)
-    from .pallas_hist import leaf_sums_pallas
-    if use_pallas:
-        sums0 = _psum(leaf_sums_pallas(g, h, c, zeros_slot, 1,
-                                       interpret=interp), gp)
-        g0, h0, c0 = sums0[0, 0], sums0[1, 0], sums0[2, 0]
-    else:
-        g0, h0, c0 = (_psum(g.sum(), gp), _psum(h.sum(), gp),
-                      _psum(c.sum(), gp))
-    rec0 = tiled_search(zeros_slot, 1, g0[None], h0[None], c0[None],
-                        jnp.ones(1, bool), jnp.full(1, -jnp.inf),
-                        jnp.full(1, jnp.inf))
+    with jax.named_scope("front"):
+        zeros_slot = jnp.zeros(n, jnp.int32)
+        # root stats from one tiny exact pass (leaf renewal needs them anyway)
+        from .pallas_hist import leaf_sums_pallas
+        if use_pallas:
+            sums0 = _psum(leaf_sums_pallas(g, h, c, zeros_slot, 1,
+                                           interpret=interp), gp)
+            g0, h0, c0 = sums0[0, 0], sums0[1, 0], sums0[2, 0]
+        else:
+            g0, h0, c0 = (_psum(g.sum(), gp), _psum(h.sum(), gp),
+                          _psum(c.sum(), gp))
+        rec0 = tiled_search(zeros_slot, 1, g0[None], h0[None], c0[None],
+                            jnp.ones(1, bool), jnp.full(1, -jnp.inf),
+                            jnp.full(1, jnp.inf))
 
     def pad_rec(r1):
         """[1]-shaped root record -> [L] record arrays."""
@@ -886,86 +899,90 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
     leaves_iota = jnp.arange(L, dtype=jnp.int32)
 
     def level(st: _LeanState, SLOTS: int, lvl):
-        res = st.rec
-        gain_gate = 0.0 if sp.has_contri \
-            else float(max(sp.min_gain_to_split, 0.0))
-        cand = st.active & (res.gain > gain_gate) & (res.gain > NEG_INF / 2)
-        budget = L - st.tree.num_leaves
-        key = jnp.where(cand, res.gain, -jnp.inf)
-        kj, ki = key[None, :], key[:, None]
-        better = (kj > ki) | ((kj == ki)
-                              & (leaves_iota[None, :] < leaves_iota[:, None]))
-        rank = jnp.sum(better, axis=1).astype(jnp.int32)
-        sel = cand & (rank < jnp.minimum(budget, SLOTS))
-        num_sel = sel.sum().astype(jnp.int32)
+        with jax.named_scope("split_search"):
+            res = st.rec
+            gain_gate = 0.0 if sp.has_contri \
+                else float(max(sp.min_gain_to_split, 0.0))
+            cand = st.active & (res.gain > gain_gate) & (res.gain > NEG_INF / 2)
+            budget = L - st.tree.num_leaves
+            key = jnp.where(cand, res.gain, -jnp.inf)
+            kj, ki = key[None, :], key[:, None]
+            better = (kj > ki) | ((kj == ki)
+                                  & (leaves_iota[None, :] < leaves_iota[:, None]))
+            rank = jnp.sum(better, axis=1).astype(jnp.int32)
+            sel = cand & (rank < jnp.minimum(budget, SLOTS))
+            num_sel = sel.sum().astype(jnp.int32)
 
-        idx_in_lvl = (jnp.cumsum(sel.astype(jnp.int32)) - 1).astype(jnp.int32)
-        node_id = st.tree.num_leaves - 1 + idx_in_lvl
-        new_leaf = st.tree.num_leaves + idx_in_lvl
+        with jax.named_scope("apply_level"):
+            idx_in_lvl = (jnp.cumsum(sel.astype(jnp.int32)) - 1).astype(jnp.int32)
+            node_id = st.tree.num_leaves - 1 + idx_in_lvl
+            new_leaf = st.tree.num_leaves + idx_in_lvl
 
-        feat, thr, dleft = res.feature, res.bin, res.default_left
-        lg, lh, lc = res.left_g, res.left_h, res.left_cnt
-        rg, rh, rc = st.leaf_g - lg, st.leaf_h - lh, st.leaf_c - lc
+            feat, thr, dleft = res.feature, res.bin, res.default_left
+            lg, lh, lc = res.left_g, res.left_h, res.left_cnt
+            rg, rh, rc = st.leaf_g - lg, st.leaf_h - lh, st.leaf_c - lc
 
-        # ---- tree arrays (shared scatter helper) ----
-        w_l = leaf_output(lg, lh, sp)
-        w_r = leaf_output(rg, rh, sp)
-        w_p = leaf_output(st.leaf_g, st.leaf_h, sp)
-        if sp.has_monotone:
-            w_l = jnp.clip(w_l, st.leaf_min, st.leaf_max)
-            w_r = jnp.clip(w_r, st.leaf_min, st.leaf_max)
-            w_p = jnp.clip(w_p, st.leaf_min, st.leaf_max)
-        tr = _apply_level_to_tree(st.tree, st.parent_node, st.parent_right,
-                                  res, sel, node_id, new_leaf, leaves_iota,
-                                  lg, lh, lc, rg, rh, rc, w_l, w_r, w_p,
-                                  num_sel)
+            # ---- tree arrays (shared scatter helper) ----
+            w_l = leaf_output(lg, lh, sp)
+            w_r = leaf_output(rg, rh, sp)
+            w_p = leaf_output(st.leaf_g, st.leaf_h, sp)
+            if sp.has_monotone:
+                w_l = jnp.clip(w_l, st.leaf_min, st.leaf_max)
+                w_r = jnp.clip(w_r, st.leaf_min, st.leaf_max)
+                w_p = jnp.clip(w_p, st.leaf_min, st.leaf_max)
+            tr = _apply_level_to_tree(st.tree, st.parent_node, st.parent_right,
+                                      res, sel, node_id, new_leaf, leaves_iota,
+                                      lg, lh, lc, rg, rh, rc, w_l, w_r, w_p,
+                                      num_sel)
 
-        # ---- route: BOTH children measured (slots 2i / 2i+1) ----
-        S_pass = 2 * SLOTS
-        tables = H.RouteTables(
-            feat=jnp.where(sel, feat, -1),
-            thr=thr,
-            dleft=dleft.astype(jnp.int32),
-            new_leaf=new_leaf,
-            slot_left=jnp.where(sel, idx_in_lvl * 2, S_pass),
-            slot_right=jnp.where(sel, idx_in_lvl * 2 + 1, S_pass),
-            is_cat=(res.is_cat & sel).astype(jnp.int32)
-            if (sp.cat_features or sp.has_bundles) else None,
-            member=(res.cat_member & sel[:, None]).astype(jnp.float32)
-            if (sp.cat_features or sp.has_bundles) else None,
-        )
-        if use_pallas and f <= 512:
-            from .pallas_hist import route_level_pallas
-            slot, leaf_id2 = route_level_pallas(bins_T, st.leaf_id, tables,
-                                                na_bin, S_pass, L,
-                                                interpret=interp)
-        else:
-            slot, leaf_id2 = H.route_level(bins, st.leaf_id, tables, na_bin,
-                                           S_pass)
+        with jax.named_scope("route_hist"):
+            # ---- route: BOTH children measured (slots 2i / 2i+1) ----
+            S_pass = 2 * SLOTS
+            tables = H.RouteTables(
+                feat=jnp.where(sel, feat, -1),
+                thr=thr,
+                dleft=dleft.astype(jnp.int32),
+                new_leaf=new_leaf,
+                slot_left=jnp.where(sel, idx_in_lvl * 2, S_pass),
+                slot_right=jnp.where(sel, idx_in_lvl * 2 + 1, S_pass),
+                is_cat=(res.is_cat & sel).astype(jnp.int32)
+                if (sp.cat_features or sp.has_bundles) else None,
+                member=(res.cat_member & sel[:, None]).astype(jnp.float32)
+                if (sp.cat_features or sp.has_bundles) else None,
+            )
+            if use_pallas and f <= 512:
+                from .pallas_hist import route_level_pallas
+                slot, leaf_id2 = route_level_pallas(bins_T, st.leaf_id, tables,
+                                                    na_bin, S_pass, L,
+                                                    interpret=interp)
+            else:
+                slot, leaf_id2 = H.route_level(bins, st.leaf_id, tables, na_bin,
+                                               S_pass)
 
-        # ---- monotone bound propagation (shared helper) ----
-        if sp.has_monotone:
-            leaf_min2, leaf_max2 = _monotone_child_bounds(
-                sp, f, res, feat, sel, w_l, w_r, st.leaf_min, st.leaf_max,
-                leaves_iota, new_leaf)
-        else:
-            leaf_min2, leaf_max2 = st.leaf_min, st.leaf_max
+        with jax.named_scope("apply_level"):
+            # ---- monotone bound propagation (shared helper) ----
+            if sp.has_monotone:
+                leaf_min2, leaf_max2 = _monotone_child_bounds(
+                    sp, f, res, feat, sel, w_l, w_r, st.leaf_min, st.leaf_max,
+                    leaves_iota, new_leaf)
+            else:
+                leaf_min2, leaf_max2 = st.leaf_min, st.leaf_max
 
-        # ---- per-leaf stats / frontier update ----
-        leaf_g2 = _scatter_set(_scatter_set(st.leaf_g, leaves_iota, lg, sel),
-                               new_leaf, rg, sel)
-        leaf_h2 = _scatter_set(_scatter_set(st.leaf_h, leaves_iota, lh, sel),
-                               new_leaf, rh, sel)
-        leaf_c2 = _scatter_set(_scatter_set(st.leaf_c, leaves_iota, lc, sel),
-                               new_leaf, rc, sel)
-        active2 = _scatter_set(sel, new_leaf, jnp.ones(L, bool), sel)
-        pn2 = _scatter_set(
-            _scatter_set(st.parent_node, leaves_iota, node_id, sel),
-            new_leaf, node_id, sel)
-        pr2 = _scatter_set(
-            _scatter_set(st.parent_right, leaves_iota,
-                         jnp.zeros(L, bool), sel),
-            new_leaf, jnp.ones(L, bool), sel)
+            # ---- per-leaf stats / frontier update ----
+            leaf_g2 = _scatter_set(_scatter_set(st.leaf_g, leaves_iota, lg, sel),
+                                   new_leaf, rg, sel)
+            leaf_h2 = _scatter_set(_scatter_set(st.leaf_h, leaves_iota, lh, sel),
+                                   new_leaf, rh, sel)
+            leaf_c2 = _scatter_set(_scatter_set(st.leaf_c, leaves_iota, lc, sel),
+                                   new_leaf, rc, sel)
+            active2 = _scatter_set(sel, new_leaf, jnp.ones(L, bool), sel)
+            pn2 = _scatter_set(
+                _scatter_set(st.parent_node, leaves_iota, node_id, sel),
+                new_leaf, node_id, sel)
+            pr2 = _scatter_set(
+                _scatter_set(st.parent_right, leaves_iota,
+                             jnp.zeros(L, bool), sel),
+                new_leaf, jnp.ones(L, bool), sel)
 
         # ---- fresh records for the 2S children (feature-tiled search) ----
         # per-slot stats: slot 2i = left child of split i, 2i+1 = right
@@ -1006,17 +1023,18 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
                                 MAX_SLOTS, slot_floor)
 
     if gp.quant:
-        # leaf renewal from EXACT sums (same epilogue as the default grower)
-        sums = _psum(leaf_sums_pallas(g, h, c, state.leaf_id, L,
-                                      interpret=interp), gp)
-        eg, eh, ec = sums[0], sums[1], sums[2]
-        w = leaf_output(eg, eh, sp)
-        if sp.has_monotone:
-            w = jnp.clip(w, state.leaf_min, state.leaf_max)
-        tr = state.tree
-        live = jnp.arange(L) < tr.num_leaves
-        state = state._replace(tree=tr._replace(
-            leaf_value=jnp.where(live, w, tr.leaf_value),
-            leaf_weight=jnp.where(live, eh, tr.leaf_weight),
-            leaf_count=jnp.where(live, ec, tr.leaf_count)))
+        with jax.named_scope("leaf_renew"):
+            # leaf renewal from EXACT sums (same epilogue as the default grower)
+            sums = _psum(leaf_sums_pallas(g, h, c, state.leaf_id, L,
+                                          interpret=interp), gp)
+            eg, eh, ec = sums[0], sums[1], sums[2]
+            w = leaf_output(eg, eh, sp)
+            if sp.has_monotone:
+                w = jnp.clip(w, state.leaf_min, state.leaf_max)
+            tr = state.tree
+            live = jnp.arange(L) < tr.num_leaves
+            state = state._replace(tree=tr._replace(
+                leaf_value=jnp.where(live, w, tr.leaf_value),
+                leaf_weight=jnp.where(live, eh, tr.leaf_weight),
+                leaf_count=jnp.where(live, ec, tr.leaf_count)))
     return state.tree, state.leaf_id

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.timer import scoped_jit
+
 _CHUNK = 1024          # rows per grid step (onehot block [F*B, C] bf16 ~3.7MB)
 # int8 kernel takes bigger chunks: the onehot block is half the bytes of the
 # bf16 one, and the SWAR one-hot (r5) freed enough VMEM that 4096 fits even
@@ -142,6 +144,7 @@ def hist_pallas(bins_T: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     kern = functools.partial(_kernel, fg=fg, b=b, s=s, chunk=chunk)
     out = pl.pallas_call(
         kern,
+        name="hist_leaf",
         grid=(n_fg, n_chunks),
         in_specs=[
             pl.BlockSpec((fg, chunk), lambda j, i: (j, i),
@@ -420,6 +423,7 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
                              pack_k=pack_k)
     out = pl.pallas_call(
         kern,
+        name="hist_leaf_q8",
         grid=(n_fg, n_chunks),
         in_specs=[
             pl.BlockSpec((fg, chunk), lambda j, i: (j, i),
@@ -647,6 +651,7 @@ def hist_routed_fused_multi_q8(bins_T, gq, hq, cq, leaf_id, tables_seq,
                              swar=_swar_ok(b, interpret), d=d, pack_k=pack_k)
     out, lid2 = pl.pallas_call(
         kern,
+        name="hist_level_q8",
         grid=(n_chunks,),
         in_specs=in_specs,
         out_specs=(
@@ -730,6 +735,7 @@ def leaf_sums_pallas(g, h, c, leaf_id, num_leaves: int, chunk: int = 8192,
     kern = functools.partial(_leaf_sums_kernel, l=l, chunk=chunk)
     out = pl.pallas_call(
         kern,
+        name="leaf_sums",
         grid=(n_chunks,),
         in_specs=[
             pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
@@ -952,6 +958,7 @@ def grad_quant_hist0_pallas(bins_T, score, aux, bag, seed, spec,
                              swar=_swar_ok(b, interpret), pack_k=pack_k)
     gq, hq, cq, sc, out = pl.pallas_call(
         kern,
+        name="grad_quant_hist0",
         grid=(2, n_chunks),
         in_specs=[
             pl.BlockSpec((f, chunk), lambda p, i: (0, i),
@@ -1050,6 +1057,7 @@ def leaf_sums_grad_pallas(score, aux, bag, leaf_id, spec, num_leaves: int,
                              spec=spec)
     out = pl.pallas_call(
         kern,
+        name="leaf_sums_grad",
         grid=(n_chunks,),
         in_specs=[
             pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
@@ -1181,6 +1189,7 @@ def route_level_pallas(bins_T, leaf_id, tables, na_bin, num_slots: int,
                              b=b_mem, has_cat=has_cat)
     slot, lid2 = pl.pallas_call(
         kern,
+        name="route_level",
         grid=(n_chunks,),
         in_specs=in_specs,
         out_specs=(
@@ -1210,27 +1219,43 @@ def _take_kernel(tab_ref, idx_ref, out_ref, *, l: int, chunk: int):
     out_ref[:] = out.reshape(chunk)
 
 
-def take_small_pallas(table: jnp.ndarray, idx: jnp.ndarray,
-                      chunk: int = 8192, interpret: bool = False) -> jnp.ndarray:
-    """table[idx] for a small f32 table (out-of-range idx -> 0.0).
-
-    The MXU one-hot contraction replaces XLA's per-element gather (~7ms per
-    1M rows); measured sub-ms at 1M rows."""
-    l = table.shape[0]
-    n = idx.shape[0]
-    idx_p = _pad_rows(idx, chunk, value=l)
-    n_chunks = idx_p.shape[0] // chunk
+def _take_call(l: int, n_pad: int, chunk: int, interpret: bool):
     kern = functools.partial(_take_kernel, l=l, chunk=chunk)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
-        grid=(n_chunks,),
+        name="take_small",
+        grid=(n_pad // chunk,),
         in_specs=[
             pl.BlockSpec((l,), lambda i: (0,), memory_space=pltpu.VMEM),
             pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((chunk,), lambda i: (i,),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((idx_p.shape[0],), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.float32),
         interpret=interpret,
-    )(table.astype(jnp.float32), idx_p)
-    return out[:n]
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _scoped_take(scope: str, l: int, n_pad: int, chunk: int, interpret: bool):
+    return scoped_jit(_take_call(l, n_pad, chunk, interpret), scope)
+
+
+def take_small_pallas(table: jnp.ndarray, idx: jnp.ndarray,
+                      chunk: int = 8192, interpret: bool = False,
+                      scope: str = None) -> jnp.ndarray:
+    """table[idx] for a small f32 table (out-of-range idx -> 0.0).
+
+    The MXU one-hot contraction replaces XLA's per-element gather (~7ms per
+    1M rows); measured sub-ms at 1M rows. ``scope``: for a call dispatched
+    on its own, the device scope the kernel runs under (``scoped_jit``).
+    The scoped program is kept per shape, so a caller that repeats a shape
+    (validation scoring, every iteration) loads it once; a bare
+    ``pallas_call`` is a fresh jit, traced and loaded again, on every call."""
+    l = table.shape[0]
+    n = idx.shape[0]
+    idx_p = _pad_rows(idx, chunk, value=l)
+    n_pad = idx_p.shape[0]
+    call = (_take_call(l, n_pad, chunk, interpret) if scope is None else
+            _scoped_take(scope, l, n_pad, chunk, interpret))
+    return call(table.astype(jnp.float32), idx_p)[:n]

@@ -8,16 +8,17 @@ encoded ~leaf_index, matching the reference's child encoding).
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
 
+from ..utils.timer import scoped_jit
 
-@partial(jax.jit, static_argnames=("max_steps",))
+
 def route_bins(split_feature, threshold_bin, default_left, left_child, right_child,
                num_leaves, bins, na_bin, max_steps: int,
-               is_cat=None, cat_mask=None):
+               is_cat=None, cat_mask=None, scope: str = None):
     """Leaf index for each row of a *binned* matrix. bins: [N, F] uint8/int32.
 
     is_cat [n_nodes] bool + cat_mask [n_nodes, B] bool extend the walk with
@@ -27,7 +28,21 @@ def route_bins(split_feature, threshold_bin, default_left, left_child, right_chi
     them into the fori_loop body's jaxpr as constants, so every call with a
     new tree lowered a fresh program (DART's per-iteration drop/re-add
     walked 6+ lowerings per iteration). Inside an outer jit the wrapper
-    just inlines."""
+    just inlines.
+
+    ``scope`` names the device scope of a call that is dispatched on its own
+    (validation scoring during training passes ``valid_score``): the walk is
+    then the program ``jit_route_bins_<scope>``, every op under the scope
+    (``utils.timer.scoped_jit``); what ``Booster.predict`` runs stays bare."""
+    walk = _WALK if scope is None else _scoped_walk(scope)
+    return walk(split_feature, threshold_bin, default_left, left_child,
+                right_child, num_leaves, bins, na_bin, max_steps=max_steps,
+                is_cat=is_cat, cat_mask=cat_mask)
+
+
+def _walk(split_feature, threshold_bin, default_left, left_child, right_child,
+          num_leaves, bins, na_bin, max_steps: int, is_cat=None,
+          cat_mask=None):
     n = bins.shape[0]
     # pointer: >=0 internal node, <0 leaf (~leaf)
     start = jnp.where(num_leaves > 1, 0, -1)
@@ -54,6 +69,16 @@ def route_bins(split_feature, threshold_bin, default_left, left_child, right_chi
 
     ptr = jax.lax.fori_loop(0, max_steps, body, ptr)
     return jnp.invert(jnp.minimum(ptr, -1))  # ~ptr, leaves only
+
+
+# the programs keep the public name: jit_route_bins, jit_route_bins_<scope>
+_walk.__name__ = _walk.__qualname__ = "route_bins"
+_WALK = jax.jit(_walk, static_argnames=("max_steps",))
+
+
+@lru_cache(maxsize=None)
+def _scoped_walk(scope: str):
+    return scoped_jit(_walk, scope, static_argnames=("max_steps",))
 
 
 def route_raw(split_feature, threshold_real, default_left, left_child, right_child,

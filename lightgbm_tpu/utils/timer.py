@@ -58,15 +58,19 @@ class TimerRegistry:
             self._cnt.clear()
 
     @contextlib.contextmanager
-    def scope(self, name: str, block_on=None):
+    def scope(self, name: str, block_on=None, step_num=None):
         """Accumulate wall time under ``name``. If ``block_on`` is a callable,
         its result is block_until_ready'd before the clock stops (so the scope
-        covers device execution, not just async dispatch)."""
+        covers device execution, not just async dispatch). With ``step_num``
+        the range is a ``StepTraceAnnotation``: the profiler's step view then
+        groups what ran under it by that number."""
         if not self.enabled:
             yield
             return
         t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(name):
+        ann = (jax.profiler.TraceAnnotation(name) if step_num is None else
+               jax.profiler.StepTraceAnnotation(name, step_num=step_num))
+        with ann:
             yield
             if block_on is not None:
                 jax.block_until_ready(block_on() if callable(block_on) else block_on)
@@ -119,6 +123,24 @@ def timed(name: str, block: bool = False):
             return out
         return inner
     return wrap
+
+
+def scoped_jit(fn, scope: str, **jit_kwargs):
+    """``jax.jit(fn)`` with the body under ``jax.named_scope(scope)``, for a
+    program that is dispatched on its own. jit drops the caller's name stack
+    at its boundary, so a scope opened around the call never reaches the
+    device trace; opened inside, it is in every op's name. The scope is in
+    the function's name as well (module ``jit_<fn>_<scope>``): the persistent
+    compilation cache leaves metadata out of its key and would otherwise hand
+    back the executable built for the unscoped program, its op names with it.
+    """
+    def body(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+    body.__name__ = body.__qualname__ = f"{fn.__name__}_{scope}"
+    # a factory: the caller keeps the wrapper (ops/predict.py caches per
+    # scope, ops/pallas_hist.py per scope and shape)
+    return jax.jit(body, **jit_kwargs)   # tpu-lint: disable=retrace-hazard
 
 
 def time_op_in_jit(op, *big, K: int = 6, reps: int = 1):

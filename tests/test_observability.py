@@ -277,13 +277,174 @@ def test_zero_retrace_predict_with_telemetry(booster):
 def test_training_lowering_count_unchanged_by_telemetry(tmp_path):
     """Identical training runs must lower the same number of programs with
     telemetry on and off (host-side observation only, no new jit boundaries)."""
-    with jtu.count_jit_and_pmap_lowerings() as off:
-        _train()
+    state = RNG.get_state()
+
+    def same(**extra):      # the same rows each time: bin counts are shapes
+        RNG.set_state(state)
+        return _train(**extra)
+    same()          # untimed: pays the compiles, so both counted runs are warm
+    # the counter goes on counting after its block: read it inside
+    with jtu.count_jit_and_pmap_lowerings() as count:
+        same()
+        off = count()
     obs.reset()
-    with jtu.count_jit_and_pmap_lowerings() as on:
-        _train(telemetry=1, metrics_out=str(tmp_path))
-    assert on() == off(), (f"telemetry changed lowering count: "
-                             f"{off()} -> {on()}")
+    with jtu.count_jit_and_pmap_lowerings() as count:
+        same(telemetry=1, metrics_out=str(tmp_path))
+        on = count()
+    assert on == off, f"telemetry changed lowering count: {off} -> {on}"
+
+
+# ---- names, spans and the program_load counter (PR 25) ----------------------
+
+# every named_scope the step's traced body opens, and the Pallas kernels of
+# the forced-Pallas path on the CPU
+STEP_SCOPES = {"front", "split_search", "apply_level", "route_hist",
+               "leaf_renew", "score_update"}
+STEP_KERNELS = {"grad_quant_hist0", "hist_level_q8", "leaf_sums_grad"}
+# children of train_iter, by name (docs/OBSERVABILITY.md)
+ITER_SPANS = {"callbacks_before", "boosting", "prewarm_adopt", "step_dispatch",
+              "valid_score", "finished_check", "eval", "metric", "callbacks",
+              "snapshot"}
+
+
+def _train_valid(rounds=3, **extra):
+    X = RNG.rand(300, 6)
+    y = (X[:, 0] + 0.2 * RNG.randn(300) > 0.5).astype(np.float32)
+    params = {"objective": "binary", "metric": "auc", "num_leaves": 7,
+              "verbose": -1, "min_data_in_leaf": 5, **extra}
+    ds = lgb.Dataset(X[:200], label=y[:200], params=params)
+    return lgb.train(params, ds, num_boost_round=rounds,
+                     valid_sets=[ds.create_valid(X[200:], label=y[200:])],
+                     verbose_eval=False)
+
+
+def test_step_jaxpr_names_kernels_and_scopes(monkeypatch):
+    """The traced step holds a pallas_call under every kernel name of the
+    path and every stage's scope in its name stacks (interpret mode, the
+    small shape of test_hist_packed)."""
+    import jax
+    from jax._src import core
+    from lightgbm_tpu.models import gbdt
+    seen = {}
+    real = gbdt.GBDT._build_fused_step
+
+    def build(self, custom):
+        fn = real(self, custom)
+
+        def call(*args):
+            seen.setdefault("jaxpr", jax.make_jaxpr(fn)(*args))
+            return fn(*args)
+        return call
+    monkeypatch.setattr(gbdt.GBDT, "_build_fused_step", build)
+    rng = np.random.RandomState(0)
+    X = rng.rand(220, 7).astype(np.float32)
+    y = (X[:, 0] + 0.3 * rng.rand(220) > 0.65).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 7, "max_bin": 31,
+              "min_data_in_leaf": 5, "verbosity": -1, "prewarm": 0,
+              "histogram_impl": "pallas", "use_quantized_grad": "true"}
+    lgb.train(params, lgb.Dataset(X, label=y, params=params),
+              num_boost_round=1)
+    kernels, scopes = set(), set()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            scopes.update(str(eqn.source_info.name_stack).split("/"))
+            if eqn.primitive.name == "pallas_call":
+                kernels.add(eqn.params["name"])
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(seen["jaxpr"].jaxpr)
+    assert kernels == STEP_KERNELS
+    assert STEP_SCOPES <= scopes
+    assert any(s.startswith("level_s") for s in scopes)
+
+
+def test_train_iter_spans_nest_and_add_up(monkeypatch):
+    """Three iterations with telemetry on: three train_iter events whose
+    spans are the documented children, add up to no more than the iteration,
+    and were opened and closed in stack order on the training thread."""
+    from lightgbm_tpu.utils import timer
+    log, real = [], timer.TIMER.scope
+
+    def scope(name, **kw):
+        cm = real(name, **kw)
+        log.append(("open", name, threading.get_ident()))
+
+        class _Logged:
+            def __enter__(self):
+                return cm.__enter__()
+
+            def __exit__(self, *exc):
+                log.append(("close", name, threading.get_ident()))
+                return cm.__exit__(*exc)
+        return _Logged()
+    monkeypatch.setattr(timer.TIMER, "scope", scope)
+    _train_valid(telemetry=1)
+    iters = [e for e in obs.EVENTS.snapshot() if e["type"] == "train_iter"]
+    assert [e["iteration"] for e in iters] == [1, 2, 3]
+    for e in iters:
+        assert set(e["spans"]) <= ITER_SPANS
+        assert {"boosting", "step_dispatch", "valid_score", "finished_check",
+                "eval", "metric"} <= set(e["spans"])
+        assert all(v >= 0 for v in e["spans"].values())
+        assert sum(e["spans"].values()) <= e["duration_s"]
+        assert e["programs_loaded"] >= 0
+    stack = []
+    for what, name, tid in log:
+        assert tid == threading.get_ident()
+        if what == "open":
+            stack.append(name)
+        else:
+            assert stack.pop() == name
+    assert not stack
+    assert [n for w, n, _ in log if w == "open"].count("train_iter") == 3
+    outside = {e["name"] for e in obs.EVENTS.snapshot()
+               if e["type"] == "span"}
+    assert {"dataset_construct", "train_setup", "finalize"} <= outside
+
+
+def test_program_load_names_the_span_it_fell_into():
+    """An eager jit dispatched inside valid_score yields a program_load event
+    with that span, and the events add up to what a plain listener counts."""
+    import jax
+    import jax.numpy as jnp
+    plain = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **kw: plain.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    obs.configure(enabled=True)
+    with obs.span("train_iter", step_num=7) as rec:
+        with obs.span("valid_score"):
+            jax.jit(lambda x: x * 3 + 1)(jnp.arange(5)).block_until_ready()
+    jax.jit(lambda x: x * 5 - 1)(jnp.arange(5)).block_until_ready()
+    loads = [e for e in obs.EVENTS.snapshot() if e["type"] == "program_load"]
+    assert len(loads) == len(plain) >= 2
+    inside = [e for e in loads if e["span"] == "valid_score"]
+    assert inside and all(e["iteration"] == 7 for e in inside)
+    assert rec.programs_loaded == len(inside)
+    assert loads[-1]["span"] == "none" and "iteration" not in loads[-1]
+    by_span = sum(
+        obs.METRICS.counter("programs_loaded", "", span=s).value
+        for s in {e["span"] for e in loads})
+    assert by_span == len(plain)
+
+
+def test_spans_and_program_load_silent_when_disabled():
+    """Telemetry off: no program_load event, no spans field, and obs.span
+    leaves the metrics registry as it found it."""
+    import jax
+    import jax.numpy as jnp
+    obs.configure(enabled=True)      # the listener is in place ...
+    obs.configure(enabled=False)     # ... and silent
+    obs.reset()
+    with obs.span("valid_score"):
+        jax.jit(lambda x: x * 7 + 2)(jnp.arange(5)).block_until_ready()
+    _train_valid(rounds=2)
+    assert len(obs.EVENTS) == 0
+    assert obs.METRICS.to_json() == {}
+    _train_valid(rounds=2, telemetry=1)
+    iters = [e for e in obs.EVENTS.snapshot() if e["type"] == "train_iter"]
+    assert iters and all("spans" in e for e in iters)
 
 
 # ---- timer satellites -------------------------------------------------------

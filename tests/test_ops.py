@@ -265,6 +265,32 @@ def test_take_small_pallas():
     np.testing.assert_allclose(out, table[idx], rtol=1e-6)
 
 
+def test_take_small_pallas_scoped_is_kept_per_shape():
+    """Under a scope the lookup is one program per (scope, shape): the second
+    call of a shape lowers nothing, the kernel sits under the scope, and the
+    values are the bare call's."""
+    from jax._src import test_util as jtu
+    from lightgbm_tpu.ops import pallas_hist as PH
+    rng = np.random.RandomState(11)
+    table = jnp.asarray(rng.randn(127).astype(np.float32))
+    idx = jnp.asarray(rng.randint(0, 127, size=9000).astype(np.int32))
+    bare = np.asarray(PH.take_small_pallas(table, idx, interpret=True))
+    first = np.asarray(PH.take_small_pallas(table, idx, interpret=True,
+                                            scope="valid_score"))
+    doubled = table * 2
+    with jtu.count_jit_and_pmap_lowerings() as count:
+        again = np.asarray(PH.take_small_pallas(doubled, idx, interpret=True,
+                                                scope="valid_score"))
+        assert count() == 0
+    np.testing.assert_array_equal(first, bare)
+    np.testing.assert_array_equal(again, bare * 2)
+    prog = PH._scoped_take("valid_score", 127, 16384, 8192, True)
+    assert prog.__name__ == "wrapped_valid_score"
+    text = prog.lower(table, jnp.zeros(16384, jnp.int32)).as_text(
+        debug_info=True)
+    assert "valid_score" in text
+
+
 # ---------------------------------------------------------------------------
 # int8 quantized-gradient histograms (LightGBM 4.x analog; ops/pallas_hist
 # _kernel_q8 + ops/histogram.quantize_sr)
