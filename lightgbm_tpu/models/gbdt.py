@@ -1423,6 +1423,7 @@ class GBDT:
         where popping trees whose score deltas are already baked into
         train/valid scores would corrupt the continuing training state."""
         self._drain_pending_stop()
+        self._emit_valid_walks()
 
     def _drain_pending_stop(self) -> None:
         """Flush the 8-deep lagged finished-check queue: if num_boost_round
@@ -1467,6 +1468,8 @@ class GBDT:
         its running average)."""
         if not self.valid_sets:
             return
+        # only a ceiling (a chain of num_leaves - 1 decisions): the walk
+        # ends when every row is on a leaf, after the tree's depth in steps
         max_steps = self.gp.num_leaves - 1 if self.gp.num_leaves > 1 else 1
         if self._dp:
             # the data-parallel step returns the tree replicated over the
@@ -1474,6 +1477,8 @@ class GBDT:
             # device from its own replica (zero-copy) instead of on every
             # chip — where the Mosaic lookup below could not be partitioned
             tree_dev = jax.tree.map(lambda a: a.addressable_data(0), tree_dev)
+        # with telemetry on, each walk's step count (a device scalar)
+        steps = [] if obs.enabled() else None
         # host span and device scope share the name; the walk and the lookup
         # run as scoped programs, so nothing Booster.predict runs carries it
         with obs.span("valid_score"):
@@ -1482,11 +1487,35 @@ class GBDT:
                     tree_dev.split_feature, tree_dev.threshold_bin,
                     tree_dev.default_left, tree_dev.left_child,
                     tree_dev.right_child, tree_dev.num_leaves, vs.bins,
-                    vs.na_bin_dev, max_steps, scope="valid_score")
+                    vs.na_bin_dev, max_steps, scope="valid_score",
+                    steps_out=steps)
                 vdelta = take_small(tree_dev.leaf_value, leaf,
                                     scope="valid_score") - bias
                 self.valid_scores[i] = self._apply_valid_delta(
                     self.valid_scores[i], vdelta, cls)
+            if steps:
+                # (iteration, valid set, steps) of the walks whose event is
+                # still to come; made on first use, as the lagged queues of
+                # _grow_and_update are. This iteration's walks are queued
+                # behind the step: reading their counts now would hold the
+                # host until they have run, so only the earlier ones go out
+                it_no = self.iter_ + 1
+                q = self.__dict__.setdefault("_valid_walks", [])
+                for i, s in enumerate(steps):
+                    s.copy_to_host_async()
+                    q.append((it_no, i, s))
+                self._emit_valid_walks(before=it_no)
+
+    def _emit_valid_walks(self, before: Optional[int] = None) -> None:
+        """``valid_walk`` events of the walks dispatched in iterations
+        earlier than ``before`` (all of them without it): the step count is
+        a device scalar, read an iteration late or at the end of training,
+        when the walk that made it has long run."""
+        q = getattr(self, "_valid_walks", None)
+        while q and (before is None or q[0][0] < before):
+            it_no, vset, steps = q.pop(0)
+            obs.emit("valid_walk", steps=int(steps), iteration=it_no,
+                     valid_set=vset)
 
     def _apply_valid_delta(self, score, vdelta, cls: int):
         if self.num_tree_per_iteration == 1:

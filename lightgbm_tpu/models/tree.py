@@ -510,9 +510,13 @@ def ensemble_path_tables(stack: Dict[str, np.ndarray],
 def ensemble_max_depth(stack: Dict[str, np.ndarray]) -> int:
     """Longest root->leaf DECISION count across stacked trees (host-side).
 
-    The jitted tree walk (ops/predict.py route_bins) runs a static-trip
-    loop; sizing it by num_leaves - 1 (254 at L=255) instead of the actual
-    depth (~10 for depthwise trees) made batch prediction ~25x slower.
+    The ceiling of the jitted tree walks. The binned walk (ops/predict.py
+    route_bins) stops by itself once every row is on a leaf, and under vmap
+    once the deepest tree of the stack is done, so there this bound is only
+    its guarantee of termination; the raw-float walk (route_raw) still runs
+    a static-trip loop, where sizing it by num_leaves - 1 (254 at L=255)
+    instead of the actual depth (~10 for depthwise trees) made batch
+    prediction ~25x slower.
     Children always carry larger
     node ids than their parents (both growers assign ids split-/level-
     ordered), so one forward pass over nodes computes exact depths."""

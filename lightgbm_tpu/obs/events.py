@@ -51,6 +51,12 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # calling thread ("none" outside all), ``iteration`` the train_iter it
     # fell into
     "program_load": ({"span": str, "duration_s": _NUM}, {"iteration": int}),
+    # validation scoring walked one finished tree over one validation set:
+    # ``steps`` is the number of steps the walk took before every row was on
+    # a leaf (ops/predict.route_bins), a device scalar read one iteration
+    # late; ``iteration`` is 1-based as train_iter's, ``valid_set`` the
+    # set's index. One event per tree: k per iteration with k classes.
+    "valid_walk": ({"steps": int, "iteration": int, "valid_set": int}, {}),
     # a jitted program was built (host-side tracing/lowering observed via
     # the function's cache size; device code itself is unchanged)
     "compile": ({"what": str, "cache_size": int},

@@ -1,10 +1,12 @@
 """Device-side prediction: route rows through trees.
 
 Reference analog: Tree::Predict / NumericalDecision node walk (tree.h:126,240) and
-the batch Predictor (predictor.hpp:29). On TPU the node walk is a bounded
-``fori_loop`` of vectorized gathers over the flat tree arrays — every row advances
-one level per iteration; finished rows park on their leaf (pointer < 0 is a leaf,
-encoded ~leaf_index, matching the reference's child encoding).
+the batch Predictor (predictor.hpp:29). On TPU the node walk is a ``while_loop``
+of vectorized gathers over the flat tree arrays — every row advances one level
+per iteration; finished rows park on their leaf (pointer < 0 is a leaf, encoded
+~leaf_index, matching the reference's child encoding). The binned walk ends
+when no row is left on an internal node, so it takes as many steps as the
+deepest leaf a row reached; ``max_steps`` is only its ceiling.
 """
 from __future__ import annotations
 
@@ -18,14 +20,24 @@ from ..utils.timer import scoped_jit
 
 def route_bins(split_feature, threshold_bin, default_left, left_child, right_child,
                num_leaves, bins, na_bin, max_steps: int,
-               is_cat=None, cat_mask=None, scope: str = None):
+               is_cat=None, cat_mask=None, scope: str = None,
+               steps_out: list = None):
     """Leaf index for each row of a *binned* matrix. bins: [N, F] uint8/int32.
+
+    The walk stops as soon as every row is on a leaf (the predicate is
+    computed on the device inside the loop; under ``vmap`` over trees, when
+    the deepest tree of the batch is done) and at ``max_steps`` at the
+    latest: a caller that knows no better passes ``num_leaves - 1``, the
+    depth of a chain, and pays for the tree's real depth. ``steps_out``, a
+    list, receives the number of steps taken as an i32 scalar on the device
+    (it is an argument and not a second return value so that the leaf array
+    stays the one thing a caller, or a test that wraps this entry, handles).
 
     is_cat [n_nodes] bool + cat_mask [n_nodes, B] bool extend the walk with
     categorical subset decisions (bin member -> LEFT; reference: tree.h:279).
 
     Jitted with the tree arrays as traced ARGUMENTS: the eager form baked
-    them into the fori_loop body's jaxpr as constants, so every call with a
+    them into the loop body's jaxpr as constants, so every call with a
     new tree lowered a fresh program (DART's per-iteration drop/re-add
     walked 6+ lowerings per iteration). Inside an outer jit the wrapper
     just inlines.
@@ -35,9 +47,12 @@ def route_bins(split_feature, threshold_bin, default_left, left_child, right_chi
     then the program ``jit_route_bins_<scope>``, every op under the scope
     (``utils.timer.scoped_jit``); what ``Booster.predict`` runs stays bare."""
     walk = _WALK if scope is None else _scoped_walk(scope)
-    return walk(split_feature, threshold_bin, default_left, left_child,
-                right_child, num_leaves, bins, na_bin, max_steps=max_steps,
-                is_cat=is_cat, cat_mask=cat_mask)
+    leaf, steps = walk(split_feature, threshold_bin, default_left, left_child,
+                       right_child, num_leaves, bins, na_bin,
+                       max_steps=max_steps, is_cat=is_cat, cat_mask=cat_mask)
+    if steps_out is not None:
+        steps_out.append(steps)
+    return leaf
 
 
 def _walk(split_feature, threshold_bin, default_left, left_child, right_child,
@@ -50,7 +65,8 @@ def _walk(split_feature, threshold_bin, default_left, left_child, right_child,
     mem_flat = (cat_mask.reshape(-1).astype(jnp.float32)
                 if cat_mask is not None else None)
 
-    def body(_, ptr):
+    def body(carry):
+        ptr, step = carry
         node = jnp.maximum(ptr, 0)
         feat = split_feature[node]
         thr = threshold_bin[node]
@@ -65,10 +81,15 @@ def _walk(split_feature, threshold_bin, default_left, left_child, right_child,
             mem = mem & (col < bm)
             go_left = jnp.where(is_cat[node], mem, go_left)
         nxt = jnp.where(go_left, left_child[node], right_child[node])
-        return jnp.where(ptr >= 0, nxt, ptr)
+        return jnp.where(ptr >= 0, nxt, ptr), step + 1
 
-    ptr = jax.lax.fori_loop(0, max_steps, body, ptr)
-    return jnp.invert(jnp.minimum(ptr, -1))  # ~ptr, leaves only
+    def rows_still_moving(carry):
+        ptr, step = carry
+        return (step < max_steps) & jnp.any(ptr >= 0)
+
+    ptr, steps = jax.lax.while_loop(rows_still_moving, body,
+                                    (ptr, jnp.int32(0)))
+    return jnp.invert(jnp.minimum(ptr, -1)), steps  # ~ptr, leaves only
 
 
 # the programs keep the public name: jit_route_bins, jit_route_bins_<scope>

@@ -447,6 +447,35 @@ def test_spans_and_program_load_silent_when_disabled():
     assert iters and all("spans" in e for e in iters)
 
 
+def test_valid_walk_event_counts_the_steps_of_each_walk(monkeypatch):
+    """Telemetry on: one valid_walk event per iteration and validation set,
+    the last one emitted when training ends, its steps at most the tree's
+    depth. Telemetry off: the walk is asked for no step count, so no device
+    scalar is kept or read."""
+    from lightgbm_tpu.ops import predict as P
+    asked, real = [], P.route_bins
+
+    def route_bins(*a, steps_out=None, **kw):
+        asked.append(steps_out is not None)
+        return real(*a, steps_out=steps_out, **kw)
+    monkeypatch.setattr(P, "route_bins", route_bins)
+    bst = _train_valid(telemetry=1)
+    walks = [e for e in obs.EVENTS.snapshot() if e["type"] == "valid_walk"]
+    assert [(e["iteration"], e["valid_set"]) for e in walks] == [
+        (1, 0), (2, 0), (3, 0)]
+    depths = [t.max_depth for t in bst.trees]
+    assert len(depths) == 3 and max(depths) <= 6
+    for e, depth in zip(walks, depths):
+        assert 1 <= e["steps"] <= depth
+    assert asked == [True] * 3 and bst._gbdt._valid_walks == []
+    del asked[:]
+    obs.reset()
+    bst = _train_valid()
+    assert asked == [False] * 3
+    assert not hasattr(bst._gbdt, "_valid_walks")
+    assert len(obs.EVENTS) == 0
+
+
 # ---- timer satellites -------------------------------------------------------
 
 def test_timed_uses_functools_wraps():

@@ -70,3 +70,167 @@ def test_predict_uses_best_iteration_after_early_stop():
     p_default = bst.predict(Xv, raw_score=True)
     p_best = bst.predict(Xv, raw_score=True, num_iteration=bst.best_iteration)
     np.testing.assert_allclose(p_default, p_best, rtol=1e-7)
+
+
+# ---- the binned walk ends when every row is on a leaf (PR 26) ---------------
+import jax
+import jax.numpy as jnp
+import pytest
+
+from lightgbm_tpu.ops import predict as P
+
+_B = 8            # bins per feature; bin _B - 1 is the NA bin
+_F = 5
+
+
+def _static_walk(t, bins, na_bin, max_steps):
+    """The walk as it was: every one of ``max_steps`` steps, for every row.
+    Returns the leaves and the last step that still moved a row."""
+    ptr = np.full(len(bins), 0 if t["num_leaves"] > 1 else -1, np.int32)
+    moved = 0
+    for step in range(max_steps):
+        if (ptr >= 0).any():
+            moved = step + 1
+        node = np.maximum(ptr, 0)
+        feat = t["split_feature"][node]
+        col = bins[np.arange(len(bins)), feat].astype(np.int32)
+        left = np.where(col == na_bin[feat], t["default_left"][node],
+                        col <= t["threshold_bin"][node])
+        if "is_cat" in t:
+            left = np.where(t["is_cat"][node], t["cat_mask"][node, col], left)
+        nxt = np.where(left, t["left_child"][node], t["right_child"][node])
+        ptr = np.where(ptr >= 0, nxt, ptr)
+    return ~np.minimum(ptr, -1), moved
+
+
+def _tree(left, right, rng, cat_nodes=()):
+    """Flat arrays of a tree given its child tables (>= 0 a node, ~leaf
+    below); features, thresholds and default directions drawn at random."""
+    m = len(left)
+    t = {"split_feature": rng.randint(0, _F, m).astype(np.int32),
+         "threshold_bin": rng.randint(1, _B - 2, m).astype(np.int32),
+         "default_left": rng.rand(m) < 0.5,
+         "left_child": np.asarray(left, np.int32),
+         "right_child": np.asarray(right, np.int32),
+         "num_leaves": np.int32(m + 1)}
+    if len(cat_nodes):
+        t["is_cat"] = np.isin(np.arange(m), cat_nodes)
+        t["cat_mask"] = rng.rand(m, _B) < 0.5
+    return t
+
+
+def _balanced(depth, rng, **kw):
+    m = 2 ** depth - 1
+    first_last = 2 ** (depth - 1) - 1      # first node of the deepest level
+    left = [2 * i + 1 if i < first_last else ~(2 * (i - first_last))
+            for i in range(m)]
+    right = [2 * i + 2 if i < first_last else ~(2 * (i - first_last) + 1)
+             for i in range(m)]
+    return _tree(left, right, rng, **kw)
+
+
+def _chain(num_leaves, rng):
+    """Every split on the right child: depth num_leaves - 1."""
+    m = num_leaves - 1
+    t = _tree([~i for i in range(m)],
+              [i + 1 if i < m - 1 else ~m for i in range(m)], rng)
+    t["default_left"][:] = False
+    return t
+
+
+def _one_leaf(rng):
+    t = _tree([-1], [-1], rng)
+    t["num_leaves"] = np.int32(1)
+    return t
+
+
+def _bins(rng, n=300):
+    bins = rng.randint(0, _B, (n, _F)).astype(np.uint8)   # NA bins among them
+    bins[0] = _B - 2        # beyond every threshold: the chain's last leaf
+    return bins, np.full(_F, _B - 1, np.int32)
+
+
+def _route(t, bins, na_bin, max_steps):
+    steps = []
+    leaf = P.route_bins(
+        *(jnp.asarray(t[k]) for k in ("split_feature", "threshold_bin",
+                                      "default_left", "left_child",
+                                      "right_child", "num_leaves")),
+        jnp.asarray(bins), jnp.asarray(na_bin), max_steps,
+        is_cat=jnp.asarray(t["is_cat"]) if "is_cat" in t else None,
+        cat_mask=jnp.asarray(t["cat_mask"]) if "is_cat" in t else None,
+        steps_out=steps)
+    return np.asarray(leaf), int(steps[0])
+
+
+@pytest.mark.parametrize("shape,depth", [
+    ("balanced", 4), ("chain", 11), ("one_leaf", 0), ("categorical", 3),
+    ("cut_short", 3)])
+def test_walk_ends_when_every_row_is_on_a_leaf(shape, depth):
+    """Same leaves as the static walk over num_leaves - 1 steps, in as many
+    steps as the tree is deep; a ``max_steps`` below the depth still ends
+    the walk there (``cut_short``: a chain of depth 11 held to 3)."""
+    rng = np.random.RandomState(5)
+    t = {"balanced": lambda: _balanced(4, rng),
+         "chain": lambda: _chain(12, rng),
+         "one_leaf": lambda: _one_leaf(rng),
+         "categorical": lambda: _balanced(3, rng, cat_nodes=(0, 2, 5)),
+         "cut_short": lambda: _chain(12, rng)}[shape]()
+    bins, na_bin = _bins(rng)
+    max_steps = 3 if shape == "cut_short" else max(int(t["num_leaves"]) - 1, 1)
+    want, moved = _static_walk(t, bins, na_bin, max_steps)
+    leaf, steps = _route(t, bins, na_bin, max_steps)
+    np.testing.assert_array_equal(leaf, want)
+    assert steps == moved == depth
+
+
+def test_ensemble_of_unequal_depths_is_the_sum_of_its_trees():
+    """Under vmap the loop runs until the deepest tree of the stack is done;
+    the shallow trees' rows stay parked meanwhile."""
+    rng = np.random.RandomState(9)
+    trees = [_balanced(2, rng), _chain(8, rng), _one_leaf(rng),
+             _balanced(3, rng)]
+    bins, na_bin = _bins(rng)
+    m = max(len(t["left_child"]) for t in trees)
+
+    def pad(a, fill=0):
+        return np.concatenate([a, np.full(m - len(a), fill, a.dtype)])
+    stack = {k: np.stack([pad(t[k], -1 if k.endswith("child") else 0)
+                          for t in trees])
+             for k in ("split_feature", "threshold_bin", "default_left",
+                       "left_child", "right_child")}
+    stack["num_leaves"] = np.array([t["num_leaves"] for t in trees])
+    stack["leaf_value"] = rng.randn(len(trees), m + 1).astype(np.float32)
+    got = P.predict_bins_ensemble(
+        {k: jnp.asarray(v) for k, v in stack.items()}, jnp.asarray(bins),
+        jnp.asarray(na_bin), max_steps=m)
+    want = np.zeros(len(bins), np.float32)
+    for i, t in enumerate(trees):
+        leaf, _ = _static_walk(t, bins, na_bin, m)
+        want += stack["leaf_value"][i][leaf]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+
+
+def test_rollback_on_a_row_sharded_mesh_takes_back_the_tree():
+    """Two shards of the CPU devices conftest forces: the walk's ``any`` is a
+    reduction across shards inside the loop's condition. Rolling back the
+    last iteration subtracts exactly what the static walk would."""
+    rng = np.random.RandomState(3)
+    X = rng.randn(1001, 6).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "verbose": -1,
+              "min_data_in_leaf": 5, "num_shards": 2}
+    ds = lgb.Dataset(X, label=y, params=params)
+    bst = lgb.train(params, ds, num_boost_round=3, verbose_eval=False)
+    g = bst._gbdt
+    assert len(g.train_set.bins.sharding.device_set) == 2
+    before = np.asarray(g.train_score)
+    tree = jax.tree.map(np.asarray, g.models_dev[-1])
+    t = {k: getattr(tree, k) for k in (
+        "split_feature", "threshold_bin", "default_left", "left_child",
+        "right_child", "num_leaves")}
+    leaf, _ = _static_walk(t, np.asarray(g.train_set.bins),
+                           np.asarray(g.train_set.na_bin_dev), 14)
+    bst.rollback_one_iter()
+    want = before - tree.leaf_value[leaf][: len(before)]
+    np.testing.assert_array_equal(np.asarray(g.train_score), want)
