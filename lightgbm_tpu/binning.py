@@ -13,6 +13,7 @@ array, and sparsity is recovered via EFB bundling at ingest (see efb.py).
 """
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -540,6 +541,31 @@ class BinnedDataset:
         return max((m.num_bins for m in self.mappers), default=1)
 
 
+# find_bin_mappers takes its row sample column-major: rows per task and
+# threads of that copy
+_FIND_BINS_ROWS = 4096
+_FIND_BINS_THREADS = 8
+
+
+def _sample_columns(data: np.ndarray, idx: Optional[np.ndarray]) -> np.ndarray:
+    """``data[idx].T`` (``data.T`` where idx is None) as a C-contiguous
+    [F, rows] array, made in blocks of rows on a few threads: one pass over
+    the sampled rows, each column then contiguous. Taking the columns one by
+    one out of a row-major sample reads one value per 4 F bytes, a page and
+    a cache line for each (20 ms a column at 200k x 2,000)."""
+    from concurrent.futures import ThreadPoolExecutor
+    k = len(data) if idx is None else len(idx)
+    out = np.empty((data.shape[1], k), data.dtype)
+
+    def fill(lo):
+        hi = min(k, lo + _FIND_BINS_ROWS)
+        out[:, lo:hi] = (data[lo:hi] if idx is None else data[idx[lo:hi]]).T
+
+    with ThreadPoolExecutor(min(_FIND_BINS_THREADS, os.cpu_count() or 1)) as pool:
+        list(pool.map(fill, range(0, k, _FIND_BINS_ROWS)))
+    return out
+
+
 def find_bin_mappers(
     data: np.ndarray,
     max_bin: int,
@@ -555,17 +581,17 @@ def find_bin_mappers(
     """Find per-feature bin mappers from a row sample of ``data`` [N, F]."""
     n, f = data.shape
     rng = np.random.RandomState(seed)
-    if n > sample_cnt:
-        idx = rng.choice(n, sample_cnt, replace=False)
-        sample = data[idx]
-    else:
-        sample = data
+    idx = rng.choice(n, sample_cnt, replace=False) if n > sample_cnt else None
+    # the sampled rows in their order, a column a row: every from_sample call
+    # sees the values a slice of the row-major sample would give it
+    columns = _sample_columns(data, idx)
+    n_sample = columns.shape[1]
     cats = set(categorical or ())
     per_feat_bin = _check_max_bin_by_feature(max_bin_by_feature, f, max_bin)
     mappers = []
     for j in range(f):
         mappers.append(BinMapper.from_sample(
-            sample[:, j], len(sample), per_feat_bin[j],
+            columns[j], n_sample, per_feat_bin[j],
             min_data_in_bin=min_data_in_bin,
             bin_type=BIN_CATEGORICAL if j in cats else BIN_NUMERICAL,
             use_missing=use_missing,

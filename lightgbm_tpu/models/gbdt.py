@@ -702,10 +702,16 @@ class GBDT:
         serial depthwise quantized grower (no lean tiling, CEGB or forced
         splits — those paths read materialized g/h), a built-in objective
         that advertises an in-register gradient replica
-        (ObjectiveFunction.fused_grad_spec), the Pallas histogram impl, and
-        an [F*B] accumulator that fits the fused kernel's VMEM row budget.
+        (ObjectiveFunction.fused_grad_spec) and the Pallas histogram impl.
         Anything else keeps the unfused gradients -> make_quant -> hist0
-        chain, which the fused kernel is bit-identical to by construction."""
+        chain, which the fused kernel is bit-identical to by construction.
+        Whether the front is ONE kernel is the width's to say: where the
+        [F*B] accumulator exceeds the kernel's VMEM row budget
+        (ops/histogram.hist_path) grad_quant_hist0 runs that same chain
+        from (score, aux). The spec is kept there so that the objective's
+        per-row constant reaches the step as an argument: closed over by
+        obj.get_gradients it would be a literal of the traced program, and
+        the persistent compile cache would key on the labels."""
         cached = getattr(self, "_fused_front_cache", None)
         if cached is not None:
             return cached
@@ -719,10 +725,7 @@ class GBDT:
                 and getattr(self, "_plan", None) is None
                 and self._cegb_dev is None and self._forced_dev is None):
             from ..ops.histogram import pick_impl
-            from ..ops.pallas_hist import _ACC_ROWS_MAX
-            F = int(self.train_set.num_features)
-            if (pick_impl(gp.hist_impl) == "pallas"
-                    and F * int(gp.max_bin) <= _ACC_ROWS_MAX):
+            if pick_impl(gp.hist_impl) == "pallas":
                 fs = obj.fused_grad_spec()
                 if fs is not None:
                     res = fs
@@ -978,12 +981,25 @@ class GBDT:
         nf = self._nf_policy
         use_bt = self._use_bt()
         fused_spec = None if custom else self._fused_front()[0]
+        gp = self.gp
+        if self.config.grow_policy == "depthwise" and gp.lean_ft <= 0:
+            # what the default depthwise grower's level passes will run at
+            # this width (ops/histogram.hist_routed selects it at trace time)
+            from ..ops.histogram import hist_path, one_kernel_front
+            width = int(self.train_set.num_features), int(gp.max_bin)
+            # the front is one kernel where grad_quant_hist0 is called (a
+            # fused spec) and its own gate says so
+            one_kernel = (fused_spec is not None
+                          and one_kernel_front(*width, gp.hist_impl))
+            obs.emit("hist_path", front="fused" if one_kernel else "unfused",
+                     bins_T_cached=bool(use_bt),
+                     **hist_path(*width, gp.hist_impl, bool(gp.quant)))
 
         def step(bins, num_bins, na_bin, score, fmask, bag_mask, grad, hess,
                  shrink, qseed, titer, cegb_st, bins_t, aux):
             bt = bins_t if use_bt else None
             if not custom and fused_spec is None:
-                with jax.named_scope("front"):
+                with jax.named_scope("front"), jax.named_scope("grad"):
                     grad, hess = obj.get_gradients(score)
             # else fused front: the grower derives gradients from
             # (score, aux) in-register — the full-N g/h arrays are never

@@ -57,6 +57,16 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # late; ``iteration`` is 1-based as train_iter's, ``valid_set`` the
     # set's index. One event per tree: k per iteration with k classes.
     "valid_walk": ({"steps": int, "iteration": int, "valid_set": int}, {}),
+    # the fused step of the default depthwise grower was built: the path its
+    # level passes take at this width (ops/histogram.hist_path). level_kernel
+    # "hist_level_q8" routes and accumulates in one launch; "hist_leaf_q8" /
+    # "hist_leaf" run on a (feature_groups, row chunks) grid after a route
+    # pass of its own ("pallas" | "xla"); off the Pallas path the histogram
+    # impl's name. front: whether gradients, quantisation and the root
+    # histogram are one kernel; bins_T_cached: whether the step is fed the
+    # Dataset's cached transposed bin matrix
+    "hist_path": ({"level_kernel": str, "feature_groups": int, "route": str,
+                   "front": str, "bins_T_cached": bool}, {}),
     # a jitted program was built (host-side tracing/lowering observed via
     # the function's cache size; device code itself is unchanged)
     "compile": ({"what": str, "cache_size": int},

@@ -300,16 +300,19 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
             # int8 quantized channels, built once per tree; per-shard scales are
             # fine under data-parallel because every histogram is dequantized to
             # f32 before the psum (each shard contributes real-valued mass)
-            quant = (H.make_quant(g, h, c, qseed, const_hess=gp.const_hess)
-                     if gp.quant else None)
+            with jax.named_scope("quant"):
+                quant = (H.make_quant(g, h, c, qseed,
+                                      const_hess=gp.const_hess)
+                         if gp.quant else None)
             # (The segment-packed level-pass experiment that used to live here is
             # archived on branch `archive/packed-levels`: row compaction measured
             # 10-24x slower on this runtime — per-level XLA gathers dominate. See
             # docs/PERF_NOTES.md "negative results".)
-            hist0 = _hist_allreduce(
-                H.hist_leaf(bins, g, h, c, B, gp.hist_impl,
-                            bins_T=bins_T, quant=quant, pack_k=gp.hist_packed),
-                gp, f_dim=1)                                             # [3, F, B]
+            with jax.named_scope("hist0"):
+                hist0 = _hist_allreduce(
+                    H.hist_leaf(bins, g, h, c, B, gp.hist_impl, bins_T=bins_T,
+                                quant=quant, pack_k=gp.hist_packed),
+                    gp, f_dim=1)                                         # [3, F, B]
         g0 = hist0[0, 0].sum()
         h0 = hist0[1, 0].sum()
         c0 = hist0[2, 0].sum()
@@ -822,10 +825,11 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
         if quant is not None and use_pallas:
             from .pallas_hist import hist_pallas_q8
             hq, ch = H._q8_h_arg(quant)
-            ht = hist_pallas_q8(bins_T[lo:hi], quant.gq, hq, quant.cq,
-                                slot, n_slots, B, quant.scale_g,
-                                quant.scale_h, const_hess=ch,
-                                pack_k=gp.hist_packed, interpret=interp)
+            with jax.named_scope("hist"):
+                ht = hist_pallas_q8(bins_T[lo:hi], quant.gq, hq, quant.cq,
+                                    slot, n_slots, B, quant.scale_g,
+                                    quant.scale_h, const_hess=ch,
+                                    pack_k=gp.hist_packed, interpret=interp)
         else:
             ht = H.hist_per_leaf(bins[:, lo:hi], gm, hm, cm, slot, n_slots, B,
                                  gp.hist_impl,
@@ -950,14 +954,8 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
                 member=(res.cat_member & sel[:, None]).astype(jnp.float32)
                 if (sp.cat_features or sp.has_bundles) else None,
             )
-            if use_pallas and f <= 512:
-                from .pallas_hist import route_level_pallas
-                slot, leaf_id2 = route_level_pallas(bins_T, st.leaf_id, tables,
-                                                    na_bin, S_pass, L,
-                                                    interpret=interp)
-            else:
-                slot, leaf_id2 = H.route_level(bins, st.leaf_id, tables, na_bin,
-                                               S_pass)
+            slot, leaf_id2 = H.route_rows(bins, bins_T, st.leaf_id, tables,
+                                          na_bin, S_pass, gp.hist_impl)
 
         with jax.named_scope("apply_level"):
             # ---- monotone bound propagation (shared helper) ----

@@ -203,8 +203,10 @@ def route_level(bins, leaf_id, tables: RouteTables, na_bin, num_slots: int
     feat = jnp.take(tables.feat, leaf_id)
     has = feat >= 0
     fsafe = jnp.maximum(feat, 0)
-    colv = jnp.take_along_axis(bins.astype(jnp.int32), fsafe[:, None],
-                               axis=1)[:, 0]
+    # gather the row's uint8 bin, then widen: widening first would make an
+    # [N, F] int32 copy of the whole matrix (6.4 GB at 800k x 2000)
+    colv = jnp.take_along_axis(bins, fsafe[:, None],
+                               axis=1)[:, 0].astype(jnp.int32)
     nav = jnp.take(na_bin, fsafe)
     is_na = colv == nav
     go_right = jnp.where(is_na, jnp.take(tables.dleft, leaf_id) == 0,
@@ -428,6 +430,14 @@ def dequant_rows(quant: QuantChannels):
     return g, h, c
 
 
+def one_kernel_front(num_features: int, num_bins: int,
+                     impl: str = "auto") -> bool:
+    """Whether grad_quant_hist0 is ONE kernel at this width (the
+    ``hist_path`` event's ``front``), or the grad -> quant -> hist0 chain."""
+    from .pallas_hist import one_group
+    return pick_impl(impl) == "pallas" and one_group(num_features, num_bins)
+
+
 def grad_quant_hist0(bins, score, aux, bag, seed, spec, num_bins,
                      const_hess: bool = False, impl: str = "auto",
                      bins_T=None, pack_k: int = 0):
@@ -443,22 +453,24 @@ def grad_quant_hist0(bins, score, aux, bag, seed, spec, num_bins,
     unfused chain. pack_k > 0 (from pack_guard_bits) packs the hist0
     accumulation into the g/h lattice word — same returns, exactly."""
     impl = pick_impl(impl)
-    from .pallas_hist import _ACC_ROWS_MAX, _grad_rows, grad_quant_hist0_pallas
-    f = bins.shape[1]
-    if impl == "pallas" and f * num_bins <= _ACC_ROWS_MAX:
+    from .pallas_hist import _grad_rows, grad_quant_hist0_pallas
+    if one_kernel_front(bins.shape[1], num_bins, impl):
         interp = jax.default_backend() == "cpu"
         bt = bins_T if bins_T is not None else bins.T
         gq, hq, cq, sg, sh, hist0 = grad_quant_hist0_pallas(
             bt, score, aux, bag, seed, spec, num_bins,
             const_hess=const_hess, pack_k=pack_k, interpret=interp)
         return QuantChannels(gq, hq, cq, sg, sh), hist0
-    grad, hess = _grad_rows(spec, score, aux)
-    g = grad * bag
-    h = hess * bag
-    c = (bag > 0).astype(jnp.float32)
-    quant = make_quant(g, h, c, seed, const_hess=const_hess)
-    hist0 = hist_leaf(bins, g, h, c, num_bins, impl=impl, bins_T=bins_T,
-                      quant=quant)
+    with jax.named_scope("grad"):
+        grad, hess = _grad_rows(spec, score, aux)
+        g = grad * bag
+        h = hess * bag
+        c = (bag > 0).astype(jnp.float32)
+    with jax.named_scope("quant"):
+        quant = make_quant(g, h, c, seed, const_hess=const_hess)
+    with jax.named_scope("hist0"):
+        hist0 = hist_leaf(bins, g, h, c, num_bins, impl=impl, bins_T=bins_T,
+                          quant=quant, pack_k=pack_k)
     return quant, hist0
 
 
@@ -512,6 +524,56 @@ def hist_per_leaf(bins, g, h, c, leaf_id, num_leaves, num_bins, impl="auto",
     return hist_per_leaf_onehot(bins, g, h, c, leaf_id, num_leaves, num_bins)
 
 
+# above this many features the Pallas route kernel's [F, chunk] block would
+# exhaust VMEM (EFB bundling keeps sparse-wide data under it)
+_ROUTE_PALLAS_MAX_F = 512
+
+
+def _router(num_features: int, impl: str) -> str:
+    """The stand-alone row router's implementation at this width."""
+    return ("pallas" if pick_impl(impl) == "pallas"
+            and num_features <= _ROUTE_PALLAS_MAX_F else "xla")
+
+
+def hist_path(num_features: int, num_bins: int, impl: str = "auto",
+              quant: bool = True) -> dict:
+    """The path a depthwise level pass takes at this width: what
+    ``hist_routed`` selects, as the ``hist_path`` event reports it.
+
+    level_kernel: the kernel that builds a level's histograms
+    (``hist_level_q8`` routes in the same launch; ``hist_leaf_q8`` /
+    ``hist_leaf`` run on a (feature group, row chunk) grid after a route pass
+    of its own; off the Pallas path the impl's name); feature_groups: that
+    grid's first axis; route: "fused", or the stand-alone router's
+    implementation ("pallas" | "xla")."""
+    impl = pick_impl(impl)
+    if impl != "pallas":
+        return {"level_kernel": impl, "feature_groups": 1,
+                "route": "xla" if impl == "scatter" else "fused"}
+    from .pallas_hist import feature_grouping, one_group
+    if quant and one_group(num_features, num_bins):
+        return {"level_kernel": "hist_level_q8", "feature_groups": 1,
+                "route": "fused"}
+    return {"level_kernel": "hist_leaf_q8" if quant else "hist_leaf",
+            "feature_groups": feature_grouping(num_features, num_bins)[1],
+            "route": _router(num_features, impl)}
+
+
+def route_rows(bins, bins_T, leaf_id, tables: RouteTables, na_bin,
+               num_slots: int, impl: str = "auto"):
+    """The row router as a pass of its own (device scope ``route``): the
+    Pallas kernel up to ``_ROUTE_PALLAS_MAX_F`` features on the Pallas path,
+    XLA gathers otherwise. Returns (slot, new_leaf_id) as ``route_level``."""
+    with jax.named_scope("route"):
+        if _router(bins.shape[1], impl) == "pallas":
+            from .pallas_hist import route_level_pallas
+            return route_level_pallas(
+                bins_T, leaf_id, tables, na_bin, num_slots,
+                tables.feat.shape[0],
+                interpret=jax.default_backend() == "cpu")
+        return route_level(bins, leaf_id, tables, na_bin, num_slots)
+
+
 def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
                 impl="auto", bins_T=None, quant=None, pack_k: int = 0):
     impl = pick_impl(impl)
@@ -521,11 +583,12 @@ def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
         return hist_routed_scatter(bins, g, h, c, leaf_id, tables, na_bin,
                                    num_slots, num_bins)
     if impl == "pallas":
-        from .pallas_hist import (_ACC_ROWS_MAX, hist_pallas, hist_pallas_q8,
-                                  hist_routed_fused_q8, route_level_pallas)
+        from .pallas_hist import (hist_pallas, hist_pallas_q8,
+                                  hist_routed_fused_q8)
         interp = jax.default_backend() == "cpu"
         bt = bins_T if bins_T is not None else bins.T
-        if quant is not None and bins.shape[1] * num_bins <= _ACC_ROWS_MAX:
+        path = hist_path(bins.shape[1], num_bins, impl, quant is not None)
+        if path["route"] == "fused":
             # single-feature-group data: route + histogram in ONE kernel
             # (one bins read per level instead of two, no [N] slot
             # round-trip; measured 8.3 ms/level for the separate route pass
@@ -536,22 +599,17 @@ def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
                 num_slots, num_bins, quant.scale_g, quant.scale_h,
                 tables.feat.shape[0], const_hess=ch, pack_k=pack_k,
                 interpret=interp)
-        if bins.shape[1] <= 512:
-            slot, lid2 = route_level_pallas(bt, leaf_id, tables, na_bin,
-                                            num_slots, tables.feat.shape[0],
-                                            interpret=interp)
-        else:
-            # wide data: the route kernel's [F, chunk] block would exhaust
-            # VMEM; fall back to the XLA gather route (EFB bundling keeps
-            # training-width under this cap for sparse-wide datasets)
-            slot, lid2 = route_level(bins, leaf_id, tables, na_bin, num_slots)
-        if quant is not None:
-            hq, ch = _q8_h_arg(quant)
-            return hist_pallas_q8(bt, quant.gq, hq, quant.cq, slot,
-                                  num_slots, num_bins, quant.scale_g,
-                                  quant.scale_h, const_hess=ch,
-                                  pack_k=pack_k, interpret=interp), lid2
-        return hist_pallas(bt, g, h, c, slot, num_slots, num_bins,
-                           interpret=interp), lid2
+        slot, lid2 = route_rows(bins, bt, leaf_id, tables, na_bin, num_slots,
+                                impl)
+        # the grouped kernel with its dequantise and transposes
+        with jax.named_scope("hist"):
+            if quant is not None:
+                hq, ch = _q8_h_arg(quant)
+                return hist_pallas_q8(bt, quant.gq, hq, quant.cq, slot,
+                                      num_slots, num_bins, quant.scale_g,
+                                      quant.scale_h, const_hess=ch,
+                                      pack_k=pack_k, interpret=interp), lid2
+            return hist_pallas(bt, g, h, c, slot, num_slots, num_bins,
+                               interpret=interp), lid2
     return hist_routed_onehot(bins, g, h, c, leaf_id, tables, na_bin,
                               num_slots, num_bins)

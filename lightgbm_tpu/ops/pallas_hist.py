@@ -58,6 +58,20 @@ def floor_slot_width(needed: int, max_slots: int) -> int:
     return max_slots
 
 
+def feature_grouping(f: int, b: int):
+    """(features per group, groups): the first grid axis of the grouped
+    kernels (hist_leaf, hist_leaf_q8), each group's fg*b accumulator rows
+    within _ACC_ROWS_MAX. What ops/histogram.hist_path reports."""
+    fg = max(1, min(f, _ACC_ROWS_MAX // b))
+    return fg, -(-f // fg)
+
+
+def one_group(f: int, b: int) -> bool:
+    """Every feature fits one accumulator block: the condition of the
+    kernels that must see all columns (hist_level_q8, grad_quant_hist0)."""
+    return f * b <= _ACC_ROWS_MAX
+
+
 def _kernel(bins_ref, g_ref, h_ref, c_ref, slot_ref, out_ref, *,
             fg: int, b: int, s: int, chunk: int):
     """One (feature-group j, row-chunk i) grid step.
@@ -126,8 +140,7 @@ def hist_pallas(bins_T: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     f, n = bins_T.shape
     b, s = num_bins, num_slots
 
-    fg = max(1, min(f, _ACC_ROWS_MAX // b))
-    n_fg = -(-f // fg)
+    fg, n_fg = feature_grouping(f, b)
     f_pad = n_fg * fg
     if f_pad != f:
         bins_T = jnp.pad(bins_T, ((0, f_pad - f), (0, 0)))
@@ -393,7 +406,7 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
     nch = _q8_nch(const_hess, pack_k)
     if pack_k > 0:
         _assert_pack_budget(n, pack_k, const_hess)
-    fg = max(1, min(f, _ACC_ROWS_MAX // b))
+    fg, n_fg = feature_grouping(f, b)
     if chunk == _CHUNK_Q8:
         # the 4096 default is budgeted for the SWAR one-hot at the bench
         # shape (fg*b = 1792 rows measured fitting VMEM at S=127); wider
@@ -405,7 +418,6 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
         if (not _swar_ok(b, interpret) or fg * b > 1792 or s * nch > 384
                 or pack_k > 0):
             chunk = 2048
-    n_fg = -(-f // fg)
     f_pad = n_fg * fg
     if f_pad != f:
         bins_T = jnp.pad(bins_T, ((0, f_pad - f), (0, 0)))
@@ -599,7 +611,7 @@ def hist_routed_fused_multi_q8(bins_T, gq, hq, cq, leaf_id, tables_seq,
     b, s, l = num_bins, num_slots, num_leaves
     d = len(tables_seq)
     nch = _q8_nch(const_hess, pack_k)
-    assert f * b <= _ACC_ROWS_MAX
+    assert one_group(f, b)
     if pack_k > 0:
         _assert_pack_budget(n, pack_k, const_hess)
     if chunk == 0:
@@ -940,7 +952,7 @@ def grad_quant_hist0_pallas(bins_T, score, aux, bag, seed, spec,
     f, n = bins_T.shape
     b = num_bins
     nch = _q8_nch(const_hess, pack_k)
-    assert f * b <= _ACC_ROWS_MAX
+    assert one_group(f, b)
     if pack_k > 0:
         _assert_pack_budget(n, pack_k, const_hess)
     if chunk == 0:
