@@ -61,10 +61,13 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # level passes take at this width (ops/histogram.hist_path). level_kernel
     # "hist_level_q8" routes and accumulates in one launch; "hist_leaf_q8" /
     # "hist_leaf" run on a (feature_groups, row chunks) grid after a route
-    # pass of its own ("pallas" | "xla"); off the Pallas path the histogram
-    # impl's name. front: whether gradients, quantisation and the root
-    # histogram are one kernel; bins_T_cached: whether the step is fed the
-    # Dataset's cached transposed bin matrix
+    # pass of its own: route "pallas", the route_level kernel over the
+    # level's split columns, at every width. Off the Pallas path
+    # level_kernel is the histogram impl's name and route "xla" (scatter:
+    # route_level's gathers) or "fused" (onehot: routed in its scan). front:
+    # whether gradients, quantisation and the root histogram are one kernel;
+    # bins_T_cached: whether the step is fed the Dataset's cached transposed
+    # bin matrix
     "hist_path": ({"level_kernel": str, "feature_groups": int, "route": str,
                    "front": str, "bins_T_cached": bool}, {}),
     # a jitted program was built (host-side tracing/lowering observed via

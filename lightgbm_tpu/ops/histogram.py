@@ -524,17 +524,6 @@ def hist_per_leaf(bins, g, h, c, leaf_id, num_leaves, num_bins, impl="auto",
     return hist_per_leaf_onehot(bins, g, h, c, leaf_id, num_leaves, num_bins)
 
 
-# above this many features the Pallas route kernel's [F, chunk] block would
-# exhaust VMEM (EFB bundling keeps sparse-wide data under it)
-_ROUTE_PALLAS_MAX_F = 512
-
-
-def _router(num_features: int, impl: str) -> str:
-    """The stand-alone row router's implementation at this width."""
-    return ("pallas" if pick_impl(impl) == "pallas"
-            and num_features <= _ROUTE_PALLAS_MAX_F else "xla")
-
-
 def hist_path(num_features: int, num_bins: int, impl: str = "auto",
               quant: bool = True) -> dict:
     """The path a depthwise level pass takes at this width: what
@@ -545,7 +534,8 @@ def hist_path(num_features: int, num_bins: int, impl: str = "auto",
     ``hist_leaf`` run on a (feature group, row chunk) grid after a route pass
     of its own; off the Pallas path the impl's name); feature_groups: that
     grid's first axis; route: "fused", or the stand-alone router's
-    implementation ("pallas" | "xla")."""
+    implementation ("pallas": the ``route_level`` kernel, at every width on
+    the Pallas path | "xla": ``route_level``'s gathers, off it)."""
     impl = pick_impl(impl)
     if impl != "pallas":
         return {"level_kernel": impl, "feature_groups": 1,
@@ -556,22 +546,32 @@ def hist_path(num_features: int, num_bins: int, impl: str = "auto",
                 "route": "fused"}
     return {"level_kernel": "hist_leaf_q8" if quant else "hist_leaf",
             "feature_groups": feature_grouping(num_features, num_bins)[1],
-            "route": _router(num_features, impl)}
+            "route": "pallas"}
 
 
-def route_rows(bins, bins_T, leaf_id, tables: RouteTables, na_bin,
-               num_slots: int, impl: str = "auto"):
-    """The row router as a pass of its own (device scope ``route``): the
-    Pallas kernel up to ``_ROUTE_PALLAS_MAX_F`` features on the Pallas path,
-    XLA gathers otherwise. Returns (slot, new_leaf_id) as ``route_level``."""
-    with jax.named_scope("route"):
-        if _router(bins.shape[1], impl) == "pallas":
-            from .pallas_hist import route_level_pallas
-            return route_level_pallas(
-                bins_T, leaf_id, tables, na_bin, num_slots,
-                tables.feat.shape[0],
-                interpret=jax.default_backend() == "cpu")
-        return route_level(bins, leaf_id, tables, na_bin, num_slots)
+def _split_columns(bins_T, tables: RouteTables, na_bin, num_slots: int):
+    """The columns one level's splits read, as rows of ``bins_T``: a level
+    never needs the F columns of the matrix, only those of the leaves that
+    split in it, at most ``num_slots`` (one slot a split, two in the lean
+    grower). Returns (cols [K_pad, N] uint8, their na_bin [K_pad], rank [L]:
+    a splitting leaf's row of ``cols``, -1 where ``tables.feat`` is). Two
+    leaves on one feature get a row each; ``cols`` is a transient of
+    K_pad x N bytes (154 MB at 1.2 M rows and 127 slots)."""
+    k_pad = -(-num_slots // 32) * 32                # uint8 sublane tile
+    has = tables.feat >= 0
+    rank = jnp.where(has, jnp.cumsum(has) - 1, -1)
+    col_feat = jnp.zeros(k_pad, jnp.int32).at[
+        jnp.where(has, rank, k_pad)].set(tables.feat, mode="drop")
+    # the rows by a one-hot contraction (no hardware gather: XLA's row gather
+    # copies the whole matrix first and takes twice the time). int8 x int8 ->
+    # int32 is exact: the uint8 bins ride as their int8 bit patterns and the
+    # mask undoes the wrap
+    pick = col_feat[:, None] == jnp.arange(bins_T.shape[0])[None, :]
+    cols = jax.lax.dot_general(
+        pick.astype(jnp.int8), jax.lax.bitcast_convert_type(bins_T, jnp.int8),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
+    return (cols & 0xFF).astype(jnp.uint8), jnp.take(na_bin, col_feat), rank
 
 
 def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
@@ -613,3 +613,20 @@ def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
                                interpret=interp), lid2
     return hist_routed_onehot(bins, g, h, c, leaf_id, tables, na_bin,
                               num_slots, num_bins)
+
+
+def route_rows(bins, bins_T, leaf_id, tables: RouteTables, na_bin,
+               num_slots: int, impl: str = "auto"):
+    """The row router as a pass of its own (device scope ``route``). On the
+    Pallas path the ``route_level`` kernel at every width, its bin block the
+    level's split columns and a leaf's ``feat`` entry its column's row there;
+    off it ``route_level``. Returns (slot, new_leaf_id) as ``route_level``."""
+    with jax.named_scope("route"):
+        if pick_impl(impl) != "pallas":
+            return route_level(bins, leaf_id, tables, na_bin, num_slots)
+        from .pallas_hist import route_level_pallas
+        cols, na_cols, rank = _split_columns(bins_T, tables, na_bin,
+                                             num_slots)
+        return route_level_pallas(
+            cols, leaf_id, tables._replace(feat=rank), na_cols, num_slots,
+            tables.feat.shape[0], interpret=jax.default_backend() == "cpu")

@@ -1160,11 +1160,11 @@ def route_level_pallas(bins_T, leaf_id, tables, na_bin, num_slots: int,
                        interpret: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Pallas DataPartition::Split analog. Returns (slot [N] i32, lid2 [N] i32).
 
-    chunk=0 picks automatically: 2048 for narrow data (+4% end-to-end at 10M
-    measured with the q8 kernel at the same chunk), 1024 when F > 256 — the
-    f32 [F, chunk] per-chunk intermediates double with the chunk, and the
-    caller's F <= 512 VMEM guard (histogram.py hist_routed) was sized for
-    1024."""
+    ``bins_T``: the [F, N] rows that ``tables.feat`` indexes — the level's
+    split columns from histogram.py route_rows, at most 128 of them.
+    chunk=0 picks automatically: _CHUNK_Q8 up to 256 rows (+4% end-to-end at
+    10M measured with the q8 kernel at the same chunk), _CHUNK above — the
+    f32 [F, chunk] per-chunk intermediates double with the chunk."""
     if chunk == 0:
         chunk = _CHUNK_Q8 if bins_T.shape[0] <= 256 else _CHUNK
     f, n = bins_T.shape

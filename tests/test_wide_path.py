@@ -1,9 +1,9 @@
 """The path data wider than one accumulator block takes (F x B > 2,048,
 ``ops/pallas_hist._ACC_ROWS_MAX``): a route pass of its own, the grouped
 histogram kernel ``hist_leaf_q8`` on a (feature group, row chunk) grid, the
-unfused front. Two widths trip the gates from both sides of the router's
-own: 520 features (> 512: XLA gathers route) and 40 features x 63 bins
-(<= 512: the Pallas route kernel). Pallas kernels run interpreted."""
+unfused front. Two widths: 520 features x 15 bins and 40 features x 63 bins.
+The router is the Pallas route kernel at every width, fed the level's split
+columns (``H.route_rows``). Pallas kernels run interpreted."""
 import numpy as np
 import pytest
 
@@ -38,15 +38,8 @@ def _train(X, y, max_bin, impl, **extra):
     return lgb.train(p, lgb.Dataset(X, label=y, params=p), num_boost_round=3)
 
 
-# ---- (a) the whole path through lgb.train against scatter ------------------
-@pytest.mark.parametrize("f,max_bin", WIDTHS)
-def test_train_matches_scatter(f, max_bin):
-    """Pallas (route pass + grouped kernel) and the scatter histograms grow
-    the same trees from the same quantised gradients."""
-    X, y = _data(f)
-    a = _train(X, y, max_bin, "pallas")
-    b = _train(X, y, max_bin, "scatter")
-    assert f * a._gbdt.gp.max_bin > ph._ACC_ROWS_MAX
+def _same_trees(a, b):
+    """Two boosters grew the same three trees; returns the first's."""
     ta, tb = a._ensure_host_trees(), b._ensure_host_trees()
     assert len(ta) == len(tb) == 3
     for t1, t2 in zip(ta, tb):
@@ -62,9 +55,50 @@ def test_train_matches_scatter(f, max_bin):
         np.testing.assert_allclose(np.asarray(t1.leaf_value)[:k],
                                    np.asarray(t2.leaf_value)[:k],
                                    rtol=2e-5, atol=1e-7)
+    return ta
+
+
+# ---- (a) the whole path through lgb.train against scatter ------------------
+@pytest.mark.parametrize("f,max_bin", WIDTHS)
+def test_train_matches_scatter(f, max_bin):
+    """Pallas (route pass + grouped kernel) and the scatter histograms grow
+    the same trees from the same quantised gradients."""
+    X, y = _data(f)
+    a = _train(X, y, max_bin, "pallas")
+    b = _train(X, y, max_bin, "scatter")
+    assert f * a._gbdt.gp.max_bin > ph._ACC_ROWS_MAX
+    ta = _same_trees(a, b)
     used = {int(v) // 32 for t in ta
             for v in np.asarray(t.split_feature)[: t.num_leaves - 1]}
     assert (f - 1) // 32 in used, "no split in the tail feature group"
+
+
+def test_lean_grower_routes_through_the_kernel():
+    """``route_rows``' second caller: the lean depthwise grower (a histogram
+    pool under the frontier's size; two slots a split) grows the same trees
+    on the Pallas path, routed by the kernel, as on scatter."""
+    X, y = _data(40)
+    a, b = (_train(X, y, 63, impl, histogram_pool_size=0.05)
+            for impl in ("pallas", "scatter"))
+    assert a._gbdt.gp.lean_ft > 0 and b._gbdt.gp.lean_ft > 0
+    _same_trees(a, b)
+
+
+@pytest.mark.parametrize("extra", [{}, {"histogram_pool_size": 0.05}],
+                         ids=["default", "lean"])
+def test_sharded_growers_route_through_the_kernel(extra):
+    """Under ``shard_map`` ``bins_T`` is the shard's block and the pick-up
+    of the split columns is local: two row shards put every row on a leaf
+    and split the root where one shard does."""
+    X, y = _data(40)
+    one = _train(X, y, 63, "pallas", **extra)
+    two = _train(X, y, 63, "pallas", num_shards=2, **extra)
+    assert (two._gbdt.gp.lean_ft > 0) == bool(extra)
+    for t1, t2 in zip(one._ensure_host_trees(), two._ensure_host_trees()):
+        k = t2.num_leaves
+        assert k > 1 and int(np.asarray(t2.leaf_count)[:k].sum()) == len(X)
+        assert t1.split_feature[0] == t2.split_feature[0]
+        assert t1.threshold_bin[0] == t2.threshold_bin[0]
 
 
 # ---- (b) the grouped kernel, column by column ------------------------------
@@ -132,7 +166,7 @@ def _route_by_hand(bins, leaf_id, t, na_bin, S):
     return slot, lid
 
 
-@pytest.mark.parametrize("f", [40, 520])
+@pytest.mark.parametrize("f", [40, 520, 2000])
 def test_routers_agree(f):
     rng = np.random.RandomState(9)
     n, L, S = 2500, 8, 4
@@ -145,13 +179,125 @@ def test_routers_agree(f):
     xs, xl = H.route_level(jnp.asarray(bins), *args)
     ps, pl_ = ph.route_level_pallas(jnp.asarray(bins.T.copy()), *args, L,
                                     interpret=True)
-    # what the growers call: the Pallas kernel up to 512 features, XLA above
+    # what the growers call: the kernel over the level's split columns
     rs, rl = H.route_rows(jnp.asarray(bins), jnp.asarray(bins.T.copy()),
                           *args, impl="pallas")
     for slot, lid in ((xs, xl), (ps, pl_), (rs, rl)):
         np.testing.assert_array_equal(np.asarray(lid), want_lid)
         np.testing.assert_array_equal(np.minimum(np.asarray(slot), S),
                                       want_slot)
+
+
+def _case_tables(case, rng, f, L, S):
+    """(tables, num_slots) of one level as the growers make them: leaf
+    ``idx``'s split in slot ``idx`` (lean grower: both children, slots
+    2 idx and 2 idx + 1), the out-of-range slot where no row is measured."""
+    t = dict(feat=np.full(L, -1, np.int32),
+             thr=rng.randint(0, 62, size=L).astype(np.int32),
+             dleft=rng.randint(0, 2, size=L).astype(np.int32),
+             new_leaf=(np.arange(L) + L).astype(np.int32))
+    k = {"no_split_leaves": 2, "k_is_num_slots": S}.get(case, min(L, S) // 2)
+    split = np.sort(rng.choice(L, size=k, replace=False))
+    t["feat"][split] = rng.randint(0, f, size=k)
+    t["feat"][split[0]] = f - 1                     # the matrix's last row
+    if case == "same_feature":
+        t["feat"][split[1:3]] = 7
+    idx = np.zeros(L, np.int32)
+    idx[split] = np.arange(k)
+    if case == "lean_two_slots":
+        S = 2 * S
+        t["slot_left"], t["slot_right"] = 2 * idx, 2 * idx + 1
+    else:                                # the smaller child is measured
+        left = rng.rand(L) < 0.5
+        t["slot_left"] = np.where(left, idx, S)
+        t["slot_right"] = np.where(left, S, idx)
+    for name in ("slot_left", "slot_right"):
+        t[name] = np.where(t["feat"] >= 0, t[name], S).astype(np.int32)
+    if case == "categorical":
+        is_cat = np.zeros(L, np.int32)
+        is_cat[split[::2]] = 1
+        t["is_cat"] = is_cat
+        t["member"] = (rng.rand(L, 256) < 0.4).astype(np.float32)
+    return H.RouteTables(**{n: jnp.asarray(v) for n, v in t.items()}), S
+
+
+@pytest.mark.parametrize("case,f,L,S,n", [
+    ("categorical", 40, 16, 8, 4096 + 17),
+    ("categorical", 2000, 255, 32, 3000),
+    ("missing_both_defaults", 520, 16, 8, 4096 + 17),
+    ("no_split_leaves", 2000, 255, 127, 3000),
+    ("same_feature", 520, 16, 8, 2500),
+    ("lean_two_slots", 40, 16, 8, 2500),
+    ("lean_two_slots", 2000, 64, 32, 2500),
+    ("k_is_num_slots", 520, 64, 32, 4096 + 17),
+    ("k_is_num_slots", 2000, 255, 127, 2 * 4096 + 5),
+])
+def test_route_rows_equals_route_level(case, f, L, S, n):
+    """``route_rows`` on the Pallas path (the route kernel over the level's
+    split columns) gives the integers of ``route_level``, the XLA reference:
+    with categorical splits, missing bins under both defaults, leaves that
+    do not split, two leaves on one feature, the lean grower's two slots a
+    split, as many splits as slots, N off the kernel's chunk."""
+    rng = np.random.RandomState(len(case) * 1000 + f)
+    bins = rng.randint(0, 63, size=(n, f)).astype(np.uint8)
+    bins[:, f - 1] = rng.randint(200, 256, size=n)  # past int8: the pick-up
+    leaf_id = rng.randint(0, L, size=n).astype(np.int32)
+    t, S = _case_tables(case, rng, f, L, S)
+    na_bin = np.where(rng.rand(f) < 0.3, 5, 256).astype(np.int32)
+    if case == "missing_both_defaults":
+        feat, dleft = np.asarray(t.feat), np.asarray(t.dleft)
+        na_bin[feat[feat >= 0]] = 5
+        assert set(dleft[feat >= 0]) == {0, 1}
+        assert (bins[:, feat[feat >= 0]] == 5).any()
+    args = (jnp.asarray(leaf_id), t, jnp.asarray(na_bin), S)
+    want_slot, want_lid = H.route_level(jnp.asarray(bins), *args)
+    slot, lid = H.route_rows(jnp.asarray(bins), jnp.asarray(bins.T.copy()),
+                             *args, impl="pallas")
+    np.testing.assert_array_equal(np.asarray(lid), np.asarray(want_lid))
+    np.testing.assert_array_equal(np.asarray(slot), np.asarray(want_slot))
+    moved = np.asarray(lid) != leaf_id
+    assert moved.any() and not moved.all()
+    if case == "k_is_num_slots":
+        assert int((np.asarray(t.feat) >= 0).sum()) == S
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations call."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_wide_level_pass_gathers_no_row_vector():
+    """The wide level pass (F = 520: route pass + grouped kernel) looks
+    nothing up row by row in XLA: no gather in it has an operand or a result
+    with a dimension of N (the tables are decoded in the route kernel, the
+    level's split columns picked by one [K_pad, F] x [F, N] contraction)."""
+    n, f, L, S = 3000, 520, 8, 4
+    t = _tables(np.random.RandomState(0), f, L, S)
+
+    def level(bins, bins_T, g, h, c, lid, na):
+        quant = H.make_quant(g, h, c, jnp.uint32(1))
+        return H.hist_routed(bins, g, h, c, lid, t, na, S, 16, impl="pallas",
+                             bins_T=bins_T, quant=quant)
+    rows = jnp.zeros(n, jnp.float32)
+    jaxpr = jax.make_jaxpr(level)(
+        jnp.zeros((n, f), jnp.uint8), jnp.zeros((f, n), jnp.uint8), rows,
+        rows, rows, jnp.zeros(n, jnp.int32), jnp.zeros(f, jnp.int32))
+    eqns = list(_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert names.count("pallas_call") == 2          # route_level, hist_leaf_q8
+    bad = [(e.primitive.name, [v.aval.shape for v in e.invars + e.outvars])
+           for e in eqns if "gather" in e.primitive.name
+           and any(n in v.aval.shape for v in e.invars + e.outvars)]
+    assert not bad, bad
+    picks = [e for e in eqns if e.primitive.name == "dot_general"
+             and e.outvars[0].aval.shape == (32, n)]
+    assert len(picks) == 1 and picks[0].invars[1].aval.shape == (f, n)
 
 
 def test_route_level_never_widens_the_matrix():
@@ -170,7 +316,7 @@ def test_route_level_never_widens_the_matrix():
 # ---- (d) the hist_path event on both sides of the gate ---------------------
 @pytest.mark.parametrize("f,max_bin,impl,want", [
     (520, 15, "pallas", {"level_kernel": "hist_leaf_q8", "feature_groups": 17,
-                         "route": "xla", "front": "unfused",
+                         "route": "pallas", "front": "unfused",
                          "bins_T_cached": True}),
     (40, 63, "pallas", {"level_kernel": "hist_leaf_q8", "feature_groups": 2,
                         "route": "pallas", "front": "unfused",
@@ -201,7 +347,8 @@ def test_hist_path_event(f, max_bin, impl, want):
 def test_hist_path_at_the_published_widths():
     """Epsilon (2,000 x 64 padded bins) and HIGGS (28 x 64), from shapes."""
     assert H.hist_path(2000, 64, "pallas") == {
-        "level_kernel": "hist_leaf_q8", "feature_groups": 63, "route": "xla"}
+        "level_kernel": "hist_leaf_q8", "feature_groups": 63,
+        "route": "pallas"}
     assert H.hist_path(28, 64, "pallas") == {
         "level_kernel": "hist_level_q8", "feature_groups": 1,
         "route": "fused"}
