@@ -165,9 +165,6 @@ def device_facts(obs, run):
     check(native.get_lib() is not None, "native fastio library built and loaded")
     check(ds.construct_phases.get("encoder") == "native",
           f"encoder = {ds.construct_phases.get('encoder')}")
-    n_fallback = len(events(obs, "hist_pack_fallback"))
-    print(f"[smoke] hist_pack_fallback events: {n_fallback} (expected at "
-          "this row count; the packed lattice needs n <= 4,095)", flush=True)
     return n_mosaic
 
 

@@ -111,28 +111,21 @@ def measure() -> dict:
         engine.predict(X[:100])
     counts["predict_engine_warm"] = int(n())
 
-    # packed/2-channel q8 surface (ISSUE 20): forced-pallas quantized
-    # training on a regression (const-hessian) workload. At 512 rows the
-    # guard budget fits (k=10), so train_3_iters_q8_packed exercises the
-    # 1-channel packed kernels end to end; train_3_iters_q8_2ch pins the
-    # same surface with packing off (2-channel const-hess elision). Both
-    # are separate step programs from the scatter-path train_3_iters above.
+    # q8 surface: forced-pallas quantized training on a regression
+    # (const-hessian, 2-channel) workload — a separate step program from
+    # the scatter-path train_3_iters above
     yreg = (X[:, 0] * 2.0 + rng.rand(512)).astype(np.float32)
     q8 = {**params, "objective": "regression", "histogram_impl": "pallas",
           "use_quantized_grad": "true"}
     dsq = lgb.Dataset(X, label=yreg, params=q8)
     dsq.construct()
     with jtu.count_jit_and_pmap_lowerings() as n:
-        bstq = lgb.train({**q8, "hist_packed": "true"}, dsq,
-                         num_boost_round=3)
-    counts["train_3_iters_q8_packed"] = int(n())
-    with jtu.count_jit_and_pmap_lowerings() as n:
-        bstq.update()
-        bstq.update()
-    counts["train_warm_extra2_q8_packed"] = int(n())
-    with jtu.count_jit_and_pmap_lowerings() as n:
-        lgb.train({**q8, "hist_packed": "false"}, dsq, num_boost_round=3)
+        bstq = lgb.train(q8, dsq, num_boost_round=3)
     counts["train_3_iters_q8_2ch"] = int(n())
+    with jtu.count_jit_and_pmap_lowerings() as n:
+        bstq.update()
+        bstq.update()
+    counts["train_warm_extra2_q8_2ch"] = int(n())
 
     return counts
 

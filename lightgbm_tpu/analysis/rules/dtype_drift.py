@@ -22,9 +22,9 @@ purpose):
 3. a WIDE-INT device request: a ``jnp`` constructor asked for
    ``int64``/``uint64`` (or ``.astype(jnp.int64)``) — with x64 disabled jax
    silently narrows the result to int32. For plain indices that truncation
-   is usually survivable; for the packed g/h lattice words of
-   ``ops/pallas_hist`` (guard-bit payloads deliberately sized up to bit 30)
-   it corrupts the high bits with no error anywhere. Host-side ``np.int64``
+   is usually survivable; for a word whose high bits carry a payload (a
+   hash, several fields packed into one integer) it corrupts them with no
+   error anywhere. Host-side ``np.int64``
    is NOT flagged — numpy keeps 64 bits; only the jnp-side request lies.
 
 An f64 construction immediately wrapped in ``.astype(np.float32)`` is not
@@ -53,7 +53,7 @@ class DtypeDrift(Rule):
                    "request that x64-disabled jax silently narrows")
     rationale = ("TPU f64 is silently downcast at jnp.asarray; split f64/f32 "
                  "accumulation breaks histogram parity with the reference, "
-                 "and narrowed int64 corrupts packed guard-bit words")
+                 "and narrowed int64 corrupts words packed past bit 31")
 
     def check_module(self, ctx: ModuleContext) -> None:
         if not ctx.jnp_aliases and not ctx.jax_aliases:
@@ -82,8 +82,7 @@ class DtypeDrift(Rule):
                            "stating the precision requirement")
             # wide-int device request: jnp ctor dtype=int64/uint64 (or
             # .astype(jnp.int64)) — x64-disabled jax narrows to int32
-            # silently, which shears the high bits off packed guard-bit
-            # lattice words (ops/pallas_hist packs payloads up to bit 30)
+            # silently, which shears the high bits off any packed word
             if self._is_i64_call(ctx, node) and \
                     not self._astype_cast_parent(ctx, node, _is_i32_expr) and \
                     id(node) not in reported:
@@ -91,7 +90,7 @@ class DtypeDrift(Rule):
                 ctx.report(self, node,
                            "int64/uint64 requested for a device array; "
                            "x64-disabled jax silently narrows to int32 — "
-                           "packed guard-bit words lose their high bits "
+                           "packed words lose their high bits "
                            "with no error; build in int32 (numpy keeps "
                            "64-bit host-side), or suppress with a comment "
                            "stating why the width survives")

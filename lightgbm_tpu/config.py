@@ -162,22 +162,6 @@ _PARAMS: Dict[str, Tuple[Any, Tuple[str, ...]]] = {
     # instead of 5 bf16 — ~3.3x on the dominant contraction; leaf values are
     # renewed from exact sums), "true"/"false" force it
     "use_quantized_grad": ("auto", ()),
-    # packed g/h histogram lattice (Shi et al., Quantized Training of GBDT,
-    # NeurIPS 2022; LightGBM >=4.0 packed gradients): pack the int8 g channel
-    # and the low channel (hq, or count under const-hessian elision) into one
-    # int32 word with guard bits sized to the training row count, halving the
-    # accumulated MXU channels. "auto" engages whenever the quantized pallas
-    # path is active AND the guard-bit budget fits n_rows (else bit-identical
-    # unpacked fallback + a hist_pack_fallback obs event); "true" requests it
-    # explicitly (same fallback rule); "false" disables packing
-    "hist_packed": ("auto", ()),
-    # RETIRED segment-packed depthwise levels (row compaction, the
-    # reference's DataPartition ordering): measured 10-24x SLOWER end-to-end
-    # on a v5e — per-level permutation gathers/scatters
-    # dominate despite the halved histogram work. The implementation is
-    # archived on branch `archive/packed-levels`; the flag stays registered
-    # (accepted, warn-ignored) so old configs don't error.
-    "packed_levels": (False, ()),
     # depthwise is the TPU default: O(depth) histogram passes per tree instead of
     # O(num_leaves) (the reference's leaf-wise semantics are available via
     # grow_policy=lossguide; tree quality is near-identical because depthwise

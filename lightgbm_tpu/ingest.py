@@ -85,8 +85,8 @@ def _device_zeros(shape, dtype, device):
     a full-buffer H2D per shard just to ship zeros)."""
     return _device_zeros_maker(tuple(shape), jnp.dtype(dtype), device)()
 
-# stats of the most recent pipeline run (profiling surface for
-# scripts/profile_ingest.py and the bench); guarded: construct can run from
+# stats of the most recent pipeline run (profiling surface for the bench);
+# guarded: construct can run from
 # a worker thread while a profiler thread reads
 _STATS_LOCK = threading.Lock()
 LAST_INGEST_STATS: Dict[str, Any] = {}

@@ -219,12 +219,6 @@ class GBDT:
             hist_pool=hist_pool,
             lean_ft=lean_ft,
         )
-        if str(config.packed_levels).lower() in ("true", "1"):
-            log.warning(
-                "packed_levels was an experiment falsified on this runtime "
-                "(10-24x slower; see docs/PERF_NOTES.md) and its "
-                "implementation is archived on branch archive/packed-levels; "
-                "the flag is ignored")
         if ((config.tree_learner == "voting"
              or int(getattr(config, "voting_parallel", 0)))
                 and config.grow_policy != "depthwise"):
@@ -746,27 +740,6 @@ class GBDT:
             # per-row hessians are not h_const * bag01.
             import dataclasses
             gp = dataclasses.replace(gp, const_hess=True)
-        from ..ops.histogram import pick_impl
-        mode = str(self.config.hist_packed).lower()
-        if (mode not in ("false", "0") and not custom and gp.quant
-                and pick_impl(gp.hist_impl) == "pallas"):
-            # packed g/h lattice (GrowParams.hist_packed docstring): pack the
-            # g channel with the low channel (hq, or count under const_hess)
-            # into one int32 word when the guard-bit budget fits the training
-            # row count. Resolved HERE, once per booster, from a static row
-            # count — hist_packed bakes into the jit cache key, never retraces.
-            from ..ops.histogram import pack_guard_bits
-            n_rows = int(self.train_set.num_data)
-            pk = pack_guard_bits(n_rows, gp.const_hess)
-            if pk > 0:
-                import dataclasses
-                gp = dataclasses.replace(gp, hist_packed=pk)
-            else:
-                # guard budget exceeded at this row count: fall back to the
-                # unpacked kernels (bit-identical) and record the denial
-                obs.emit("hist_pack_fallback", n_rows=n_rows,
-                         reason="guard_budget", requested=mode,
-                         const_hess=bool(gp.const_hess))
         grow_fn = self._grow_fn()
         bundle = self._bundle_dev
         forced = self._forced_dev

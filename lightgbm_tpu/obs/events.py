@@ -247,12 +247,6 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
                     {"spans": int, "path": str, "error": str}),
     # ObsServer HTTP endpoint lifecycle (obs/http_server.py)
     "obs_server": ({"phase": str}, {"port": int, "error": str}),
-    # packed g/h histogram lattice was requested (hist_packed=true/auto) but
-    # the guard-bit budget doesn't fit the training row count — the booster
-    # fell back to the unpacked q8 kernels (bit-identical, just more MXU
-    # channels). reason: "guard_budget"; requested: the config knob value
-    "hist_pack_fallback": ({"n_rows": int, "reason": str},
-                           {"requested": str, "const_hess": bool}),
 }
 
 

@@ -45,15 +45,6 @@ class GrowParams:
     # auto-gradient step for IsConstantHessian objectives (never for custom
     # gradients / GOSS-amplified channels, where h varies per row)
     const_hess: bool = False
-    # packed g/h lattice (reference: Shi et al., Quantized Training of GBDT,
-    # NeurIPS 2022 — LightGBM >=4.0 packed gradients): number of guard bits k
-    # from ops/histogram.pack_guard_bits. When > 0 the q8 kernels pack the
-    # int8 g lattice and the low channel (hq, or count under const_hess) into
-    # one int32 word g*2^k + low and accumulate both in ONE contraction
-    # channel; the histogram epilogue unpacks exactly (low = P & (2^k - 1),
-    # g = P >> k). 0 = unpacked. Static (baked into the jit cache key via
-    # GrowParams), resolved once per booster from the training row count.
-    hist_packed: int = 0
     # voting-parallel: top-k features elected per level for histogram exchange
     # (reference: VotingParallelTreeLearner, top_k config); 0 = off
     voting_top_k: int = 0

@@ -293,8 +293,7 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
             f_score, f_aux, f_bag = fused
             quant, hist0 = H.grad_quant_hist0(
                 bins, f_score, f_aux, f_bag, qseed, gp.fused_obj, B,
-                const_hess=gp.const_hess, impl=gp.hist_impl, bins_T=bins_T,
-                pack_k=gp.hist_packed)
+                const_hess=gp.const_hess, impl=gp.hist_impl, bins_T=bins_T)
             hist0 = _hist_allreduce(hist0, gp, f_dim=1)
         else:
             # int8 quantized channels, built once per tree; per-shard scales are
@@ -304,14 +303,10 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
                 quant = (H.make_quant(g, h, c, qseed,
                                       const_hess=gp.const_hess)
                          if gp.quant else None)
-            # (The segment-packed level-pass experiment that used to live here is
-            # archived on branch `archive/packed-levels`: row compaction measured
-            # 10-24x slower on this runtime — per-level XLA gathers dominate. See
-            # docs/PERF_NOTES.md "negative results".)
             with jax.named_scope("hist0"):
                 hist0 = _hist_allreduce(
                     H.hist_leaf(bins, g, h, c, B, gp.hist_impl, bins_T=bins_T,
-                                quant=quant, pack_k=gp.hist_packed),
+                                quant=quant),
                     gp, f_dim=1)                                         # [3, F, B]
         g0 = hist0[0, 0].sum()
         h0 = hist0[1, 0].sum()
@@ -535,7 +530,7 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
             )
             hist_pass, leaf_id2 = H.hist_routed(
                 bins, g, h, c, st.leaf_id, tables, na_bin, S_pass, B,
-                gp.hist_impl, bins_T=bins_T, quant=quant, pack_k=gp.hist_packed)
+                gp.hist_impl, bins_T=bins_T, quant=quant)
             if voting:
                 # ---- voting-parallel histogram exchange (PV-Tree; reference:
                 # VotingParallelTreeLearner GlobalVoting + CopyLocalHistogram,
@@ -829,7 +824,7 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
                 ht = hist_pallas_q8(bins_T[lo:hi], quant.gq, hq, quant.cq,
                                     slot, n_slots, B, quant.scale_g,
                                     quant.scale_h, const_hess=ch,
-                                    pack_k=gp.hist_packed, interpret=interp)
+                                    interpret=interp)
         else:
             ht = H.hist_per_leaf(bins[:, lo:hi], gm, hm, cm, slot, n_slots, B,
                                  gp.hist_impl,

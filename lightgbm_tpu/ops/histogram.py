@@ -395,30 +395,6 @@ def _q8_h_arg(quant: QuantChannels):
     return (quant.cq, True) if quant.hq is None else (quant.hq, False)
 
 
-def pack_guard_bits(n_rows: int, const_hess: bool = False) -> int:
-    """Guard-bit budget k for the packed g/h lattice, or 0 when packing
-    cannot be overflow-safe at this row count (callers fall back to the
-    unpacked kernels — bit-identical, just one more MXU channel).
-
-    The packed int32 word is ``w = gq * 2^k + low`` with the low field
-    holding hq (in [0, 127]: hessians of the built-in objectives are
-    non-negative and stochastic rounding preserves the sign) or the 0/1
-    count under const-hessian elision. Exact unpacking of a reduced cell
-    needs the worst-case low-field sum — every row landing in one
-    (slot, feature, bin) cell — to stay below 2^k, and the full packed sum
-    ``127*n*2^k + low_max*n`` to fit int32. Both bounds are against the
-    STATIC row count, so the budget never depends on data values and the
-    fallback decision cannot retrace."""
-    n = int(n_rows)
-    if n <= 0:
-        return 0
-    low_max = 1 if const_hess else 127
-    k = int(low_max * n).bit_length()      # smallest k with low_max*n < 2^k
-    if 127 * n * (1 << k) + low_max * n > (1 << 31) - 1:
-        return 0
-    return k
-
-
 def dequant_rows(quant: QuantChannels):
     """Per-row f32 (g, h, c) for non-pallas backends — the same numbers the
     int32 accumulator would produce, up to f32 summation order. With elided
@@ -440,7 +416,7 @@ def one_kernel_front(num_features: int, num_bins: int,
 
 def grad_quant_hist0(bins, score, aux, bag, seed, spec, num_bins,
                      const_hess: bool = False, impl: str = "auto",
-                     bins_T=None, pack_k: int = 0):
+                     bins_T=None):
     """Fused per-iteration front: objective gradients + SR quantization +
     root histogram in one pass.
 
@@ -450,8 +426,7 @@ def grad_quant_hist0(bins, score, aux, bag, seed, spec, num_bins,
     [3, F, B] f32) — bit-identical to get_gradients -> mask-by-bag ->
     make_quant -> hist_leaf on every backend: the Pallas kernel replays the
     same f32 ops and dither hash, and the non-Pallas fallback below IS that
-    unfused chain. pack_k > 0 (from pack_guard_bits) packs the hist0
-    accumulation into the g/h lattice word — same returns, exactly."""
+    unfused chain."""
     impl = pick_impl(impl)
     from .pallas_hist import _grad_rows, grad_quant_hist0_pallas
     if one_kernel_front(bins.shape[1], num_bins, impl):
@@ -459,7 +434,7 @@ def grad_quant_hist0(bins, score, aux, bag, seed, spec, num_bins,
         bt = bins_T if bins_T is not None else bins.T
         gq, hq, cq, sg, sh, hist0 = grad_quant_hist0_pallas(
             bt, score, aux, bag, seed, spec, num_bins,
-            const_hess=const_hess, pack_k=pack_k, interpret=interp)
+            const_hess=const_hess, interpret=interp)
         return QuantChannels(gq, hq, cq, sg, sh), hist0
     with jax.named_scope("grad"):
         grad, hess = _grad_rows(spec, score, aux)
@@ -470,7 +445,7 @@ def grad_quant_hist0(bins, score, aux, bag, seed, spec, num_bins,
         quant = make_quant(g, h, c, seed, const_hess=const_hess)
     with jax.named_scope("hist0"):
         hist0 = hist_leaf(bins, g, h, c, num_bins, impl=impl, bins_T=bins_T,
-                          quant=quant, pack_k=pack_k)
+                          quant=quant)
     return quant, hist0
 
 
@@ -488,8 +463,7 @@ def pick_impl(requested: str, backend: Optional[str] = None) -> str:
     return "scatter" if backend == "cpu" else "pallas"
 
 
-def hist_leaf(bins, g, h, c, num_bins, impl="auto", bins_T=None, quant=None,
-              pack_k: int = 0):
+def hist_leaf(bins, g, h, c, num_bins, impl="auto", bins_T=None, quant=None):
     impl = pick_impl(impl)
     interp = jax.default_backend() == "cpu"   # tests force impl=pallas on CPU
     if quant is not None and impl == "pallas":
@@ -499,8 +473,7 @@ def hist_leaf(bins, g, h, c, num_bins, impl="auto", bins_T=None, quant=None,
         hq, ch = _q8_h_arg(quant)
         return hist_pallas_q8(bt, quant.gq, hq, quant.cq, slot, 1,
                               num_bins, quant.scale_g, quant.scale_h,
-                              const_hess=ch, pack_k=pack_k,
-                              interpret=interp)[0]
+                              const_hess=ch, interpret=interp)[0]
     if quant is not None:
         g, h, c = dequant_rows(quant)
     if impl == "scatter":
@@ -575,7 +548,7 @@ def _split_columns(bins_T, tables: RouteTables, na_bin, num_slots: int):
 
 
 def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
-                impl="auto", bins_T=None, quant=None, pack_k: int = 0):
+                impl="auto", bins_T=None, quant=None):
     impl = pick_impl(impl)
     if quant is not None and impl != "pallas":
         g, h, c = dequant_rows(quant)
@@ -597,8 +570,7 @@ def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
             return hist_routed_fused_q8(
                 bt, quant.gq, hq, quant.cq, leaf_id, tables, na_bin,
                 num_slots, num_bins, quant.scale_g, quant.scale_h,
-                tables.feat.shape[0], const_hess=ch, pack_k=pack_k,
-                interpret=interp)
+                tables.feat.shape[0], const_hess=ch, interpret=interp)
         slot, lid2 = route_rows(bins, bt, leaf_id, tables, na_bin, num_slots,
                                 impl)
         # the grouped kernel with its dequantise and transposes
@@ -608,7 +580,7 @@ def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
                 return hist_pallas_q8(bt, quant.gq, hq, quant.cq, slot,
                                       num_slots, num_bins, quant.scale_g,
                                       quant.scale_h, const_hess=ch,
-                                      pack_k=pack_k, interpret=interp), lid2
+                                      interpret=interp), lid2
             return hist_pallas(bt, g, h, c, slot, num_slots, num_bins,
                                interpret=interp), lid2
     return hist_routed_onehot(bins, g, h, c, leaf_id, tables, na_bin,

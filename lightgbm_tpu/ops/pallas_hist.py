@@ -42,11 +42,11 @@ _CHUNK_Q8 = 4096
 _ACC_ROWS_MAX = 2048   # Fg*B cap: keeps the f32 accumulator block <= ~6.3MB
 
 # Master slot-width set: every Pallas level pass floors its slot count to one
-# of these widths, so the depthwise default grower, the lean grower and the
-# replay megapass all reuse the same traced kernel programs — fewer distinct
-# widths = fewer lowerings. Over-wide S is free for correctness: extra slots
-# accumulate nothing (no row routes into them) and split selection binds on
-# the per-level budget, not the kernel width.
+# of these widths, so the depthwise default grower and the lean grower reuse
+# the same traced kernel programs — fewer distinct widths = fewer lowerings.
+# Over-wide S is free for correctness: extra slots accumulate nothing (no row
+# routes into them) and split selection binds on the per-level budget, not
+# the kernel width.
 MASTER_SLOT_WIDTHS = (32, 128, 512)
 
 
@@ -208,26 +208,9 @@ def hist_leaf_pallas(bins_T, g, h, c, num_bins: int,
 # accumulator is exact up to ~16M rows/shard per (slot, feature, bin) cell
 # (127 * 16.9M = 2^31), far beyond any real per-cell mass.
 #
-# Packed g/h lattice (Shi et al. §4.2 — the guard-bit packing LightGBM 4.x
-# ships inside quantized training): when ``pack_k > 0`` the int8 g row and
-# the low channel (hq, or the 0/1 count under const-hessian elision) are
-# packed into ONE int32 word ``w = gq * 2^k + low`` with k guard bits sized
-# so a whole per-(slot, feature, bin) cell's low-field sum can never carry
-# into g's field: k = bit_length(low_max * n_rows). The MXU then accumulates
-# ONE packed channel instead of two, and the reduced histogram unpacks
-# exactly:
-#
-#   P = sum(w) = Gsum * 2^k + Lsum   with 0 <= Lsum < 2^k
-#   Lsum = P & (2^k - 1);  Gsum = P >> k   (arithmetic shift = floor
-#   division — exact in two's complement because Lsum never borrows)
-#
-# Channel counts per variant: 3 (plain), 2 (const-hess elision, or packed
-# g+h with a separate count), 1 (packed g+count under const-hess). The
-# packed contraction runs int32 x int32 — widening the 0/1 one-hot is exact
-# — and every int32 op here is replayed identically by the CPU interpreter,
-# so packed-vs-unpacked bit-identity is provable off-TPU.
-# ops/histogram.py pack_guard_bits() owns the overflow budget and returns 0
-# (fall back to the unpacked kernels) when int32 can't hold the worst case.
+# Two weight layouts, chosen by nch alone: (gq, hq, count) int8, and
+# (gq, count) int8 under a constant hessian (the hessian histogram is then
+# count * scale_h/127, see _dequant_stack).
 # ---------------------------------------------------------------------------
 
 def _onehot_i8(bins_i, fg: int, b: int, chunk: int, swar: bool):
@@ -274,26 +257,15 @@ def _swar_ok(b: int, interpret: bool) -> bool:
     return (not interpret) and b % 4 == 0 and b <= 128
 
 
-def _pack_rows_i32(g, low, pack_k: int):
-    """[1, C] int32 packed lattice rows: w = g * 2^k + low (low in [0, 2^k))."""
-    return g * jnp.int32(1 << pack_k) + low
-
-
 def _kernel_q8(bins_ref, gq_ref, hq_ref, c_ref, slot_ref, out_ref, *,
                fg: int, b: int, s: int, chunk: int, nch: int = 3,
-               swar: bool = False, pack_k: int = 0):
+               swar: bool = False):
     """One (feature-group j, row-chunk i) grid step, int8 x int8 -> int32.
 
     bins_ref: [Fg, C] uint8; gq/hq/c_ref: [C] int8; slot_ref: [C] i32;
     out_ref: [Fg*B, S*nch] i32 accumulated across i. nch=2 is the
     constant-hessian variant (channels (gq, count); hq_ref unused — the
-    hessian histogram is count * scale_h/127, reconstructed by the caller).
-
-    pack_k > 0 is the packed g/h lattice (module comment above): the g row
-    and the low channel (hq, or count when nch == 1) fold into one int32
-    word, the contraction runs int32 x int32 and the caller unpacks the
-    accumulated word exactly. nch is then the EFFECTIVE channel count:
-    1 = packed (g, count) under const-hess, 2 = packed (g, h) + count."""
+    hessian histogram is count * scale_h/127, reconstructed by the caller)."""
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -309,11 +281,7 @@ def _kernel_q8(bins_ref, gq_ref, hq_ref, c_ref, slot_ref, out_ref, *,
     # exact)
     g = gq_ref[:].reshape(1, chunk).astype(jnp.int32)
     c = c_ref[:].reshape(1, chunk).astype(jnp.int32)
-    if pack_k > 0:
-        low = c if nch == 1 else hq_ref[:].reshape(1, chunk).astype(jnp.int32)
-        packed = _pack_rows_i32(g, low, pack_k)                 # [1, C] i32
-        ghc = packed if nch == 1 else jnp.concatenate([packed, c], axis=0)
-    elif nch == 3:
+    if nch == 3:
         h = hq_ref[:].reshape(1, chunk).astype(jnp.int32)
         ghc = jnp.concatenate([g, h, c], axis=0)                # [3, C] i32
     else:
@@ -323,60 +291,25 @@ def _kernel_q8(bins_ref, gq_ref, hq_ref, c_ref, slot_ref, out_ref, *,
     slot = slot_ref[:].reshape(1, chunk)
     slot_of_row = jax.lax.broadcasted_iota(
         jnp.int32, (s * nch, chunk), 0) // nch
-    if pack_k > 0:
-        # packed words exceed int8 — keep the weights int32 and widen the
-        # 0/1 one-hot to match (exact; the MXU still contracts one channel
-        # fewer, which is the whole point)
-        w = jnp.where(slot == slot_of_row, w, 0)
-        part = jax.lax.dot_general(
-            onehot.astype(jnp.int32), w,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)                   # [Fg*B, S*nch]
-    else:
-        w = jnp.where(slot == slot_of_row, w, 0).astype(jnp.int8)
-        part = jax.lax.dot_general(
-            onehot, w, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)                   # [Fg*B, S*nch]
+    w = jnp.where(slot == slot_of_row, w, 0).astype(jnp.int8)
+    part = jax.lax.dot_general(
+        onehot, w, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32)                       # [Fg*B, S*nch]
     out_ref[:] += part
 
 
-def _q8_nch(const_hess: bool, pack_k: int) -> int:
-    """Effective MXU channel count for the q8 kernels: 3 plain, 2 const-hess
-    or packed, 1 packed + const-hess."""
-    if pack_k > 0:
-        return 1 if const_hess else 2
+def _q8_nch(const_hess: bool) -> int:
+    """MXU channel count of the q8 kernels: (g, h, count), or (g, count)
+    under a constant hessian."""
     return 2 if const_hess else 3
 
 
-def _assert_pack_budget(n: int, pack_k: int, const_hess: bool) -> None:
-    """Trace-time overflow-safety assert for the packed lattice: the guard
-    field must hold the worst-case per-(slot, feature, bin) low-field sum
-    (every row in one cell) and the packed int32 word sum must fit int32.
-    Callers size pack_k via ops/histogram.py pack_guard_bits, which returns
-    0 when this cannot hold — tripping here means a caller bypassed it."""
-    low_max = 1 if const_hess else 127
-    assert low_max * n < (1 << pack_k), (
-        f"packed-lattice guard bits too small: {pack_k} bits cannot hold "
-        f"low_max*n = {low_max * n}")
-    assert 127 * n * (1 << pack_k) + low_max * n <= (1 << 31) - 1, (
-        f"packed-lattice int32 overflow: n={n} rows at pack_k={pack_k}")
-
-
-def _dequant_stack(out, pack_k: int, const_hess: bool, sg, sh):
+def _dequant_stack(out, const_hess: bool, sg, sh):
     """[..., nch] int32 accumulator -> [..., 3] f32 (g, h, count) channels.
 
-    pack_k > 0 unpacks the packed word exactly (Lsum = P & (2^k-1),
-    Gsum = P >> k — module comment above); const_hess reconstructs the
-    hessian channel as count * sh (sh = scale_h/127 with scale_h =
-    127 * h_const, see ops/histogram.py make_quant). The f32 casts and
-    multiply order match the unpacked path bit-for-bit."""
-    if pack_k > 0:
-        p = out[..., 0]
-        low = (p & jnp.int32((1 << pack_k) - 1)).astype(jnp.float32)
-        gsum = (p >> pack_k).astype(jnp.float32)
-        cnt = low if const_hess else out[..., 1].astype(jnp.float32)
-        hch = cnt * sh if const_hess else low * sh
-        return jnp.stack([gsum * sg, hch, cnt], axis=-1)
+    const_hess reconstructs the hessian channel as count * sh (sh =
+    scale_h/127 with scale_h = 127 * h_const, see ops/histogram.py
+    make_quant)."""
     out = out.astype(jnp.float32)
     if const_hess:
         cnt = out[..., 1]
@@ -388,7 +321,7 @@ def _dequant_stack(out, pack_k: int, const_hess: bool, sg, sh):
 def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
                    cq: jnp.ndarray, slot: jnp.ndarray, num_slots: int,
                    num_bins: int, scale_g, scale_h, chunk: int = _CHUNK_Q8,
-                   const_hess: bool = False, pack_k: int = 0,
+                   const_hess: bool = False,
                    interpret: bool = False) -> jnp.ndarray:
     """Slot-routed histogram from int8-quantized channels.
 
@@ -397,26 +330,18 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
     (traced f32 scalars). Returns [S, 3, F, B] f32 with grad/hess channels
     dequantized (count channel is exact). const_hess drops the in-kernel
     hessian channel (2-channel MXU contraction) and reconstructs it as
-    count * scale_h/127 — exact for h = h_const * bag01 rows. pack_k > 0
-    additionally folds g and the low channel into one packed int32 word
-    (module comment above) — callers size it with ops/histogram.py
-    pack_guard_bits and MUST pass 0 when that returns 0."""
+    count * scale_h/127 — exact for h = h_const * bag01 rows."""
     f, n = bins_T.shape
     b, s = num_bins, num_slots
-    nch = _q8_nch(const_hess, pack_k)
-    if pack_k > 0:
-        _assert_pack_budget(n, pack_k, const_hess)
+    nch = _q8_nch(const_hess)
     fg, n_fg = feature_grouping(f, b)
     if chunk == _CHUNK_Q8:
         # the 4096 default is budgeted for the SWAR one-hot at the bench
         # shape (fg*b = 1792 rows measured fitting VMEM at S=127); wider
         # feature groups (fg*b = 2048 at 700 features: measured 16.75MB,
         # 764KB over the scoped-vmem limit) or the compare path's int32
-        # broadcast intermediates keep the old 2048 chunk. The packed
-        # lattice widens the one-hot operand to int32 (4x the bytes), so it
-        # also keeps the conservative chunk
-        if (not _swar_ok(b, interpret) or fg * b > 1792 or s * nch > 384
-                or pack_k > 0):
+        # broadcast intermediates keep the old 2048 chunk
+        if not _swar_ok(b, interpret) or fg * b > 1792 or s * nch > 384:
             chunk = 2048
     f_pad = n_fg * fg
     if f_pad != f:
@@ -431,8 +356,7 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
     n_chunks = bins_T.shape[1] // chunk
 
     kern = functools.partial(_kernel_q8, fg=fg, b=b, s=s, chunk=chunk,
-                             nch=nch, swar=_swar_ok(b, interpret),
-                             pack_k=pack_k)
+                             nch=nch, swar=_swar_ok(b, interpret))
     out = pl.pallas_call(
         kern,
         name="hist_leaf_q8",
@@ -462,34 +386,25 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
     out = out.reshape(f_pad, b, s, nch)
     sg = scale_g * jnp.float32(1.0 / 127.0)
     sh = scale_h * jnp.float32(1.0 / 127.0)
-    hist = _dequant_stack(out, pack_k, const_hess, sg, sh) \
-        .transpose(2, 3, 0, 1)
+    hist = _dequant_stack(out, const_hess, sg, sh).transpose(2, 3, 0, 1)
     return hist[:, :, :f, :]
 
 
 def _kernel_q8_fused(*refs, f: int, b: int, s: int, l: int, chunk: int,
-                     has_cat: bool, nch: int = 3, swar: bool = False,
-                     d: int = 1, pack_k: int = 0):
+                     has_cat: bool, nch: int = 3, swar: bool = False):
     """Fused route + int8 histogram for ONE feature group (F*B <= block cap).
 
     Per level the two-pass scheme reads the bin matrix twice (route kernel,
     then histogram kernel) and round-trips the [N] slot vector through HBM;
     at 10M rows the route pass alone measured 8.3 ms against the small-S
     histogram floor of ~15 ms. This kernel routes the chunk in-register and
-    feeds the slot straight into the weight mask — one bins read, one launch.
+    feeds the slot straight into the weight mask — one bins read, one launch,
+    one level.
 
-    d > 1 replays SEVERAL consecutive levels in the one launch (the shallow
-    megapass): the leaf id chains through the per-level split tables
-    in-register, each level accumulating into its own [S*nch] column band —
-    one bins read and one launch for the whole shallow stack. The serial
-    hist -> best-split -> route dependency means all d tables must already
-    be known, so d > 1 is a replay (profiling / parity harnesses); d = 1 is
-    the live level pass.
-
-    refs: bins [F, C] u8; gq/hq/cq [C] i8; lid [C] i32; tabs [D*8, L] f32
-    (rows per level: feat, thr, dleft, new_leaf, slot_left, slot_right,
-    is_cat, _); nab [F, 1] f32; [memT [D*B, L] f32 when has_cat]; outputs:
-    out [F*B, D*S*nch] i32 accumulated, lid_out [C] i32.
+    refs: bins [F, C] u8; gq/hq/cq [C] i8; lid [C] i32; tabs [8, L] f32
+    (rows: feat, thr, dleft, new_leaf, slot_left, slot_right, is_cat, _);
+    nab [F, 1] f32; [memT [B, L] f32 when has_cat]; outputs: out
+    [F*B, S*nch] i32 accumulated, lid_out [C] i32.
     """
     if has_cat:
         (bins_ref, gq_ref, hq_ref, cq_ref, lid_ref, tabs_ref, nab_ref,
@@ -512,12 +427,7 @@ def _kernel_q8_fused(*refs, f: int, b: int, s: int, l: int, chunk: int,
     onehot = _onehot_i8(bins_i, f, b, chunk, swar)
     g = gq_ref[:].reshape(1, chunk).astype(jnp.int32)
     c = cq_ref[:].reshape(1, chunk).astype(jnp.int32)
-    if pack_k > 0:   # packed lattice (see _kernel_q8): nch is EFFECTIVE
-        low = c if nch == 1 else hq_ref[:].reshape(1, chunk).astype(jnp.int32)
-        packed = _pack_rows_i32(g, low, pack_k)
-        ghc = packed if nch == 1 else jnp.concatenate([packed, c], axis=0)
-        onehot = onehot.astype(jnp.int32)   # hoisted: shared by all d levels
-    elif nch == 3:
+    if nch == 3:
         h = hq_ref[:].reshape(1, chunk).astype(jnp.int32)
         ghc = jnp.concatenate([g, h, c], axis=0)
     else:   # constant hessian: (gq, count) only
@@ -527,51 +437,45 @@ def _kernel_q8_fused(*refs, f: int, b: int, s: int, l: int, chunk: int,
     slot_of_row = jax.lax.broadcasted_iota(
         jnp.int32, (s * nch, chunk), 0) // nch
 
+    # ---- route (see _route_kernel for the one-hot decode rationale) ----
     lid = lid_ref[:].reshape(1, chunk)
-    for dd in range(d):
-        # ---- route (see _route_kernel for the one-hot decode rationale) ----
-        oh = (lid == iota_l).astype(jnp.float32)                 # [L, C]
-        tv = jax.lax.dot_general(
-            tabs_ref[dd * 8:(dd + 1) * 8, :], oh,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)                 # [8, C]
-        feat, thr, dleft = tv[0:1], tv[1:2], tv[2:3]
-        new_leaf, slot_l, slot_r = tv[3:4], tv[4:5], tv[5:6]
-        fm = iota_f == feat
-        colv = jnp.sum(jnp.where(fm, bins_f, 0.0), axis=0, keepdims=True)
-        nav = jnp.sum(jnp.where(fm, nab_f, 0.0), axis=0, keepdims=True)
-        has = jnp.where(feat >= 0, 1.0, 0.0)
-        is_na = jnp.where(colv == nav, 1.0, 0.0)
-        gr_na = jnp.where(dleft == 0, 1.0, 0.0)
-        gr_num = jnp.where(colv > thr, 1.0, 0.0)
-        go_right = is_na * gr_na + (1.0 - is_na) * gr_num
-        if has_cat:
-            mem_bc = jax.lax.dot_general(
-                memT_ref[dd * b:(dd + 1) * b, :], oh,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)              # [B, C]
-            iota_b1 = jax.lax.broadcasted_iota(jnp.int32, (b, chunk), 0) \
-                .astype(jnp.float32)
-            member = jnp.sum(jnp.where(iota_b1 == colv, mem_bc, 0.0),
-                             axis=0, keepdims=True)
-            iscat = tv[6:7]
-            go_right = iscat * (1.0 - member) + (1.0 - iscat) * go_right
-        lid2 = jnp.where(has * go_right > 0, new_leaf, lid)
-        slot_f = has * (go_right * slot_r + (1.0 - go_right) * slot_l) \
-            + (1.0 - has) * float(s)
-        slot = jnp.minimum(slot_f.astype(jnp.int32), s)          # [1, C]
+    oh = (lid == iota_l).astype(jnp.float32)                     # [L, C]
+    tv = jax.lax.dot_general(
+        tabs_ref[:], oh, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)                     # [8, C]
+    feat, thr, dleft = tv[0:1], tv[1:2], tv[2:3]
+    new_leaf, slot_l, slot_r = tv[3:4], tv[4:5], tv[5:6]
+    fm = iota_f == feat
+    colv = jnp.sum(jnp.where(fm, bins_f, 0.0), axis=0, keepdims=True)
+    nav = jnp.sum(jnp.where(fm, nab_f, 0.0), axis=0, keepdims=True)
+    has = jnp.where(feat >= 0, 1.0, 0.0)
+    is_na = jnp.where(colv == nav, 1.0, 0.0)
+    gr_na = jnp.where(dleft == 0, 1.0, 0.0)
+    gr_num = jnp.where(colv > thr, 1.0, 0.0)
+    go_right = is_na * gr_na + (1.0 - is_na) * gr_num
+    if has_cat:
+        mem_bc = jax.lax.dot_general(
+            memT_ref[:], oh, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # [B, C]
+        iota_b1 = jax.lax.broadcasted_iota(jnp.int32, (b, chunk), 0) \
+            .astype(jnp.float32)
+        member = jnp.sum(jnp.where(iota_b1 == colv, mem_bc, 0.0),
+                         axis=0, keepdims=True)
+        iscat = tv[6:7]
+        go_right = iscat * (1.0 - member) + (1.0 - iscat) * go_right
+    lid2 = jnp.where(has * go_right > 0, new_leaf, lid)
+    slot_f = has * (go_right * slot_r + (1.0 - go_right) * slot_l) \
+        + (1.0 - has) * float(s)
+    slot = jnp.minimum(slot_f.astype(jnp.int32), s)              # [1, C]
 
-        # ---- int8 histogram (see _kernel_q8 / _onehot_i8) ----
-        w = jnp.where(slot == slot_of_row, wv, 0)
-        if pack_k == 0:
-            w = w.astype(jnp.int8)
-        part = jax.lax.dot_general(
-            onehot, w, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)
-        out_ref[:, dd * s * nch:(dd + 1) * s * nch] += part
-        lid = lid2.astype(jnp.int32)
-    lid_out[:] = lid.reshape(chunk)
+    # ---- int8 histogram (see _kernel_q8 / _onehot_i8) ----
+    w = jnp.where(slot == slot_of_row, wv, 0).astype(jnp.int8)
+    part = jax.lax.dot_general(
+        onehot, w, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32)
+    out_ref[:] += part
+    lid_out[:] = lid2.astype(jnp.int32).reshape(chunk)
 
 
 def _route_tabs(tables, l: int) -> jnp.ndarray:
@@ -587,46 +491,33 @@ def _route_tabs(tables, l: int) -> jnp.ndarray:
         iscat_row, jnp.zeros(l, jnp.float32)])                    # [8, L]
 
 
-def hist_routed_fused_multi_q8(bins_T, gq, hq, cq, leaf_id, tables_seq,
-                               na_bin, num_slots: int, num_bins: int,
-                               scale_g, scale_h, num_leaves: int,
-                               chunk: int = 0, const_hess: bool = False,
-                               pack_k: int = 0, interpret: bool = False):
-    """Multi-level fused route+histogram megapass.
-
-    ``tables_seq``: sequence of D per-level RouteTables. ONE kernel launch
-    routes every row through all D consecutive levels, accumulating each
-    level's slot histogram into its own column band. Returns
-    (hist [D, S, 3, F, B] f32, lid_final [N] i32), bit-identical to D
-    sequential hist_routed_fused_q8 calls (int32 accumulation is
-    order-independent; the routing arithmetic is the same ops in the same
-    order). D=1 is the live level pass; D>1 requires all D split tables up
-    front — a replay — because split selection at level d depends on the
-    reduced histogram of level d-1 (see PERF_NOTES Round 9).
+def hist_routed_fused_q8(bins_T, gq, hq, cq, leaf_id, tables, na_bin,
+                         num_slots: int, num_bins: int, scale_g, scale_h,
+                         num_leaves: int, chunk: int = 0,
+                         const_hess: bool = False, interpret: bool = False):
+    """Fused route+histogram level pass: ONE kernel launch routes every row
+    through the level's splits and accumulates the slot histograms. Returns
+    (hist [S, 3, F, B] f32, lid2 [N] i32), bit-identical to route_level
+    followed by hist_pallas_q8 (int32 accumulation is order-independent;
+    the routing arithmetic is the same ops in the same order).
 
     Only valid when every feature fits one accumulator block
     (F * num_bins <= _ACC_ROWS_MAX) — the router must see ALL columns.
-    const_hess / pack_k: see hist_pallas_q8."""
+    const_hess: see hist_pallas_q8."""
     f, n = bins_T.shape
     b, s, l = num_bins, num_slots, num_leaves
-    d = len(tables_seq)
-    nch = _q8_nch(const_hess, pack_k)
+    nch = _q8_nch(const_hess)
     assert one_group(f, b)
-    if pack_k > 0:
-        _assert_pack_budget(n, pack_k, const_hess)
     if chunk == 0:
         # doubled chunk halves per-chunk fixed costs; the SWAR int8
         # one-hot keeps 4096 under the 16MB VMEM ceiling through S=127
         # (measured 35 -> 31.7 ms at S=127). Without SWAR (B > 128 or
         # interpret) the compare path's wider intermediates keep the old
-        # 192-row threshold. The accumulator band is D levels wide. The
-        # packed lattice widens the one-hot to int32 (4x bytes): keep the
-        # conservative chunk there too
+        # 192-row threshold
         wide_ok = 384 if (_swar_ok(b, interpret) and f * b <= 1792) else 192
-        chunk = 4096 if (d * s * nch <= wide_ok and pack_k == 0) else 2048
+        chunk = 4096 if s * nch <= wide_ok else 2048
 
-    has_cat = any(t.is_cat is not None for t in tables_seq)
-    tabs = jnp.concatenate([_route_tabs(t, l) for t in tables_seq], axis=0)
+    has_cat = tables.is_cat is not None
     nab = na_bin.astype(jnp.float32).reshape(f, 1)
 
     bins_Tp = _pad_rows(bins_T, chunk)
@@ -642,69 +533,45 @@ def hist_routed_fused_multi_q8(bins_T, gq, hq, cq, leaf_id, tables_seq,
         pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
         pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
         pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
-        pl.BlockSpec((d * 8, l), lambda i: (0, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((8, l), lambda i: (0, 0), memory_space=pltpu.VMEM),
         pl.BlockSpec((f, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
     ]
-    args = [bins_Tp, gq, hq, cq, lid_p, tabs, nab]
+    args = [bins_Tp, gq, hq, cq, lid_p, _route_tabs(tables, l), nab]
     if has_cat:
-        b_mem = next(t.member.shape[1] for t in tables_seq
-                     if t.member is not None)
-
-        def _memT(t):
-            if t.member is None:
-                return jnp.zeros((b_mem, l), jnp.float32)
-            return t.member.astype(jnp.float32).T
-        in_specs.append(pl.BlockSpec((d * b_mem, l), lambda i: (0, 0),
+        in_specs.append(pl.BlockSpec((tables.member.shape[1], l),
+                                     lambda i: (0, 0),
                                      memory_space=pltpu.VMEM))
-        args.append(jnp.concatenate([_memT(t) for t in tables_seq], axis=0))
+        args.append(tables.member.astype(jnp.float32).T)
 
     kern = functools.partial(_kernel_q8_fused, f=f, b=b, s=s, l=l,
                              chunk=chunk, has_cat=has_cat, nch=nch,
-                             swar=_swar_ok(b, interpret), d=d, pack_k=pack_k)
+                             swar=_swar_ok(b, interpret))
     out, lid2 = pl.pallas_call(
         kern,
         name="hist_level_q8",
         grid=(n_chunks,),
         in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((f * b, d * s * nch), lambda i: (0, 0),
+            pl.BlockSpec((f * b, s * nch), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((f * b, d * s * nch), jnp.int32),
+            jax.ShapeDtypeStruct((f * b, s * nch), jnp.int32),
             jax.ShapeDtypeStruct((bins_Tp.shape[1],), jnp.int32),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=d * (2 * n * f * b * s * nch + 2 * n * l * 9),
-            bytes_accessed=n * (f + 11) + d * f * b * s * 4 * nch,
+            flops=2 * n * f * b * s * nch + 2 * n * l * 9,
+            bytes_accessed=n * (f + 11) + f * b * s * 4 * nch,
             transcendentals=0),
         interpret=interpret,
     )(*args)
 
-    out = out.reshape(f, b, d, s, nch)
+    out = out.reshape(f, b, s, nch)
     sg = scale_g * jnp.float32(1.0 / 127.0)
     sh = scale_h * jnp.float32(1.0 / 127.0)
-    hist = _dequant_stack(out, pack_k, const_hess, sg, sh) \
-        .transpose(2, 3, 4, 0, 1)
+    hist = _dequant_stack(out, const_hess, sg, sh).transpose(2, 3, 0, 1)
     return hist, lid2[:n]
-
-
-def hist_routed_fused_q8(bins_T, gq, hq, cq, leaf_id, tables, na_bin,
-                         num_slots: int, num_bins: int, scale_g, scale_h,
-                         num_leaves: int, chunk: int = 0,
-                         const_hess: bool = False, pack_k: int = 0,
-                         interpret: bool = False):
-    """Fused route+histogram level pass. Returns ([S, 3, F, B] f32, lid2 [N]).
-
-    The D=1 specialization of hist_routed_fused_multi_q8 — the live level
-    pass and the replay megapass share one traced program per shape, so
-    they cost a single lowering between them."""
-    hist, lid2 = hist_routed_fused_multi_q8(
-        bins_T, gq, hq, cq, leaf_id, (tables,), na_bin, num_slots, num_bins,
-        scale_g, scale_h, num_leaves, chunk=chunk, const_hess=const_hess,
-        pack_k=pack_k, interpret=interpret)
-    return hist[0], lid2
 
 
 def _leaf_sums_kernel(g_ref, h_ref, c_ref, lid_ref, out_ref, *,
@@ -825,7 +692,7 @@ def _grad_rows(spec, score, aux):
 def _grad_quant_kernel(bins_ref, score_ref, aux_ref, bag_ref, seed_ref,
                        gq_ref, hq_ref, cq_ref, sc_ref, out_ref, mx_ref, *,
                        f: int, b: int, chunk: int, spec,
-                       const_hess: bool, swar: bool, pack_k: int = 0):
+                       const_hess: bool, swar: bool):
     """Two-phase fused gradient + SR-quantization + root histogram.
 
     grid (2, n_chunks) — the TPU grid runs the trailing axis innermost, so
@@ -838,10 +705,7 @@ def _grad_quant_kernel(bins_ref, score_ref, aux_ref, bag_ref, seed_ref,
     bins [F, C] u8; score/aux/bag [C] f32; seed (1, 1) i32 SMEM; outputs
     gq/hq/cq [C] i8, sc (8, 128) f32 (row 0 lane 0 = scale_g, row 1 lane 0 =
     scale_h), out [F*B, nch] i32; scratch mx (2, 128) f32 lane-max partials.
-    pack_k > 0 packs the hist0 weight rows into the g/h lattice word
-    (see _kernel_q8) — the emitted gq/hq/cq row channels are unchanged.
     """
-    nch = _q8_nch(const_hess, pack_k)
     p = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -899,65 +763,43 @@ def _grad_quant_kernel(bins_ref, score_ref, aux_ref, bag_ref, seed_ref,
         cq_ref[:] = cw.astype(jnp.int8).reshape(chunk)
         if const_hess:
             hq_ref[:] = jnp.zeros_like(hq_ref)
-            if pack_k > 0:
-                w3 = _pack_rows_i32(gq.astype(jnp.int32),
-                                    cw.astype(jnp.int32), pack_k)
-            else:
-                w3 = jnp.concatenate([gq.astype(jnp.int32),
-                                      cw.astype(jnp.int32)], axis=0)
+            w3 = jnp.concatenate([gq.astype(jnp.int32),
+                                  cw.astype(jnp.int32)], axis=0)
         else:
             uh = _sr_dither(idx, seed, 2)
             hq = jnp.clip(jnp.floor(h * (127.0 / scale_h) + uh), -127, 127)
             hq_ref[:] = hq.astype(jnp.int8).reshape(chunk)
-            if pack_k > 0:
-                w3 = jnp.concatenate([
-                    _pack_rows_i32(gq.astype(jnp.int32),
-                                   hq.astype(jnp.int32), pack_k),
-                    cw.astype(jnp.int32)], axis=0)
-            else:
-                w3 = jnp.concatenate([gq.astype(jnp.int32),
-                                      hq.astype(jnp.int32),
-                                      cw.astype(jnp.int32)], axis=0)
+            w3 = jnp.concatenate([gq.astype(jnp.int32),
+                                  hq.astype(jnp.int32),
+                                  cw.astype(jnp.int32)], axis=0)
         bins_i = bins_ref[:].astype(jnp.int32)
         onehot = _onehot_i8(bins_i, f, b, chunk, swar)
-        if pack_k > 0:   # int32 weights: widen the 0/1 one-hot (exact)
-            part = jax.lax.dot_general(
-                onehot.astype(jnp.int32), w3,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32)                 # [F*B, nch]
-        else:
-            part = jax.lax.dot_general(
-                onehot, w3.astype(jnp.int8),
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32)                 # [F*B, nch]
+        part = jax.lax.dot_general(
+            onehot, w3.astype(jnp.int8),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32)                     # [F*B, nch]
         out_ref[:] += part
 
 
 def grad_quant_hist0_pallas(bins_T, score, aux, bag, seed, spec,
                             num_bins: int, const_hess: bool = False,
-                            pack_k: int = 0, chunk: int = 0,
-                            interpret: bool = False):
+                            chunk: int = 0, interpret: bool = False):
     """Fused objective gradient + int8 quantization + root histogram.
 
     Returns (gq [N] i8, hq [N] i8 | None, cq [N] i8, scale_g f32 scalar,
     scale_h f32 scalar, hist0 [3, F, B] f32) — bit-identical to the unfused
     objective.get_gradients -> make_quant -> hist_leaf chain on the Pallas
     path (f32 max is order-independent, the dither hash is replayed exactly,
-    and the int32 histogram accumulation is order-independent). pack_k > 0
-    packs the hist0 accumulation into the g/h lattice word (see
-    hist_pallas_q8); the emitted row channels are identical either way.
+    and the int32 histogram accumulation is order-independent).
 
     Only valid when every feature fits one accumulator block
     (F * num_bins <= _ACC_ROWS_MAX)."""
     f, n = bins_T.shape
     b = num_bins
-    nch = _q8_nch(const_hess, pack_k)
+    nch = _q8_nch(const_hess)
     assert one_group(f, b)
-    if pack_k > 0:
-        _assert_pack_budget(n, pack_k, const_hess)
     if chunk == 0:
-        chunk = 4096 if (_swar_ok(b, interpret) and f * b <= 1792
-                         and pack_k == 0) else 2048
+        chunk = 4096 if (_swar_ok(b, interpret) and f * b <= 1792) else 2048
     bins_Tp = _pad_rows(bins_T, chunk)
     score_p = _pad_rows(score, chunk)
     aux_p = _pad_rows(aux, chunk)
@@ -967,7 +809,7 @@ def grad_quant_hist0_pallas(bins_T, score, aux, bag, seed, spec,
 
     kern = functools.partial(_grad_quant_kernel, f=f, b=b, chunk=chunk,
                              spec=spec, const_hess=const_hess,
-                             swar=_swar_ok(b, interpret), pack_k=pack_k)
+                             swar=_swar_ok(b, interpret))
     gq, hq, cq, sc, out = pl.pallas_call(
         kern,
         name="grad_quant_hist0",
@@ -1016,7 +858,7 @@ def grad_quant_hist0_pallas(bins_T, score, aux, bag, seed, spec,
     out = out.reshape(f, b, nch)
     sg = scale_g * jnp.float32(1.0 / 127.0)
     sh = scale_h * jnp.float32(1.0 / 127.0)
-    hist0 = _dequant_stack(out, pack_k, const_hess, sg, sh).transpose(2, 0, 1)
+    hist0 = _dequant_stack(out, const_hess, sg, sh).transpose(2, 0, 1)
     return (gq[:n], None if const_hess else hq[:n], cq[:n],
             scale_g, scale_h, hist0)
 
@@ -1170,14 +1012,7 @@ def route_level_pallas(bins_T, leaf_id, tables, na_bin, num_slots: int,
     f, n = bins_T.shape
     l, s = num_leaves, num_slots
     has_cat = tables.is_cat is not None
-    iscat_row = (tables.is_cat.astype(jnp.float32) if has_cat
-                 else jnp.zeros(l, jnp.float32))
-    tabs = jnp.stack([
-        tables.feat.astype(jnp.float32), tables.thr.astype(jnp.float32),
-        tables.dleft.astype(jnp.float32), tables.new_leaf.astype(jnp.float32),
-        tables.slot_left.astype(jnp.float32),
-        tables.slot_right.astype(jnp.float32),
-        iscat_row, jnp.zeros(l, jnp.float32)])                    # [8, L]
+    tabs = _route_tabs(tables, l)
     nab = na_bin.astype(jnp.float32).reshape(f, 1)
 
     bins_Tp = _pad_rows(bins_T, chunk)

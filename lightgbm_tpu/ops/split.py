@@ -225,10 +225,9 @@ def best_split(hist: jnp.ndarray, num_bins: jnp.ndarray, na_bin: jnp.ndarray,
     hist: [..., 3, F, B] channel-major (grad, hess, count); num_bins: [F] i32
     actual bins per feature; na_bin: [F] i32 missing-bin index (or >= B if
     none).  The hess channel is ALWAYS materialized here even when the q8
-    kernels elide it (const-hessian) or pack it with g (packed lattice): the
-    histogram epilogue reconstructs h as ``hess_scale * count`` / unpacks the
-    lattice word before this function sees the array, so split evaluation is
-    variant-agnostic (ops/pallas_hist._dequant_stack).
+    kernels elide it (const-hessian): the histogram epilogue reconstructs h
+    as ``hess_scale * count`` before this function sees the array, so split
+    evaluation is variant-agnostic (ops/pallas_hist._dequant_stack).
     feature_mask: [F] bool, or per-leaf [*batch, F] bool (voting mode:
     each frontier leaf may only search features its stored histogram holds);
     parent_g/h/cnt and allow_split broadcast over the leading batch dims.

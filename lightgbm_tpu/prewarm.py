@@ -53,7 +53,7 @@ _SPEC_KEYS = (
     "objective", "num_class", "boosting", "sigmoid", "alpha", "fair_c",
     "poisson_max_delta_step", "tweedie_variance_power", "is_unbalance",
     "scale_pos_weight", "reg_sqrt", "boost_from_average", "grow_policy",
-    "histogram_impl", "use_quantized_grad", "hist_packed", "hist_dtype",
+    "histogram_impl", "use_quantized_grad", "hist_dtype",
     "nonfinite_policy",
     "tree_learner", "top_k", "label_gain", "lambdarank_truncation_level",
     "lambdarank_norm", "histogram_pool_size", "forcedsplits_filename",

@@ -152,8 +152,7 @@ def time_op_in_jit(op, *big, K: int = 6, reps: int = 1):
     XLA hoists the op out of the loop and the measurement reads ~0. The
     large arrays MUST be passed via ``*big`` (closure constants are embedded
     in the compiled program).
-    Returns milliseconds per op. Shared by bench.py's phase breakdown and
-    the scripts/profile_* tools."""
+    Returns milliseconds per op. Used by bench.py's phase breakdown."""
     import time as _time
     from functools import partial as _partial
     import jax

@@ -80,9 +80,8 @@ PROBE_ENTRIES = {"dataset_construct", "train_3_iters", "predict_cold",
                  "predict_warm_repeat", "train_3_iters_lossguide",
                  "train_warm_extra2_dart", "train_warm_extra2_goss",
                  "train_warm_extra2_rf", "predict_engine_warm",
-                 # packed / 2-channel q8 kernels (ISSUE 20)
-                 "train_3_iters_q8_packed", "train_warm_extra2_q8_packed",
-                 "train_3_iters_q8_2ch",
+                 # forced-pallas quantised training (2-channel q8 kernels)
+                 "train_3_iters_q8_2ch", "train_warm_extra2_q8_2ch",
                  # pod surface (the --multihost probe pass)
                  "train_3_iters_pod2d", "train_warm_extra2_pod2d",
                  "train_3_iters_voting", "train_warm_extra2_voting"}
@@ -102,13 +101,12 @@ def test_warmed_entries_budgeted_at_zero():
     for name in ("predict_warm_repeat", "train_warm_extra2_dart",
                  "train_warm_extra2_goss", "train_warm_extra2_rf",
                  "predict_engine_warm", "train_warm_extra2_pod2d",
-                 "train_warm_extra2_voting", "train_warm_extra2_q8_packed"):
+                 "train_warm_extra2_voting", "train_warm_extra2_q8_2ch"):
         assert committed.get(name) == 0, name
 
 
 def test_flat_train_budget_preserved():
-    """ISSUE 20 acceptance: adding the packed/2-channel kernel variants must
-    not grow the flat train budget."""
+    """The q8 kernel variants must not grow the flat train budget."""
     committed = cb.load_budget()
     assert committed.get("train_3_iters") <= 11
 

@@ -1,5 +1,5 @@
-"""Fused megapass front (grad+quant+hist0), multi-level replay kernel and
-the warm-path zero-compile guarantees.
+"""The one-kernel front (grad+quant+hist0) and the warm-path zero-compile
+guarantees.
 
 Kernel parity runs the pallas kernels in interpret mode on CPU and asserts
 BIT-exact agreement with the unfused reference chain — the fused front's
@@ -96,40 +96,6 @@ def test_leaf_sums_grad_bit_exact(rows):
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
 
-def test_multi_level_replay_bit_exact_vs_sequential(rows):
-    """ONE hist_routed_fused_multi_q8 launch over D stacked tables must
-    reproduce D sequential single-level passes exactly — histograms per
-    level AND the final row routing."""
-    bag = rows["bag"]
-    grad, hess = _logloss_gh(rows["score"], rows["label_pos"])
-    c = (bag > 0).astype(jnp.float32)
-    q = hg.make_quant(grad * bag, hess * bag, c, SEED, const_hess=False)
-    S = 4
-
-    def mk_tables(key):
-        r = np.random.default_rng(key)
-        mk = lambda lo, hi: jnp.asarray(r.integers(lo, hi, size=L),
-                                        dtype=jnp.int32)
-        return hg.RouteTables(mk(0, F), mk(1, B - 1), mk(0, 2), mk(0, L),
-                              mk(0, S), mk(0, S))
-
-    tabs = [mk_tables(k) for k in (1, 2, 3)]
-    lid_seq = rows["lid"]
-    hists_seq = []
-    for t in tabs:
-        hh, lid_seq = ph.hist_routed_fused_q8(
-            rows["bins_T"], q.gq, q.hq, q.cq, lid_seq, t, rows["na_bin"],
-            S, B, q.scale_g, q.scale_h, L, interpret=True)
-        hists_seq.append(hh)
-    hist_multi, lid_multi = ph.hist_routed_fused_multi_q8(
-        rows["bins_T"], q.gq, q.hq, q.cq, rows["lid"], tuple(tabs),
-        rows["na_bin"], S, B, q.scale_g, q.scale_h, L, interpret=True)
-    np.testing.assert_array_equal(np.asarray(lid_seq), np.asarray(lid_multi))
-    for d in range(len(tabs)):
-        np.testing.assert_array_equal(np.asarray(hists_seq[d]),
-                                      np.asarray(hist_multi[d]))
-
-
 # ---------------------------------------------------------------------------
 # end-to-end: whole models bit-identical with the fused front on vs off
 
@@ -146,13 +112,18 @@ PALLAS_PARAMS = {"num_leaves": 7, "max_bin": 31, "min_data_in_leaf": 5,
                  "use_quantized_grad": "true"}
 
 
+@pytest.mark.parametrize("boosting,extra", [
+    ("gbdt", {}), ("dart", {"skip_drop": 0.0, "drop_rate": 0.5})],
+    ids=["gbdt", "dart"])
 @pytest.mark.parametrize("objective,objcls", [("binary", "Binary"),
                                               ("regression", "RegressionL2")])
-def test_fused_front_models_bit_identical(monkeypatch, objective, objcls):
+def test_fused_front_models_bit_identical(monkeypatch, objective, objcls,
+                                          boosting, extra):
     import lightgbm_tpu.objectives as O
     X, yb, yr = _train_data()
     y = yb if objective == "binary" else yr
-    params = dict(PALLAS_PARAMS, objective=objective)
+    params = dict(PALLAS_PARAMS, objective=objective, boosting=boosting,
+                  **extra)
 
     def run():
         bst = lgb.train(params, lgb.Dataset(X, label=y, params=params),

@@ -320,8 +320,9 @@ def _train_valid(rounds=3, **extra):
 
 def test_step_jaxpr_names_kernels_and_scopes(monkeypatch):
     """The traced step holds a pallas_call under every kernel name of the
-    path and every stage's scope in its name stacks (interpret mode, the
-    small shape of test_hist_packed)."""
+    path and every stage's scope in its name stacks, and the q8 kernels
+    contract int8 x int8 as on the chip (interpret mode, the small shape of
+    test_q8_kernels)."""
     import jax
     from jax._src import core
     from lightgbm_tpu.models import gbdt
@@ -344,17 +345,25 @@ def test_step_jaxpr_names_kernels_and_scopes(monkeypatch):
               "histogram_impl": "pallas", "use_quantized_grad": "true"}
     lgb.train(params, lgb.Dataset(X, label=y, params=params),
               num_boost_round=1)
-    kernels, scopes = set(), set()
+    kernels, scopes, dots = set(), set(), {}
 
-    def walk(jaxpr):
+    def walk(jaxpr, kernel=None):
         for eqn in jaxpr.eqns:
             scopes.update(str(eqn.source_info.name_stack).split("/"))
+            inside = kernel
             if eqn.primitive.name == "pallas_call":
-                kernels.add(eqn.params["name"])
+                inside = eqn.params["name"]
+                kernels.add(inside)
+            if eqn.primitive.name == "dot_general" and kernel:
+                dots.setdefault(kernel, []).append(
+                    tuple(str(v.aval.dtype) for v in eqn.invars))
             for sub in core.jaxprs_in_params(eqn.params):
-                walk(sub)
+                walk(sub, inside)
     walk(seen["jaxpr"].jaxpr)
     assert kernels == STEP_KERNELS
+    for name in ("hist_level_q8", "grad_quant_hist0"):
+        assert ("int8", "int8") in dots[name]
+        assert not any("int32" in d for d in dots[name]), dots[name]
     assert STEP_SCOPES <= scopes
     assert any(s.startswith("level_s") for s in scopes)
 

@@ -1,0 +1,217 @@
+"""The int8 quantised-gradient kernels at one feature group (F x B <= 2,048,
+``ops/pallas_hist._ACC_ROWS_MAX``): ``hist_leaf_q8``, the fused level pass
+``hist_level_q8`` and the one-kernel front, in their two weight layouts
+((g, h, count), and (g, count) under a constant hessian).
+
+Each is held against something that shares no code with it: the leaf kernel
+against int64 sums in numpy, the level kernel against the XLA router followed
+by the leaf kernel, whole models against the scatter histograms on the same
+quantised gradients, and the 2-channel layout against the 3-channel one. The
+Pallas kernels run interpreted, the same int8 x int8 contraction the chip
+runs. (The path wider than one group is test_wide_path.py's.)"""
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.ops import histogram as hg
+from lightgbm_tpu.ops import pallas_hist as ph
+
+from _trees import same_trees
+
+N, F, B = 220, 7, 16
+SEED = 12345
+
+
+@pytest.fixture(scope="module")
+def rows():
+    rng = np.random.default_rng(0)
+    bins = jnp.asarray(rng.integers(0, B, size=(N, F)), dtype=jnp.uint8)
+    return {
+        "bins": bins, "bins_T": bins.T,
+        "score": jnp.asarray(rng.normal(size=N).astype(np.float32)),
+        "label": jnp.asarray(rng.normal(size=N).astype(np.float32)),
+        "label_pos": jnp.asarray((rng.random(N) < 0.5).astype(np.float32)),
+        "bag": jnp.asarray((rng.random(N) < 0.8).astype(np.float32)),
+    }
+
+
+def _logloss_gh(score, label_pos):
+    t = 2.0 * label_pos - 1.0
+    resp = 1.0 / (1.0 + jnp.exp(t * score))
+    return -t * resp, resp * (1.0 - resp)
+
+
+def _quant(rows, const_hess):
+    bag = rows["bag"]
+    if const_hess:
+        g, h = (rows["score"] - rows["label"]) * bag, jnp.ones(N) * bag
+    else:
+        grad, hess = _logloss_gh(rows["score"], rows["label_pos"])
+        g, h = grad * bag, hess * bag
+    c = (bag > 0).astype(jnp.float32)
+    return hg.make_quant(g, h, c, SEED, const_hess=const_hess)
+
+
+# ---------------------------------------------------------------------------
+# the leaf kernel against integer sums
+
+@pytest.mark.parametrize("s", [1, 32, 127])
+@pytest.mark.parametrize("const_hess", [False, True])
+def test_hist_leaf_q8_equals_integer_sums(rows, const_hess, s):
+    """``hist_pallas_q8`` is the int64 sum of (gq, hq, cq) by (slot, feature,
+    bin), dequantised as ``_dequant_stack`` does: exactly. Rows in slot ``s``
+    are dropped."""
+    q = _quant(rows, const_hess)
+    hq, ch = hg._q8_h_arg(q)
+    assert ch == const_hess
+    slot = np.random.default_rng(s).integers(0, s + 1, size=N).astype(np.int32)
+    assert (slot == s).any()
+    got = np.asarray(ph.hist_pallas_q8(
+        rows["bins_T"], q.gq, hq, q.cq, jnp.asarray(slot), s, B,
+        q.scale_g, q.scale_h, const_hess=ch, interpret=True))
+
+    bins = np.asarray(rows["bins"]).astype(np.int64)
+    keep = slot < s
+    acc = np.zeros((3, s, F, B), np.int64)
+    chans = (q.gq, q.cq if const_hess else q.hq, q.cq)
+    for k, w in enumerate(chans):
+        w = np.asarray(w).astype(np.int64)
+        for j in range(F):
+            np.add.at(acc[k], (slot[keep], j, bins[keep, j]), w[keep])
+    sg = np.float32(q.scale_g) * np.float32(1.0 / 127.0)
+    sh = np.float32(q.scale_h) * np.float32(1.0 / 127.0)
+    ref = np.stack([acc[0].astype(np.float32) * sg,
+                    acc[1].astype(np.float32) * sh,
+                    acc[2].astype(np.float32)], axis=1)       # [S, 3, F, B]
+    assert got.dtype == np.float32 and ref.dtype == np.float32
+    np.testing.assert_array_equal(got, ref)
+
+
+# ---------------------------------------------------------------------------
+# the level kernel against the XLA router followed by the leaf kernel
+
+def _level_tables(s, categorical):
+    """One level over 2s + 2 leaves: the first s may split (one in five does
+    not), one child to slot i and the other to the sentinel s (its histogram
+    is the parent's less its sibling's), the right child a new leaf."""
+    r = np.random.default_rng(100 + s)
+    l = 2 * s + 2
+    splits = np.arange(l) < s
+    feat = np.where(splits & (r.random(l) < 0.8), r.integers(0, F, size=l), -1)
+    left_small = r.random(l) < 0.5
+    own = np.where(splits, np.arange(l), s)
+    i32 = lambda a: jnp.asarray(a, dtype=jnp.int32)
+    cat = {}
+    if categorical:
+        cat = {"is_cat": i32(r.random(l) < 0.5),
+               "member": jnp.asarray((r.random((l, B)) < 0.5)
+                                     .astype(np.float32))}
+    return hg.RouteTables(
+        i32(feat), i32(r.integers(0, B - 1, size=l)),
+        i32(r.integers(0, 2, size=l)), i32(np.minimum(s + np.arange(l), l - 1)),
+        i32(np.where(left_small, own, s)), i32(np.where(left_small, s, own)),
+        **cat)
+
+
+@pytest.mark.parametrize("categorical", [False, True])
+@pytest.mark.parametrize("s", [32, 127])
+@pytest.mark.parametrize("const_hess", [False, True])
+def test_hist_level_q8_equals_route_then_leaf(rows, const_hess, s,
+                                              categorical):
+    """``hist_routed_fused_q8`` is ``route_level`` (XLA gathers) followed by
+    ``hist_pallas_q8`` on its slots: histograms and new leaf ids, exactly.
+    Some features have a missing-value bin, so both default directions run."""
+    q = _quant(rows, const_hess)
+    hq, ch = hg._q8_h_arg(q)
+    tables = _level_tables(s, categorical)
+    l = tables.feat.shape[0]
+    r = np.random.default_rng(s)
+    lid = jnp.asarray(r.integers(0, l, size=N), dtype=jnp.int32)
+    na_bin = jnp.asarray(np.where(np.arange(F) % 2 == 0, B - 1, -1),
+                         dtype=jnp.int32)
+    hist, lid2 = ph.hist_routed_fused_q8(
+        rows["bins_T"], q.gq, hq, q.cq, lid, tables, na_bin, s, B,
+        q.scale_g, q.scale_h, l, const_hess=ch, interpret=True)
+    slot, lid_ref = hg.route_level(rows["bins"], lid, tables, na_bin, s)
+    assert (np.asarray(slot) < s).any() and (np.asarray(slot) == s).any()
+    assert (np.asarray(lid_ref) != np.asarray(lid)).any()
+    ref = ph.hist_pallas_q8(
+        rows["bins_T"], q.gq, hq, q.cq, slot, s, B, q.scale_g, q.scale_h,
+        const_hess=ch, interpret=True)
+    np.testing.assert_array_equal(np.asarray(lid2), np.asarray(lid_ref))
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# whole models across the booster x objective matrix
+
+PALLAS_PARAMS = {"num_leaves": 7, "max_bin": 31, "min_data_in_leaf": 5,
+                 "verbosity": -1, "prewarm": 0, "histogram_impl": "pallas",
+                 "use_quantized_grad": "true"}
+
+BOOSTER_EXTRA = {
+    "gbdt": {},
+    "dart": {"skip_drop": 0.0, "drop_rate": 0.5},
+    "goss": {"top_rate": 0.3, "other_rate": 0.2},
+    "rf": {"bagging_freq": 1, "bagging_fraction": 0.8},
+}
+
+
+def _matrix_data(seed=0):
+    rng = np.random.RandomState(seed)
+    X = rng.rand(N, F).astype(np.float32)
+    yb = (X[:, 0] + 0.3 * rng.rand(N) > 0.65).astype(np.float32)
+    yr = (X[:, 1] * 2.0 + rng.rand(N)).astype(np.float32)
+    return X, {"binary": yb, "regression": yr}
+
+
+# binary at seed 0 (and 1) grows a leaf that is all one class: its best
+# "split" has a gain of f32 round-off (8e-6 on one path, 2e-5 on the other),
+# and whether that dust wins a place in the tree depends on the summation
+# order. Seed 2 has no such leaf
+DATA_SEED = {"regression": 0, "binary": 2}
+
+
+def _train(params, X, y):
+    return lgb.train(params, lgb.Dataset(X, label=y, params=params),
+                     num_boost_round=3)
+
+
+@pytest.mark.parametrize("boosting", ["gbdt", "dart", "goss", "rf"])
+@pytest.mark.parametrize("objective", ["regression", "binary"])
+def test_one_group_train_matches_scatter(boosting, objective):
+    """``lgb.train`` at one feature group (the fused level kernel, and the
+    one-kernel front where its gates pass: gbdt and dart, whose gradients
+    are the objective's own) grows the trees the scatter histograms grow
+    from the same quantised gradients."""
+    X, ys = _matrix_data(DATA_SEED[objective])
+    base = dict(PALLAS_PARAMS, objective=objective, boosting=boosting,
+                **BOOSTER_EXTRA[boosting])
+    a = _train(base, X, ys[objective])
+    b = _train(dict(base, histogram_impl="scatter"), X, ys[objective])
+    gp = a._gbdt.gp
+    assert gp.quant and ph.one_group(X.shape[1], gp.max_bin)
+    same_trees(a, b)
+
+
+@pytest.mark.parametrize("boosting", ["gbdt", "dart"])
+def test_models_bit_identical_2ch_vs_3ch(monkeypatch, boosting):
+    """Const-hessian elision (2 channels) vs the flag forced off (3
+    channels): same trees, bit for bit. Only the auto-gradient boosters
+    reach the elided kernels."""
+    import lightgbm_tpu.objectives as O
+    X, ys = _matrix_data()
+    params = dict(PALLAS_PARAMS, objective="regression", boosting=boosting,
+                  **BOOSTER_EXTRA[boosting])
+
+    def run():
+        bst = _train(params, X, ys["regression"])
+        return bst.predict(X, raw_score=True), bst.model_to_string()
+
+    pred_2, model_2 = run()
+    monkeypatch.setattr(O.RegressionL2, "is_constant_hessian", False)
+    pred_3, model_3 = run()
+    np.testing.assert_array_equal(pred_2, pred_3)
+    assert model_2 == model_3

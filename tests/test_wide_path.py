@@ -15,6 +15,8 @@ from lightgbm_tpu import binning, obs
 from lightgbm_tpu.ops import histogram as H
 from lightgbm_tpu.ops import pallas_hist as ph
 
+from _trees import same_trees
+
 # (features, max_bin): both pad to 64 bins a feature, 32 features a group;
 # neither width is a multiple of 32, so the last group is part padding
 WIDTHS = [(520, 15), (40, 63)]
@@ -38,26 +40,6 @@ def _train(X, y, max_bin, impl, **extra):
     return lgb.train(p, lgb.Dataset(X, label=y, params=p), num_boost_round=3)
 
 
-def _same_trees(a, b):
-    """Two boosters grew the same three trees; returns the first's."""
-    ta, tb = a._ensure_host_trees(), b._ensure_host_trees()
-    assert len(ta) == len(tb) == 3
-    for t1, t2 in zip(ta, tb):
-        k = t1.num_leaves
-        assert k == t2.num_leaves and k > 1
-        for name in ("split_feature", "threshold_bin", "left_child",
-                     "right_child"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(t1, name))[: k - 1],
-                np.asarray(getattr(t2, name))[: k - 1], err_msg=name)
-        np.testing.assert_array_equal(np.asarray(t1.leaf_count)[:k],
-                                      np.asarray(t2.leaf_count)[:k])
-        np.testing.assert_allclose(np.asarray(t1.leaf_value)[:k],
-                                   np.asarray(t2.leaf_value)[:k],
-                                   rtol=2e-5, atol=1e-7)
-    return ta
-
-
 # ---- (a) the whole path through lgb.train against scatter ------------------
 @pytest.mark.parametrize("f,max_bin", WIDTHS)
 def test_train_matches_scatter(f, max_bin):
@@ -67,7 +49,7 @@ def test_train_matches_scatter(f, max_bin):
     a = _train(X, y, max_bin, "pallas")
     b = _train(X, y, max_bin, "scatter")
     assert f * a._gbdt.gp.max_bin > ph._ACC_ROWS_MAX
-    ta = _same_trees(a, b)
+    ta = same_trees(a, b)
     used = {int(v) // 32 for t in ta
             for v in np.asarray(t.split_feature)[: t.num_leaves - 1]}
     assert (f - 1) // 32 in used, "no split in the tail feature group"
@@ -81,7 +63,7 @@ def test_lean_grower_routes_through_the_kernel():
     a, b = (_train(X, y, 63, impl, histogram_pool_size=0.05)
             for impl in ("pallas", "scatter"))
     assert a._gbdt.gp.lean_ft > 0 and b._gbdt.gp.lean_ft > 0
-    _same_trees(a, b)
+    same_trees(a, b)
 
 
 @pytest.mark.parametrize("extra", [{}, {"histogram_pool_size": 0.05}],
