@@ -958,14 +958,19 @@ class GBDT:
         if self.config.grow_policy == "depthwise" and gp.lean_ft <= 0:
             # what the default depthwise grower's level passes will run at
             # this width (ops/histogram.hist_routed selects it at trace time)
-            from ..ops.histogram import hist_path, one_kernel_front
+            from ..ops.grow_depthwise import level_groups
+            from ..ops.histogram import (hist_path, one_kernel_front,
+                                         pick_impl)
             width = int(self.train_set.num_features), int(gp.max_bin)
             # the front is one kernel where grad_quant_hist0 is called (a
             # fused spec) and its own gate says so
             one_kernel = (fused_spec is not None
                           and one_kernel_front(*width, gp.hist_impl))
+            groups = level_groups(gp.num_leaves, gp.max_depth,
+                                  pick_impl(gp.hist_impl) == "pallas")
             obs.emit("hist_path", front="fused" if one_kernel else "unfused",
                      bins_T_cached=bool(use_bt),
+                     decode_leaves=[g[3] for g in groups],
                      **hist_path(*width, gp.hist_impl, bool(gp.quant)))
 
         def step(bins, num_bins, na_bin, score, fmask, bag_mask, grad, hess,

@@ -67,9 +67,13 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # route_level's gathers) or "fused" (onehot: routed in its scan). front:
     # whether gradients, quantisation and the root histogram are one kernel;
     # bins_T_cached: whether the step is fed the Dataset's cached transposed
-    # bin matrix
+    # bin matrix; decode_leaves: for each level group of the schedule
+    # (ops/grow_depthwise.level_groups) the leaves its route tables hold, the
+    # width of the kernels' per-row split-table decode ([32, 255] at 255
+    # leaves)
     "hist_path": ({"level_kernel": str, "feature_groups": int, "route": str,
-                   "front": str, "bins_T_cached": bool}, {}),
+                   "front": str, "bins_T_cached": bool,
+                   "decode_leaves": list}, {}),
     # a jitted program was built (host-side tracing/lowering observed via
     # the function's cache size; device code itself is unchanged)
     "compile": ({"what": str, "cache_size": int},

@@ -164,37 +164,44 @@ def _monotone_child_bounds(sp: SplitParams, f: int, res, feat, sel,
     return leaf_min2, leaf_max2
 
 
-def _run_level_schedule(state, level, L, max_levels, n_unroll, MAX_SLOTS,
-                        slot_floor):
-    """Bucketed level schedule shared by both depthwise growers: run
-    ``level(state, SLOTS, lvl)`` for lvl in [0, max_levels) with the slot
-    width growing as min(MAX_SLOTS, max(2**lvl, slot_floor)).
+def level_groups(num_leaves: int, max_depth: int, use_pallas: bool):
+    """The bucketed level schedule of both depthwise growers: a list of
+    (width, first level, one-past-last level, decode leaves) groups.
 
-    Consecutive levels with the SAME width are fused into one
-    ``lax.while_loop`` so the level body is traced (and XLA-compiled) once
-    per DISTINCT width instead of once per depth — with the pallas slot
-    floor at 32 and L=255 that is 3 traced bodies ({32, 64, 127}) instead
-    of 10, which is most of the BENCH_r05 compile_s regression. The loop
-    form is bit-identical to the old per-level ``lax.cond`` unroll: the
-    loop guard is the same early-exit predicate the conds used (once a
-    level selects nothing, ``last`` stays 0 and every later group runs
-    zero iterations), and the level index reaches the body as a traced
-    i32 either way (it only feeds ``jax.random.fold_in``).
-    """
-    if slot_floor > 1:
-        # pallas path: floor every unrolled width to the master slot-width
-        # set, so the depthwise default, lean and leaf-wise growers share one
-        # compiled kernel program per master width instead of one per 2^k.
-        # Over-wide S never changes selection: level k has <= 2^k candidate
-        # leaves <= the un-floored width, so `rank < min(budget, SLOTS)`
-        # binds identically (see the schedule comment in grow_tree_depthwise)
+    Level k has at most min(2^k, L // 2) splittable leaves, so the first
+    ~log2(L) levels run at small static slot widths — the histogram pass cost
+    scales with the slot axis, and one fixed width made every level pay for
+    the deepest one (measured ~2x whole-tree cost at L=255). The tail past
+    them (unbalanced growth, up to max_depth or L - 1 levels) runs at the
+    full width L // 2: the most splits a level can SELECT, min(frontier 2^d,
+    budget L - 2^d). The dropped-row slot id equals the slot count (no
+    weight row in the kernel), so the pass width is exactly the split cap —
+    at L=255 the deepest pass is S=127 -> 381 lanes -> 384 MXU-lane pad.
+
+    On the pallas path every width is floored at _SLOT_FLOOR and snapped to
+    pallas_hist.MASTER_SLOT_WIDTHS (the fused pass is latency-bound and flat
+    below S=32, but every distinct S compiles its own Mosaic kernel); the
+    XLA fallback pays real FLOPs per slot and keeps exact 2^k widths.
+    Over-wide S never changes selection: level k has <= 2^k candidate leaves
+    <= the un-floored width, so `rank < min(budget, SLOTS)` binds identically
+    and the grown tree is bit-identical.
+
+    Consecutive levels of one width form one group, traced once. ``decode
+    leaves`` is the most leaves that can exist before the group's last
+    level, min(L, 2^(k1-1)): leaf ids are dense in creation order, so the
+    level's RouteTables and the kernels' per-row decode span that many
+    entries and not num_leaves (32 and 255 for the two groups at L=255)."""
+    L = num_leaves
+    max_levels = max_depth if max_depth > 0 else max(1, L - 1)
+    max_slots = max(1, L // 2)
+    n_unroll = min(max_levels, max(1, math.ceil(math.log2(max(L - 1, 2)))) + 1)
+    if use_pallas:
         from .pallas_hist import floor_slot_width
-        widths = [floor_slot_width(max(min(2 ** k, MAX_SLOTS), slot_floor),
-                                   MAX_SLOTS)
+        widths = [floor_slot_width(max(min(2 ** k, max_slots), _SLOT_FLOOR),
+                                   max_slots)
                   for k in range(n_unroll)]
     else:
-        widths = [min(MAX_SLOTS, max(2 ** k, slot_floor))
-                  for k in range(n_unroll)]
+        widths = [min(max_slots, 2 ** k) for k in range(n_unroll)]
     groups = []   # [width, first level, one-past-last level]
     for k, w in enumerate(widths):
         if groups and groups[-1][0] == w:
@@ -203,13 +210,30 @@ def _run_level_schedule(state, level, L, max_levels, n_unroll, MAX_SLOTS,
             groups.append([w, k, k + 1])
     if max_levels > n_unroll:
         # unbalanced-growth tail: full width, merged with the last unrolled
-        # group when that group already runs at MAX_SLOTS
-        if groups and groups[-1][0] == MAX_SLOTS:
+        # group when that group already runs at max_slots
+        if groups and groups[-1][0] == max_slots:
             groups[-1][2] = max_levels
         else:
-            groups.append([MAX_SLOTS, n_unroll, max_levels])
+            groups.append([max_slots, n_unroll, max_levels])
+    return [(w, k0, k1, min(L, 2 ** (k1 - 1))) for w, k0, k1 in groups]
+
+
+def _run_level_schedule(state, level, L, groups):
+    """Run ``level(state, SLOTS, lvl, decode_leaves)`` over ``level_groups``'
+    schedule, each group one ``lax.while_loop`` (one ``lax.cond`` where it
+    holds a single level), so the level body is traced and compiled once per
+    group instead of once per depth.
+
+    Early exit is the loop guard: once a level selects no splits OR the leaf
+    budget is exhausted, the tree is finished, ``last`` stays 0 and every
+    later group runs zero iterations. The budget check matters for balanced
+    growth: a tree that fills num_leaves=255 exactly at level 8 would
+    otherwise pay one more full-width pass just to select nothing (~25% of
+    whole-tree cost, measured at 10M rows). The level index reaches the body
+    as a traced i32 (it only feeds ``jax.random.fold_in``).
+    """
     last_sel = jnp.int32(1)
-    for w, k0, k1 in groups:
+    for w, k0, k1, l_dec in groups:
         # the scope holds the loop construct too, so a trace reads the
         # group's whole device time (guard, carry copies) under its width
         with jax.named_scope(f"level_s{w}"):
@@ -218,7 +242,8 @@ def _run_level_schedule(state, level, L, max_levels, n_unroll, MAX_SLOTS,
                 # the body exactly once; cond skips the carry plumbing
                 state, last_sel = jax.lax.cond(
                     (last_sel > 0) & (state.tree.num_leaves < L),
-                    lambda st, _w=w, _k=k0: level(st, _w, jnp.int32(_k)),
+                    lambda st, _w=w, _k=k0, _l=l_dec: level(
+                        st, _w, jnp.int32(_k), _l),
                     lambda st: (st, jnp.int32(0)),
                     state)
                 continue
@@ -227,14 +252,31 @@ def _run_level_schedule(state, level, L, max_levels, n_unroll, MAX_SLOTS,
                 st, lvl, last = carry
                 return (lvl < _k1) & (last > 0) & (st.tree.num_leaves < L)
 
-            def body(carry, _w=w):
+            def body(carry, _w=w, _l=l_dec):
                 st, lvl, _ = carry
-                st2, num_sel = level(st, _w, lvl)
+                st2, num_sel = level(st, _w, lvl, _l)
                 return st2, lvl + 1, num_sel
 
             state, _, last_sel = jax.lax.while_loop(
                 cond, body, (state, jnp.int32(k0), last_sel))
     return state
+
+
+def _level_tables(l_dec: int, sel, res, new_leaf, slot_left, slot_right,
+                  sp: SplitParams):
+    """One level's RouteTables over the ``l_dec`` leaves it can hold (every
+    live leaf id is below it: level_groups)."""
+    cat = bool(sp.cat_features or sp.has_bundles)
+    return jax.tree.map(lambda a: a[:l_dec], H.RouteTables(
+        feat=jnp.where(sel, res.feature, -1),
+        thr=res.bin,
+        dleft=res.default_left.astype(jnp.int32),
+        new_leaf=new_leaf,
+        slot_left=slot_left,
+        slot_right=slot_right,
+        is_cat=(res.is_cat & sel).astype(jnp.int32) if cat else None,
+        member=(res.cat_member & sel[:, None]).astype(jnp.float32)
+        if cat else None))
 
 
 @partial(jax.jit, static_argnames=("gp",))
@@ -263,16 +305,6 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     n, f = bins.shape
     L, B = gp.num_leaves, gp.max_bin
     sp = gp.split
-    # unlimited depth => up to L-1 levels; the loop exits as soon as a level
-    # selects no splits, so balanced trees still cost ~log2(L) passes
-    max_levels = gp.max_depth if gp.max_depth > 0 else max(1, L - 1)
-    # max splits any level can SELECT: min(frontier 2^d, budget L - 2^d) peaks
-    # at L // 2. The dropped-row slot id equals the slot count (no weight row
-    # in the kernel), so the pass width is exactly the split cap — at L=255
-    # the deepest pass is S=127 -> 381 lanes -> 384 MXU-lane pad, vs the old
-    # cap+1 = 129 -> 387 -> 512 lanes (+33% MXU on the deepest level)
-    MAX_SLOTS = max(1, L // 2)
-
     # pallas kernels read a transposed bin matrix: prefer the Dataset's
     # cached device-resident copy, else build it once per tree (XLA CSEs it
     # across all level passes inside this jit)
@@ -348,7 +380,7 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
 
     leaves_iota = jnp.arange(L, dtype=jnp.int32)
 
-    def level(st: _DWState, SLOTS: int, lvl):
+    def level(st: _DWState, SLOTS: int, lvl, l_dec: int):
         with jax.named_scope("split_search"):
             # ---- per-node feature sampling (feature_fraction_bynode;
             # reference samples per node, serial_tree_learner.cpp:397+ — here
@@ -460,7 +492,7 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
             node_id = st.tree.num_leaves - 1 + idx_in_lvl      # node_cnt == n_leaves-1
             new_leaf = st.tree.num_leaves + idx_in_lvl
 
-            feat, thr, dleft = res.feature, res.bin, res.default_left
+            feat = res.feature
             lg, lh, lc = res.left_g, res.left_h, res.left_cnt
             rg, rh, rc = st.leaf_g - lg, st.leaf_h - lh, st.leaf_c - lc
 
@@ -516,18 +548,8 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
                 slot_l_tab = jnp.where(sel & small_is_left, idx_in_lvl, SLOTS)
                 slot_r_tab = jnp.where(sel & ~small_is_left, idx_in_lvl,
                                        SLOTS)
-            tables = H.RouteTables(
-                feat=jnp.where(sel, feat, -1),
-                thr=thr,
-                dleft=dleft.astype(jnp.int32),
-                new_leaf=new_leaf,
-                slot_left=slot_l_tab,
-                slot_right=slot_r_tab,
-                is_cat=(res.is_cat & sel).astype(jnp.int32)
-                if (sp.cat_features or sp.has_bundles) else None,
-                member=(res.cat_member & sel[:, None]).astype(jnp.float32)
-                if (sp.cat_features or sp.has_bundles) else None,
-            )
+            tables = _level_tables(l_dec, sel, res, new_leaf, slot_l_tab,
+                                   slot_r_tab, sp)
             hist_pass, leaf_id2 = H.hist_routed(
                 bins, g, h, c, st.leaf_id, tables, na_bin, S_pass, B,
                 gp.hist_impl, bins_T=bins_T, quant=quant)
@@ -637,30 +659,8 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
             tree=tr,
         ), num_sel
 
-    # ---- bucketed level schedule ----
-    # Level k has at most min(2^k, MAX_SLOTS-1) splittable leaves, so the first
-    # ~log2(L) levels are Python-unrolled with small static slot counts — the
-    # histogram pass cost scales with the slot axis, and a fixed-width while_loop
-    # made every level pay for the deepest one (measured ~2x whole-tree cost at
-    # L=255). A while_loop tail covers unbalanced growth past the unroll.
-    # On the pallas path slot widths are floored at _SLOT_FLOOR: the fused
-    # pass is latency-bound and flat below S=32 (PERF_NOTES cost table) but
-    # every distinct S compiles its own Mosaic kernel variant, so S in
-    # {1,2,4,8,16} only added compile time (the BENCH_r05 compile
-    # regression). The XLA fallback pays real FLOPs per slot, so it keeps
-    # exact 2^k widths. Selection is unchanged under padding — at level k
-    # the frontier is <= 2^k <= padded S, so `rank < min(budget, SLOTS)`
-    # binds identically and the grown tree is bit-identical.
-    # Early exit is built into the schedule guard: once a level selects no
-    # splits OR the leaf budget is exhausted, the tree is finished and every
-    # remaining full-data pass is skipped. The budget check matters for
-    # balanced growth: a tree that fills num_leaves=255 exactly at level 8
-    # would otherwise pay one more full-width hist pass just to select
-    # nothing (~25% of whole-tree cost, measured at 10M rows).
-    slot_floor = _SLOT_FLOOR if use_pallas else 1
-    n_unroll = min(max_levels, max(1, math.ceil(math.log2(max(L - 1, 2)))) + 1)
-    state = _run_level_schedule(state, level, L, max_levels, n_unroll,
-                                MAX_SLOTS, slot_floor)
+    state = _run_level_schedule(
+        state, level, L, level_groups(L, gp.max_depth, use_pallas))
 
     if gp.quant:
         # leaf renewal from EXACT sums (quantized-training paper: splits
@@ -795,8 +795,6 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
     sp = gp.split
     ft = max(1, min(gp.lean_ft or f, f))
     n_tiles = -(-f // ft)
-    max_levels = gp.max_depth if gp.max_depth > 0 else max(1, L - 1)
-    MAX_SLOTS = max(1, L // 2)
 
     use_pallas = H.pick_impl(gp.hist_impl) == "pallas"
     if not use_pallas:
@@ -897,7 +895,7 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
         leaf_count=state.tree.leaf_count.at[0].set(c0)))
     leaves_iota = jnp.arange(L, dtype=jnp.int32)
 
-    def level(st: _LeanState, SLOTS: int, lvl):
+    def level(st: _LeanState, SLOTS: int, lvl, l_dec: int):
         with jax.named_scope("split_search"):
             res = st.rec
             gain_gate = 0.0 if sp.has_contri \
@@ -917,7 +915,7 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
             node_id = st.tree.num_leaves - 1 + idx_in_lvl
             new_leaf = st.tree.num_leaves + idx_in_lvl
 
-            feat, thr, dleft = res.feature, res.bin, res.default_left
+            feat = res.feature
             lg, lh, lc = res.left_g, res.left_h, res.left_cnt
             rg, rh, rc = st.leaf_g - lg, st.leaf_h - lh, st.leaf_c - lc
 
@@ -937,18 +935,10 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
         with jax.named_scope("route_hist"):
             # ---- route: BOTH children measured (slots 2i / 2i+1) ----
             S_pass = 2 * SLOTS
-            tables = H.RouteTables(
-                feat=jnp.where(sel, feat, -1),
-                thr=thr,
-                dleft=dleft.astype(jnp.int32),
-                new_leaf=new_leaf,
-                slot_left=jnp.where(sel, idx_in_lvl * 2, S_pass),
-                slot_right=jnp.where(sel, idx_in_lvl * 2 + 1, S_pass),
-                is_cat=(res.is_cat & sel).astype(jnp.int32)
-                if (sp.cat_features or sp.has_bundles) else None,
-                member=(res.cat_member & sel[:, None]).astype(jnp.float32)
-                if (sp.cat_features or sp.has_bundles) else None,
-            )
+            tables = _level_tables(
+                l_dec, sel, res, new_leaf,
+                jnp.where(sel, idx_in_lvl * 2, S_pass),
+                jnp.where(sel, idx_in_lvl * 2 + 1, S_pass), sp)
             slot, leaf_id2 = H.route_rows(bins, bins_T, st.leaf_id, tables,
                                           na_bin, S_pass, gp.hist_impl)
 
@@ -1007,13 +997,8 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
             tree=tr,
         ), num_sel
 
-    n_unroll = min(max_levels,
-                   max(1, math.ceil(math.log2(max(L - 1, 2)))) + 1)
-    # floored like the default grower: fewer distinct slot widths -> fewer
-    # compiled kernel variants, identical selection (see _run_level_schedule)
-    slot_floor = _SLOT_FLOOR if use_pallas else 1
-    state = _run_level_schedule(state, level, L, max_levels, n_unroll,
-                                MAX_SLOTS, slot_floor)
+    state = _run_level_schedule(
+        state, level, L, level_groups(L, gp.max_depth, use_pallas))
 
     if gp.quant:
         with jax.named_scope("leaf_renew"):

@@ -570,7 +570,7 @@ def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
             return hist_routed_fused_q8(
                 bt, quant.gq, hq, quant.cq, leaf_id, tables, na_bin,
                 num_slots, num_bins, quant.scale_g, quant.scale_h,
-                tables.feat.shape[0], const_hess=ch, interpret=interp)
+                const_hess=ch, interpret=interp)
         slot, lid2 = route_rows(bins, bt, leaf_id, tables, na_bin, num_slots,
                                 impl)
         # the grouped kernel with its dequantise and transposes
@@ -601,4 +601,4 @@ def route_rows(bins, bins_T, leaf_id, tables: RouteTables, na_bin,
                                              num_slots)
         return route_level_pallas(
             cols, leaf_id, tables._replace(feat=rank), na_cols, num_slots,
-            tables.feat.shape[0], interpret=jax.default_backend() == "cpu")
+            interpret=jax.default_backend() == "cpu")

@@ -390,7 +390,108 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
     return hist[:, :, :f, :]
 
 
-def _kernel_q8_fused(*refs, f: int, b: int, s: int, l: int, chunk: int,
+# ---------------------------------------------------------------------------
+# per-row routing inside a kernel
+#
+# A plain XLA gather of an [N] index vector from a small [L] table costs ~7ms
+# per million rows on v5e (no hardware gather; XLA lowers to per-element
+# dynamic-slice). One depthwise level needs eight such lookups. The level
+# kernels express them as ONE one-hot [L, C] contraction on the MXU, over the
+# leaves the level can hold (the tables' length), not num_leaves.
+# ---------------------------------------------------------------------------
+
+_TAB_ROWS = 8    # feat, thr, dleft, new_leaf, slot_left, slot_right, is_cat, na
+_TAB_LANES = 32  # the tables' length is padded to a multiple of this
+
+
+def _route_tabs(tables, na_bin) -> jnp.ndarray:
+    """One level's RouteTables as the kernels' decode operand: [16, L_pad]
+    bf16, the eight rows (feat, thr, dleft, new_leaf, slot_left, slot_right,
+    is_cat, the split feature's missing bin) each as ``value + 1`` in a
+    low-byte row (0-7) and a high-byte row (8-15). A byte is exact in bf16,
+    so entries in [-1, 65534] are (num_leaves > 256, feature ids > 255,
+    na_bin 256 = none); the +1 makes an all-zero column, and so an id past
+    the tables, decode to feat = -1: does not split. ``na_bin`` is indexed
+    by ``tables.feat``."""
+    iscat = (tables.is_cat if tables.is_cat is not None
+             else jnp.zeros_like(tables.feat))
+    na = jnp.take(na_bin, jnp.maximum(tables.feat, 0))
+    v = jnp.stack([tables.feat, tables.thr, tables.dleft, tables.new_leaf,
+                   tables.slot_left, tables.slot_right, iscat,
+                   na]).astype(jnp.int32) + 1                      # [8, L]
+    v = _pad_rows(v, _TAB_LANES)
+    return jnp.concatenate([v & 0xFF, (v >> 8) & 0xFF]).astype(jnp.bfloat16)
+
+
+def _member_tab(tables) -> jnp.ndarray:
+    """Categorical membership as the decode's second operand: [B, L_pad]
+    bf16 0/1, a column a leaf."""
+    return _pad_rows(tables.member.astype(jnp.bfloat16).T, _TAB_LANES)
+
+
+def _decode_leaf(lid, tabs_ref, chunk: int):
+    """Per-row lookup of the level's split tables: lid [1, C] i32 against
+    tabs_ref [16, L] bf16 (see _route_tabs) -> (tv [8, C] f32, the table
+    rows at each row's leaf; oh [L, C] bf16, the leaf one-hot, for the
+    membership decode). One bf16 x bf16 -> f32 MXU pass, exact by its
+    operand types: bytes and 0/1 are exact in bf16 and a column of the
+    one-hot holds at most one 1 (int8 x int8 -> int32 read 0.5-4 ms a pass
+    slower on the v5e: PERF.md, PR 30). An id outside [0, L) decodes to
+    all -1."""
+    l = tabs_ref.shape[1]
+    iota_l = jax.lax.broadcasted_iota(jnp.int32, (l, chunk), 0)
+    oh = (lid == iota_l).astype(jnp.bfloat16)                     # [L, C]
+    by = jax.lax.dot_general(
+        tabs_ref[:], oh, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                       # [16, C]
+    return by[0:_TAB_ROWS] + 256.0 * by[_TAB_ROWS:2 * _TAB_ROWS] - 1.0, oh
+
+
+def _route_chunk(lid, bins_i, tabs_ref, memT_ref, *, f: int, s: int,
+                 chunk: int):
+    """Route one row-chunk through its leaves' splits (DataPartition::Split;
+    NumericalDecision tree.h:240, CategoricalDecision tree.h:279).
+
+    lid [1, C] i32; bins_i [F, C] i32, the rows ``feat`` indexes; memT_ref
+    [B, L] bf16 or None. Returns (slot [1, C] i32, s where the row's child
+    is not measured; new leaf id [1, C] i32)."""
+    tv, oh = _decode_leaf(lid, tabs_ref, chunk)
+    feat, thr, dleft = tv[0:1], tv[1:2], tv[2:3]
+    new_leaf, slot_l, slot_r = tv[3:4], tv[4:5], tv[5:6]
+    nav = tv[7:8]
+    # Mosaic has no direct uint8 -> f32 cast: the caller hops through int32
+    bins_f = bins_i.astype(jnp.float32)                           # [F, C]
+    iota_f = jax.lax.broadcasted_iota(jnp.int32, (f, chunk), 0) \
+        .astype(jnp.float32)
+    colv = jnp.sum(jnp.where(iota_f == feat, bins_f, 0.0), axis=0,
+                   keepdims=True)
+    # all-f32 mask arithmetic: a bool-valued jnp.where lowers to an i1 select
+    # Mosaic cannot truncate to ("Unsupported target bitwidth for truncation")
+    has = jnp.where(feat >= 0, 1.0, 0.0)
+    is_na = jnp.where(colv == nav, 1.0, 0.0)
+    gr_na = jnp.where(dleft == 0, 1.0, 0.0)
+    gr_num = jnp.where(colv > thr, 1.0, 0.0)
+    go_right = is_na * gr_na + (1.0 - is_na) * gr_num
+    if memT_ref is not None:
+        # decode the leaf's [B] bin-membership row, pick the row's bin ->
+        # member -> LEFT
+        b = memT_ref.shape[0]
+        mem_bc = jax.lax.dot_general(
+            memT_ref[:], oh, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                   # [B, C] 0/1
+        iota_b = jax.lax.broadcasted_iota(jnp.int32, (b, chunk), 0) \
+            .astype(jnp.float32)
+        member = jnp.sum(jnp.where(iota_b == colv, mem_bc, 0.0),
+                         axis=0, keepdims=True)
+        iscat = tv[6:7]
+        go_right = iscat * (1.0 - member) + (1.0 - iscat) * go_right
+    lid2 = jnp.where(has * go_right > 0, new_leaf, lid)
+    slot = has * (go_right * slot_r + (1.0 - go_right) * slot_l) \
+        + (1.0 - has) * float(s)
+    return slot.astype(jnp.int32), lid2.astype(jnp.int32)
+
+
+def _kernel_q8_fused(*refs, f: int, b: int, s: int, chunk: int,
                      has_cat: bool, nch: int = 3, swar: bool = False):
     """Fused route + int8 histogram for ONE feature group (F*B <= block cap).
 
@@ -401,17 +502,17 @@ def _kernel_q8_fused(*refs, f: int, b: int, s: int, l: int, chunk: int,
     feeds the slot straight into the weight mask — one bins read, one launch,
     one level.
 
-    refs: bins [F, C] u8; gq/hq/cq [C] i8; lid [C] i32; tabs [8, L] f32
-    (rows: feat, thr, dleft, new_leaf, slot_left, slot_right, is_cat, _);
-    nab [F, 1] f32; [memT [B, L] f32 when has_cat]; outputs: out
+    refs: bins [F, C] u8; gq/hq/cq [C] i8; lid [C] i32; tabs [16, L] bf16
+    (_route_tabs); [memT [B, L] bf16 when has_cat]; outputs: out
     [F*B, S*nch] i32 accumulated, lid_out [C] i32.
     """
     if has_cat:
-        (bins_ref, gq_ref, hq_ref, cq_ref, lid_ref, tabs_ref, nab_ref,
+        (bins_ref, gq_ref, hq_ref, cq_ref, lid_ref, tabs_ref,
          memT_ref, out_ref, lid_out) = refs
     else:
-        (bins_ref, gq_ref, hq_ref, cq_ref, lid_ref, tabs_ref, nab_ref,
+        (bins_ref, gq_ref, hq_ref, cq_ref, lid_ref, tabs_ref,
          out_ref, lid_out) = refs
+        memT_ref = None
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -419,11 +520,6 @@ def _kernel_q8_fused(*refs, f: int, b: int, s: int, l: int, chunk: int,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     bins_i = bins_ref[:].astype(jnp.int32)                       # [F, C]
-    bins_f = bins_i.astype(jnp.float32)
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (l, chunk), 0)
-    iota_f = jax.lax.broadcasted_iota(jnp.int32, (f, chunk), 0) \
-        .astype(jnp.float32)
-    nab_f = nab_ref[:].astype(jnp.float32)
     onehot = _onehot_i8(bins_i, f, b, chunk, swar)
     g = gq_ref[:].reshape(1, chunk).astype(jnp.int32)
     c = cq_ref[:].reshape(1, chunk).astype(jnp.int32)
@@ -437,37 +533,9 @@ def _kernel_q8_fused(*refs, f: int, b: int, s: int, l: int, chunk: int,
     slot_of_row = jax.lax.broadcasted_iota(
         jnp.int32, (s * nch, chunk), 0) // nch
 
-    # ---- route (see _route_kernel for the one-hot decode rationale) ----
-    lid = lid_ref[:].reshape(1, chunk)
-    oh = (lid == iota_l).astype(jnp.float32)                     # [L, C]
-    tv = jax.lax.dot_general(
-        tabs_ref[:], oh, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)                     # [8, C]
-    feat, thr, dleft = tv[0:1], tv[1:2], tv[2:3]
-    new_leaf, slot_l, slot_r = tv[3:4], tv[4:5], tv[5:6]
-    fm = iota_f == feat
-    colv = jnp.sum(jnp.where(fm, bins_f, 0.0), axis=0, keepdims=True)
-    nav = jnp.sum(jnp.where(fm, nab_f, 0.0), axis=0, keepdims=True)
-    has = jnp.where(feat >= 0, 1.0, 0.0)
-    is_na = jnp.where(colv == nav, 1.0, 0.0)
-    gr_na = jnp.where(dleft == 0, 1.0, 0.0)
-    gr_num = jnp.where(colv > thr, 1.0, 0.0)
-    go_right = is_na * gr_na + (1.0 - is_na) * gr_num
-    if has_cat:
-        mem_bc = jax.lax.dot_general(
-            memT_ref[:], oh, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # [B, C]
-        iota_b1 = jax.lax.broadcasted_iota(jnp.int32, (b, chunk), 0) \
-            .astype(jnp.float32)
-        member = jnp.sum(jnp.where(iota_b1 == colv, mem_bc, 0.0),
-                         axis=0, keepdims=True)
-        iscat = tv[6:7]
-        go_right = iscat * (1.0 - member) + (1.0 - iscat) * go_right
-    lid2 = jnp.where(has * go_right > 0, new_leaf, lid)
-    slot_f = has * (go_right * slot_r + (1.0 - go_right) * slot_l) \
-        + (1.0 - has) * float(s)
-    slot = jnp.minimum(slot_f.astype(jnp.int32), s)              # [1, C]
+    slot, lid2 = _route_chunk(lid_ref[:].reshape(1, chunk), bins_i, tabs_ref,
+                              memT_ref, f=f, s=s, chunk=chunk)
+    slot = jnp.minimum(slot, s)                                  # [1, C]
 
     # ---- int8 histogram (see _kernel_q8 / _onehot_i8) ----
     w = jnp.where(slot == slot_of_row, wv, 0).astype(jnp.int8)
@@ -475,37 +543,26 @@ def _kernel_q8_fused(*refs, f: int, b: int, s: int, l: int, chunk: int,
         onehot, w, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32)
     out_ref[:] += part
-    lid_out[:] = lid2.astype(jnp.int32).reshape(chunk)
-
-
-def _route_tabs(tables, l: int) -> jnp.ndarray:
-    """One level's RouteTables as the kernel's [8, L] f32 decode rows."""
-    iscat_row = (tables.is_cat.astype(jnp.float32)
-                 if tables.is_cat is not None
-                 else jnp.zeros(l, jnp.float32))
-    return jnp.stack([
-        tables.feat.astype(jnp.float32), tables.thr.astype(jnp.float32),
-        tables.dleft.astype(jnp.float32), tables.new_leaf.astype(jnp.float32),
-        tables.slot_left.astype(jnp.float32),
-        tables.slot_right.astype(jnp.float32),
-        iscat_row, jnp.zeros(l, jnp.float32)])                    # [8, L]
+    lid_out[:] = lid2.reshape(chunk)
 
 
 def hist_routed_fused_q8(bins_T, gq, hq, cq, leaf_id, tables, na_bin,
                          num_slots: int, num_bins: int, scale_g, scale_h,
-                         num_leaves: int, chunk: int = 0,
-                         const_hess: bool = False, interpret: bool = False):
+                         chunk: int = 0, const_hess: bool = False,
+                         interpret: bool = False):
     """Fused route+histogram level pass: ONE kernel launch routes every row
     through the level's splits and accumulates the slot histograms. Returns
     (hist [S, 3, F, B] f32, lid2 [N] i32), bit-identical to route_level
     followed by hist_pallas_q8 (int32 accumulation is order-independent;
-    the routing arithmetic is the same ops in the same order).
+    the routing is the same integers).
 
+    ``tables`` hold the leaves the level can have (their length is the
+    decode's width, at most num_leaves): every ``leaf_id`` is below it.
     Only valid when every feature fits one accumulator block
     (F * num_bins <= _ACC_ROWS_MAX) — the router must see ALL columns.
     const_hess: see hist_pallas_q8."""
     f, n = bins_T.shape
-    b, s, l = num_bins, num_slots, num_leaves
+    b, s = num_bins, num_slots
     nch = _q8_nch(const_hess)
     assert one_group(f, b)
     if chunk == 0:
@@ -518,7 +575,8 @@ def hist_routed_fused_q8(bins_T, gq, hq, cq, leaf_id, tables, na_bin,
         chunk = 4096 if s * nch <= wide_ok else 2048
 
     has_cat = tables.is_cat is not None
-    nab = na_bin.astype(jnp.float32).reshape(f, 1)
+    tabs = _route_tabs(tables, na_bin)
+    l = tabs.shape[1]
 
     bins_Tp = _pad_rows(bins_T, chunk)
     gq = _pad_rows(gq, chunk)
@@ -533,18 +591,17 @@ def hist_routed_fused_q8(bins_T, gq, hq, cq, leaf_id, tables, na_bin,
         pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
         pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
         pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
-        pl.BlockSpec((8, l), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((f, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec(tabs.shape, lambda i: (0, 0), memory_space=pltpu.VMEM),
     ]
-    args = [bins_Tp, gq, hq, cq, lid_p, _route_tabs(tables, l), nab]
+    args = [bins_Tp, gq, hq, cq, lid_p, tabs]
     if has_cat:
-        in_specs.append(pl.BlockSpec((tables.member.shape[1], l),
-                                     lambda i: (0, 0),
+        memT = _member_tab(tables)
+        in_specs.append(pl.BlockSpec(memT.shape, lambda i: (0, 0),
                                      memory_space=pltpu.VMEM))
-        args.append(tables.member.astype(jnp.float32).T)
+        args.append(memT)
 
-    kern = functools.partial(_kernel_q8_fused, f=f, b=b, s=s, l=l,
-                             chunk=chunk, has_cat=has_cat, nch=nch,
+    kern = functools.partial(_kernel_q8_fused, f=f, b=b, s=s, chunk=chunk,
+                             has_cat=has_cat, nch=nch,
                              swar=_swar_ok(b, interpret))
     out, lid2 = pl.pallas_call(
         kern,
@@ -561,7 +618,7 @@ def hist_routed_fused_q8(bins_T, gq, hq, cq, leaf_id, tables, na_bin,
             jax.ShapeDtypeStruct((bins_Tp.shape[1],), jnp.int32),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=2 * n * f * b * s * nch + 2 * n * l * 9,
+            flops=2 * n * f * b * s * nch + 2 * n * l * 16,
             bytes_accessed=n * (f + 11) + f * b * s * 4 * nch,
             transcendentals=0),
         interpret=interpret,
@@ -927,93 +984,40 @@ def leaf_sums_grad_pallas(score, aux, bag, leaf_id, spec, num_leaves: int,
     return jnp.stack([out[0] + out[3], out[1] + out[4], out[2]], axis=0)
 
 
-# ---------------------------------------------------------------------------
-# routing + small-table gathers
-#
-# A plain XLA gather of an [N] index vector from a small [L] table costs ~7ms
-# per million rows on v5e (no hardware gather; XLA lowers to per-element
-# dynamic-slice). One depthwise level needs ~7 such lookups -> ~50ms/level,
-# which dominated whole-tree time in rounds 1-2. Both kernels below express
-# the lookup as a one-hot [L, C] mask contraction — pure VPU/MXU work.
-# ---------------------------------------------------------------------------
+def _route_kernel(*refs, f: int, s: int, chunk: int, has_cat: bool):
+    """Route one row-chunk through its leaf's split (_route_chunk).
 
-def _route_kernel(*refs, f: int, l: int, s: int, chunk: int, b: int,
-                  has_cat: bool):
-    """Route one row-chunk through its leaf's split.
-
-    refs: bins [F, C] uint8; lid [C] i32; tabs [8, L] f32 rows = (feat, thr,
-    dleft, new_leaf, slot_left, slot_right, is_cat, _); nab [F, 1] f32
-    missing-bin ids; [memT [B, L] f32 when has_cat]; outputs slot [C] i32,
-    new leaf id [C] i32.
+    refs: bins [F, C] uint8; lid [C] i32; tabs [16, L] bf16 (_route_tabs);
+    [memT [B, L] bf16 when has_cat]; outputs slot [C] i32, new leaf id [C] i32.
     """
     if has_cat:
-        bins_ref, lid_ref, tabs_ref, nab_ref, memT_ref, slot_out, lid_out = refs
+        bins_ref, lid_ref, tabs_ref, memT_ref, slot_out, lid_out = refs
     else:
-        bins_ref, lid_ref, tabs_ref, nab_ref, slot_out, lid_out = refs
-    lid = lid_ref[:].reshape(1, chunk)
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (l, chunk), 0)
-    oh = (lid == iota_l).astype(jnp.float32)                     # [L, C]
-    # HIGHEST precision: the default MXU pass truncates the f32 tables operand
-    # to bf16, mis-decoding integer values > 256 (feature ids on wide data,
-    # leaf ids at num_leaves > 257) -> silent mis-routing
-    tv = jax.lax.dot_general(
-        tabs_ref[:], oh, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)                     # [8, C] exact
-    feat, thr, dleft = tv[0:1], tv[1:2], tv[2:3]
-    new_leaf, slot_l, slot_r = tv[3:4], tv[4:5], tv[5:6]
-
-    # Mosaic has no direct uint8 -> f32 cast; hop through int32
-    bins_f = bins_ref[:].astype(jnp.int32).astype(jnp.float32)   # [F, C]
-    iota_f = jax.lax.broadcasted_iota(jnp.int32, (f, chunk), 0) \
-        .astype(jnp.float32)
-    fm = iota_f == feat                                          # [F, C]
-    colv = jnp.sum(jnp.where(fm, bins_f, 0.0), axis=0, keepdims=True)
-    nav = jnp.sum(jnp.where(fm, nab_ref[:].astype(jnp.float32), 0.0),
-                  axis=0, keepdims=True)
-    # all-f32 mask arithmetic: a bool-valued jnp.where lowers to an i1 select
-    # Mosaic cannot truncate to ("Unsupported target bitwidth for truncation")
-    has = jnp.where(feat >= 0, 1.0, 0.0)
-    is_na = jnp.where(colv == nav, 1.0, 0.0)
-    gr_na = jnp.where(dleft == 0, 1.0, 0.0)
-    gr_num = jnp.where(colv > thr, 1.0, 0.0)
-    go_right = is_na * gr_na + (1.0 - is_na) * gr_num
-    if has_cat:
-        # categorical membership (CategoricalDecision, tree.h:279): decode the
-        # leaf's [B] bin-membership row, pick the row's bin -> member -> LEFT
-        mem_bc = jax.lax.dot_general(
-            memT_ref[:], oh, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # [B, C] 0/1
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (b, chunk), 0) \
-            .astype(jnp.float32)
-        member = jnp.sum(jnp.where(iota_b == colv, mem_bc, 0.0),
-                         axis=0, keepdims=True)
-        iscat = tv[6:7]
-        go_right = iscat * (1.0 - member) + (1.0 - iscat) * go_right
-    lid2 = jnp.where(has * go_right > 0, new_leaf, lid)
-    slot = has * (go_right * slot_r + (1.0 - go_right) * slot_l) \
-        + (1.0 - has) * float(s)
-    slot_out[:] = slot.astype(jnp.int32).reshape(chunk)
-    lid_out[:] = lid2.astype(jnp.int32).reshape(chunk)
+        bins_ref, lid_ref, tabs_ref, slot_out, lid_out = refs
+        memT_ref = None
+    slot, lid2 = _route_chunk(lid_ref[:].reshape(1, chunk),
+                              bins_ref[:].astype(jnp.int32), tabs_ref,
+                              memT_ref, f=f, s=s, chunk=chunk)
+    slot_out[:] = slot.reshape(chunk)
+    lid_out[:] = lid2.reshape(chunk)
 
 
 def route_level_pallas(bins_T, leaf_id, tables, na_bin, num_slots: int,
-                       num_leaves: int, chunk: int = 0,
+                       chunk: int = 0,
                        interpret: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Pallas DataPartition::Split analog. Returns (slot [N] i32, lid2 [N] i32).
 
     ``bins_T``: the [F, N] rows that ``tables.feat`` indexes — the level's
-    split columns from histogram.py route_rows, at most 128 of them.
+    split columns from histogram.py route_rows, at most 128 of them, and
+    ``na_bin`` their missing bins. ``tables``: as hist_routed_fused_q8.
     chunk=0 picks automatically: _CHUNK_Q8 up to 256 rows (+4% end-to-end at
     10M measured with the q8 kernel at the same chunk), _CHUNK above — the
     f32 [F, chunk] per-chunk intermediates double with the chunk."""
     if chunk == 0:
         chunk = _CHUNK_Q8 if bins_T.shape[0] <= 256 else _CHUNK
     f, n = bins_T.shape
-    l, s = num_leaves, num_slots
     has_cat = tables.is_cat is not None
-    tabs = _route_tabs(tables, l)
-    nab = na_bin.astype(jnp.float32).reshape(f, 1)
+    tabs = _route_tabs(tables, na_bin)
 
     bins_Tp = _pad_rows(bins_T, chunk)
     lid_p = _pad_rows(leaf_id, chunk)
@@ -1022,18 +1026,17 @@ def route_level_pallas(bins_T, leaf_id, tables, na_bin, num_slots: int,
     in_specs = [
         pl.BlockSpec((f, chunk), lambda i: (0, i), memory_space=pltpu.VMEM),
         pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
-        pl.BlockSpec((8, l), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((f, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec(tabs.shape, lambda i: (0, 0), memory_space=pltpu.VMEM),
     ]
-    args = [bins_Tp, lid_p, tabs, nab]
-    b_mem = tables.member.shape[1] if has_cat else 1
+    args = [bins_Tp, lid_p, tabs]
     if has_cat:
-        in_specs.append(pl.BlockSpec((b_mem, l), lambda i: (0, 0),
+        memT = _member_tab(tables)
+        in_specs.append(pl.BlockSpec(memT.shape, lambda i: (0, 0),
                                      memory_space=pltpu.VMEM))
-        args.append(tables.member.astype(jnp.float32).T)
+        args.append(memT)
 
-    kern = functools.partial(_route_kernel, f=f, l=l, s=s, chunk=chunk,
-                             b=b_mem, has_cat=has_cat)
+    kern = functools.partial(_route_kernel, f=f, s=num_slots, chunk=chunk,
+                             has_cat=has_cat)
     slot, lid2 = pl.pallas_call(
         kern,
         name="route_level",
@@ -1051,6 +1054,11 @@ def route_level_pallas(bins_T, leaf_id, tables, na_bin, num_slots: int,
     )(*args)
     return slot[:n], lid2[:n]
 
+
+# ---------------------------------------------------------------------------
+# small-table gather: table[idx] as a one-hot [L, C] mask contraction, as the
+# level kernels' decode above, over an f32 table (leaf values)
+# ---------------------------------------------------------------------------
 
 def _take_kernel(tab_ref, idx_ref, out_ref, *, l: int, chunk: int):
     idx = idx_ref[:].reshape(1, chunk)

@@ -29,17 +29,23 @@ F, B, L = 28, 64, 255
 N = 50_000
 
 
-def _route_tables(rng, l, f, b, s):
+def _route_tables(rng, l, f, b, s, categorical=False):
     """Random per-leaf split tables; ~1/8 of the leaves do not split."""
     feat = rng.randint(0, f, size=l).astype(np.int32)
     feat[rng.rand(l) < 0.125] = -1
+    cat = {}
+    if categorical:
+        cat = {"is_cat": jnp.asarray((rng.rand(l) < 0.5).astype(np.int32)),
+               "member": jnp.asarray((rng.rand(l, b) < 0.5)
+                                     .astype(np.float32))}
     return H.RouteTables(
         feat=jnp.asarray(feat),
         thr=jnp.asarray(rng.randint(0, b, size=l).astype(np.int32)),
         dleft=jnp.asarray(rng.randint(0, 2, size=l).astype(np.int32)),
         new_leaf=jnp.asarray(rng.permutation(l).astype(np.int32)),
         slot_left=jnp.asarray(rng.randint(0, s + 1, size=l).astype(np.int32)),
-        slot_right=jnp.asarray(rng.randint(0, s + 1, size=l).astype(np.int32)))
+        slot_right=jnp.asarray(rng.randint(0, s + 1, size=l).astype(np.int32)),
+        **cat)
 
 
 def check_hist_pallas():
@@ -98,21 +104,25 @@ def check_hist_pallas_q8():
 
 
 def check_route_level():
-    """Standalone route kernel vs the XLA gather route."""
+    """Standalone route kernel vs the XLA gather route: a small level, and
+    one whose leaf, feature and slot ids need the decode's high byte
+    (600 leaves, 300 columns, 290 slots), with categorical tables."""
     rng = np.random.RandomState(2)
-    l, s = 8, 4
-    n, f, b = 30000, 5, 16
-    bins = rng.randint(0, b, size=(n, f)).astype(np.uint8)
-    leaf_id = jnp.asarray(rng.randint(0, l, size=n).astype(np.int32))
-    na_bin = jnp.asarray(np.array([3, 256, 256, 7, 256], dtype=np.int32))
-    tables = _route_tables(rng, l, f, b, s)
-    ref_slot, ref_lid = H.route_level(jnp.asarray(bins), leaf_id, tables,
-                                      na_bin, s)
-    out_slot, out_lid = PH.route_level_pallas(
-        jnp.asarray(bins.T.copy()), leaf_id, tables, na_bin, s, l)
-    np.testing.assert_array_equal(np.asarray(ref_lid), np.asarray(out_lid))
-    np.testing.assert_array_equal(np.minimum(np.asarray(ref_slot), s),
-                                  np.minimum(np.asarray(out_slot), s))
+    for l, s, f, b, categorical in ((8, 4, 5, 16, False),
+                                    (600, 290, 300, 64, True)):
+        n = 30000
+        bins = rng.randint(0, b, size=(n, f)).astype(np.uint8)
+        leaf_id = jnp.asarray(rng.randint(0, l, size=n).astype(np.int32))
+        na_bin = jnp.asarray(np.where(np.arange(f) % 3 == 0, 3, 256)
+                             .astype(np.int32))
+        tables = _route_tables(rng, l, f, b, s, categorical)
+        ref_slot, ref_lid = H.route_level(jnp.asarray(bins), leaf_id, tables,
+                                          na_bin, s)
+        out_slot, out_lid = PH.route_level_pallas(
+            jnp.asarray(bins.T.copy()), leaf_id, tables, na_bin, s)
+        np.testing.assert_array_equal(np.asarray(ref_lid), np.asarray(out_lid))
+        np.testing.assert_array_equal(np.minimum(np.asarray(ref_slot), s),
+                                      np.minimum(np.asarray(out_slot), s))
 
 
 def check_take_small():
@@ -244,21 +254,27 @@ def check_fused_level():
     gq = rng.randint(-127, 128, size=N).astype(np.int8)
     hq = rng.randint(0, 128, size=N).astype(np.int8)
     cq = (rng.rand(N) < 0.8).astype(np.int8)
-    lid = jnp.asarray(rng.randint(0, L, size=N).astype(np.int32))
     # a few features carry a missing bin so the default-direction branch runs
     na = np.full(F, 256, np.int32)
     na[::5] = B - 2
     na_bin = jnp.asarray(na)
     h_const = 0.5   # keeps the reference's f32 hessian sums exact
-    for s in (32, 127, 128):
-        tables = _route_tables(rng, L, F, B, s)
+    # live: the leaves that hold rows. The S = 32 group's tables are cut to
+    # them (32 of 255, the decode the grower hands a shallow level), once
+    # with categorical splits
+    for s, live, categorical in ((32, L, False), (32, 32, False),
+                                 (32, 32, True), (127, L, True),
+                                 (127, L, False), (128, L, False)):
+        tables = _route_tables(rng, L, F, B, s, categorical)
+        lid = jnp.asarray(rng.randint(0, live, size=N).astype(np.int32))
         for const_hess in (False, True):
             hrow = cq if const_hess else hq
             scale_h = 127.0 * h_const if const_hess else 127.0
             hist, lid2 = PH.hist_routed_fused_q8(
                 bins_T, jnp.asarray(gq), jnp.asarray(hrow), jnp.asarray(cq),
-                lid, tables, na_bin, s, B, jnp.float32(127.0),
-                jnp.float32(scale_h), L, const_hess=const_hess)
+                lid, jax.tree.map(lambda a: a[:live], tables), na_bin, s, B,
+                jnp.float32(127.0), jnp.float32(scale_h),
+                const_hess=const_hess)
             # scale 127 -> dequantization factor exactly 1: the reference
             # accumulates the same integers in f32 (|sums| < 2^24, exact)
             h_ref = (cq * np.float32(h_const) if const_hess
@@ -270,7 +286,8 @@ def check_fused_level():
             np.testing.assert_array_equal(np.asarray(lid2), np.asarray(rlid))
             np.testing.assert_allclose(np.asarray(hist), np.asarray(rhist),
                                        rtol=0, atol=0.25,
-                                       err_msg=f"S={s} const_hess={const_hess}")
+                                       err_msg=f"S={s} live={live} "
+                                       f"const_hess={const_hess}")
 
 
 CHECKS = (check_hist_pallas, check_hist_pallas_q8, check_route_level,

@@ -248,7 +248,7 @@ def test_route_level_pallas_matches_xla():
                                       tables, jnp.asarray(na_bin), S)
     out_slot, out_lid = route_level_pallas(
         jnp.asarray(bins.T.copy()), jnp.asarray(leaf_id), tables,
-        jnp.asarray(na_bin), S, L, interpret=True)
+        jnp.asarray(na_bin), S, interpret=True)
     np.testing.assert_array_equal(np.asarray(ref_lid), np.asarray(out_lid))
     # sentinel slots (>= S) may differ in exact value; compare clamped
     np.testing.assert_array_equal(np.minimum(np.asarray(ref_slot), S),

@@ -159,7 +159,7 @@ def test_routers_agree(f):
     want_slot, want_lid = _route_by_hand(bins, leaf_id, t, na_bin, S)
     args = (jnp.asarray(leaf_id), t, jnp.asarray(na_bin), S)
     xs, xl = H.route_level(jnp.asarray(bins), *args)
-    ps, pl_ = ph.route_level_pallas(jnp.asarray(bins.T.copy()), *args, L,
+    ps, pl_ = ph.route_level_pallas(jnp.asarray(bins.T.copy()), *args,
                                     interpret=True)
     # what the growers call: the kernel over the level's split columns
     rs, rl = H.route_rows(jnp.asarray(bins), jnp.asarray(bins.T.copy()),
@@ -179,7 +179,9 @@ def _case_tables(case, rng, f, L, S):
              dleft=rng.randint(0, 2, size=L).astype(np.int32),
              new_leaf=(np.arange(L) + L).astype(np.int32))
     k = {"no_split_leaves": 2, "k_is_num_slots": S}.get(case, min(L, S) // 2)
-    split = np.sort(rng.choice(L, size=k, replace=False))
+    # live_leaves: every splitting leaf among the first S, where the rows are
+    among = S if case.startswith("live_leaves") else L
+    split = np.sort(rng.choice(among, size=k, replace=False))
     t["feat"][split] = rng.randint(0, f, size=k)
     t["feat"][split[0]] = f - 1                     # the matrix's last row
     if case == "same_feature":
@@ -195,7 +197,7 @@ def _case_tables(case, rng, f, L, S):
         t["slot_right"] = np.where(left, S, idx)
     for name in ("slot_left", "slot_right"):
         t[name] = np.where(t["feat"] >= 0, t[name], S).astype(np.int32)
-    if case == "categorical":
+    if case.endswith("categorical"):
         is_cat = np.zeros(L, np.int32)
         is_cat[split[::2]] = 1
         t["is_cat"] = is_cat
@@ -213,19 +215,30 @@ def _case_tables(case, rng, f, L, S):
     ("lean_two_slots", 2000, 64, 32, 2500),
     ("k_is_num_slots", 520, 64, 32, 4096 + 17),
     ("k_is_num_slots", 2000, 255, 127, 2 * 4096 + 5),
+    ("live_leaves", 2000, 255, 32, 3000),
+    ("live_leaves_categorical", 520, 64, 8, 4096 + 17),
+    ("thr_255", 2000, 255, 32, 3000),
+    ("thr_255", 40, 16, 8, 2500),
 ])
 def test_route_rows_equals_route_level(case, f, L, S, n):
     """``route_rows`` on the Pallas path (the route kernel over the level's
     split columns) gives the integers of ``route_level``, the XLA reference:
     with categorical splits, missing bins under both defaults, leaves that
     do not split, two leaves on one feature, the lean grower's two slots a
-    split, as many splits as slots, N off the kernel's chunk."""
+    split, as many splits as slots, N off the kernel's chunk, tables cut to
+    the leaves that hold rows (the reference reads the whole ones),
+    thresholds of 255."""
     rng = np.random.RandomState(len(case) * 1000 + f)
     bins = rng.randint(0, 63, size=(n, f)).astype(np.uint8)
     bins[:, f - 1] = rng.randint(200, 256, size=n)  # past int8: the pick-up
-    leaf_id = rng.randint(0, L, size=n).astype(np.int32)
+    live = S if case.startswith("live_leaves") else L
+    leaf_id = rng.randint(0, live, size=n).astype(np.int32)
     t, S = _case_tables(case, rng, f, L, S)
     na_bin = np.where(rng.rand(f) < 0.3, 5, 256).astype(np.int32)
+    if case == "thr_255":       # on the column that holds bins up to 255
+        thr = np.asarray(t.thr).copy()
+        thr[np.asarray(t.feat) == f - 1] = 255
+        t = t._replace(thr=jnp.asarray(thr))
     if case == "missing_both_defaults":
         feat, dleft = np.asarray(t.feat), np.asarray(t.dleft)
         na_bin[feat[feat >= 0]] = 5
@@ -233,8 +246,10 @@ def test_route_rows_equals_route_level(case, f, L, S, n):
         assert (bins[:, feat[feat >= 0]] == 5).any()
     args = (jnp.asarray(leaf_id), t, jnp.asarray(na_bin), S)
     want_slot, want_lid = H.route_level(jnp.asarray(bins), *args)
+    cut = jax.tree.map(lambda a: a[:live], t)
     slot, lid = H.route_rows(jnp.asarray(bins), jnp.asarray(bins.T.copy()),
-                             *args, impl="pallas")
+                             jnp.asarray(leaf_id), cut, jnp.asarray(na_bin),
+                             S, impl="pallas")
     np.testing.assert_array_equal(np.asarray(lid), np.asarray(want_lid))
     np.testing.assert_array_equal(np.asarray(slot), np.asarray(want_slot))
     moved = np.asarray(lid) != leaf_id
@@ -299,23 +314,29 @@ def test_route_level_never_widens_the_matrix():
 @pytest.mark.parametrize("f,max_bin,impl,want", [
     (520, 15, "pallas", {"level_kernel": "hist_leaf_q8", "feature_groups": 17,
                          "route": "pallas", "front": "unfused",
-                         "bins_T_cached": True}),
+                         "bins_T_cached": True, "decode_leaves": [4]}),
     (40, 63, "pallas", {"level_kernel": "hist_leaf_q8", "feature_groups": 2,
                         "route": "pallas", "front": "unfused",
-                        "bins_T_cached": True}),
+                        "bins_T_cached": True, "decode_leaves": [4]}),
     (28, 63, "pallas", {"level_kernel": "hist_level_q8", "feature_groups": 1,
                         "route": "fused", "front": "fused",
-                        "bins_T_cached": True}),
+                        "bins_T_cached": True, "decode_leaves": [4]}),
     (28, 63, "scatter", {"level_kernel": "scatter", "feature_groups": 1,
                          "route": "xla", "front": "unfused",
-                         "bins_T_cached": False}),
+                         "bins_T_cached": False, "decode_leaves": [1, 4]}),
+    # the published shape: a level's leaves in the S = 32 group, num_leaves
+    # in the group that holds the tail
+    (28, 63, "pallas", {"level_kernel": "hist_level_q8", "route": "fused",
+                        "num_leaves": 255, "decode_leaves": [32, 255]}),
 ])
 def test_hist_path_event(f, max_bin, impl, want):
     X, y = _data(f, n=400)
+    want = dict(want)
     obs.reset()
     obs.configure(enabled=True)
     try:
-        p = {"objective": "binary", "num_leaves": 4, "max_bin": max_bin,
+        p = {"objective": "binary", "num_leaves": want.pop("num_leaves", 4),
+             "max_bin": max_bin,
              "verbosity": -1, "histogram_impl": impl, "telemetry": True}
         lgb.train(p, lgb.Dataset(X, label=y, params=p), num_boost_round=1)
         events = [e for e in obs.EVENTS.snapshot() if e["type"] == "hist_path"]
