@@ -385,6 +385,18 @@ class GBDT:
                 mesh_preflight(config, train_set, plan)
             if not quiet:
                 self._emit_hist_allreduce_probe()
+        if not quiet:
+            # the row grid this trainer ADOPTED: Dataset.construct may have
+            # re-planned or dropped the grid it first published after a
+            # device fault (ingest.stream_with_recovery), so a run on fewer
+            # shards than it asked for shows here and nowhere else
+            n_pad = int(train_set.num_data) + (self._pad_rows if self._dp
+                                               else 0)
+            shards = int(self._mesh.devices.shape[0]) if self._dp else 1
+            obs.emit("shard_plan", num_shards=shards,
+                     rows_per_shard=n_pad // shards,
+                     pad_rows=n_pad - int(train_set.num_data),
+                     feature_shards=int(getattr(plan, "feature_shards", 1)))
         # background AOT compile handed over by Dataset.construct (prewarm.py);
         # resolved lazily at the first _fused_step dispatch so the compile
         # keeps overlapping whatever runs between construction and training.
@@ -687,6 +699,35 @@ class GBDT:
                 and getattr(self, "_plan", None) is None
                 and pick_impl(self.gp.hist_impl) == "pallas")
 
+    def _grad_rows_spec(self):
+        """(spec, aux_rows) where the objective's gradients are a function of
+        the score and ONE per-row constant that the step can take as an
+        argument (ObjectiveFunction.fused_grad_spec; ops/pallas_hist.
+        _grad_rows replays the objective's own float32 ops), else (None,
+        None). Every auto-gradient step of such an objective computes its
+        gradients from (score, aux): closed over by obj.get_gradients the
+        rows would be a literal of the traced program, [N] floats of it
+        (588 MB at 147 M rows), and the persistent compile cache would key
+        on the labels. Whether the grower fuses them into the root pass is
+        _fused_front's to say."""
+        obj = self.objective
+        if obj is None or self.num_tree_per_iteration != 1:
+            return None, None
+        return obj.fused_grad_spec() or (None, None)
+
+    def _row_sharding(self):
+        """Sharding of the step's [N] row arguments under a row-shard plan
+        whose grid divides the rows: the trainer then lays the bag weights
+        and the objective's rows over the plan's mesh ONCE, as the grower's
+        shard_map wants them. Left on the default device, every dispatch
+        copied them to the other chips again. None elsewhere: padded grids
+        (the step pads [N] vectors itself) and pod mode (replicated global
+        arrays) keep the compiler's choice."""
+        plan = getattr(self, "_plan", None)
+        if plan is None or plan.pad_rows or getattr(self, "_pod", False):
+            return None
+        return plan.sharding(1)
+
     def _fused_front(self):
         """(spec, aux_rows) for the fused grad+quant+hist0 front
         (ops/histogram.grad_quant_hist0), or (None, None) when any gate
@@ -711,18 +752,14 @@ class GBDT:
             return cached
         res = (None, None)
         gp = self.gp
-        obj = self.objective
-        if (self.num_tree_per_iteration == 1 and obj is not None
-                and self.config.grow_policy == "depthwise"
+        if (self.config.grow_policy == "depthwise"
                 and gp.quant and gp.lean_ft <= 0
                 and not self._dp and not self._fp
                 and getattr(self, "_plan", None) is None
                 and self._cegb_dev is None and self._forced_dev is None):
             from ..ops.histogram import pick_impl
             if pick_impl(gp.hist_impl) == "pallas":
-                fs = obj.fused_grad_spec()
-                if fs is not None:
-                    res = fs
+                res = self._grad_rows_spec()
         self._fused_front_cache = res
         return res
 
@@ -954,11 +991,13 @@ class GBDT:
         nf = self._nf_policy
         use_bt = self._use_bt()
         fused_spec = None if custom else self._fused_front()[0]
+        rows_spec = None if custom else self._grad_rows_spec()[0]
         gp = self.gp
         if self.config.grow_policy == "depthwise" and gp.lean_ft <= 0:
             # what the default depthwise grower's level passes will run at
             # this width (ops/histogram.hist_routed selects it at trace time)
-            from ..ops.grow_depthwise import level_groups
+            from ..ops.grow_depthwise import (allreduce_bytes_per_tree,
+                                              level_groups)
             from ..ops.histogram import (hist_path, one_kernel_front,
                                          pick_impl)
             width = int(self.train_set.num_features), int(gp.max_bin)
@@ -966,11 +1005,17 @@ class GBDT:
             # fused spec) and its own gate says so
             one_kernel = (fused_spec is not None
                           and one_kernel_front(*width, gp.hist_impl))
-            groups = level_groups(gp.num_leaves, gp.max_depth,
-                                  pick_impl(gp.hist_impl) == "pallas")
+            pallas = pick_impl(gp.hist_impl) == "pallas"
+            groups = level_groups(gp.num_leaves, gp.max_depth, pallas)
+            reduced = {}
+            if self._dp and gp.voting_top_k <= 0:
+                # what one iteration hands to the cross-chip reduction
+                reduced["allreduce_bytes_per_iter"] = k * \
+                    allreduce_bytes_per_tree(gp.num_leaves, gp.max_depth,
+                                             *width, pallas)
             obs.emit("hist_path", front="fused" if one_kernel else "unfused",
                      bins_T_cached=bool(use_bt),
-                     decode_leaves=[g[3] for g in groups],
+                     decode_leaves=[g[3] for g in groups], **reduced,
                      **hist_path(*width, gp.hist_impl, bool(gp.quant)))
 
         def step(bins, num_bins, na_bin, score, fmask, bag_mask, grad, hess,
@@ -978,7 +1023,12 @@ class GBDT:
             bt = bins_t if use_bt else None
             if not custom and fused_spec is None:
                 with jax.named_scope("front"), jax.named_scope("grad"):
-                    grad, hess = obj.get_gradients(score)
+                    if rows_spec is not None:
+                        # the objective's rows are the argument ``aux``
+                        from ..ops.pallas_hist import _grad_rows
+                        grad, hess = _grad_rows(rows_spec, score, aux)
+                    else:
+                        grad, hess = obj.get_gradients(score)
             # else fused front: the grower derives gradients from
             # (score, aux) in-register — the full-N g/h arrays are never
             # materialized (two HBM round-trips fewer per iteration)
@@ -1028,6 +1078,13 @@ class GBDT:
                              lids)
             return trees, new_score, cegb_st, ok
 
+        if self._dp:
+            # a module name of its own (jit_step_dp): a trace tells the
+            # sharded step from the serial one, and the persistent compile
+            # cache, whose key leaves metadata out, cannot hand back an
+            # executable built before the reduction and the transpose had
+            # their scopes (utils.timer.scoped_jit has the same remedy)
+            step.__name__ = step.__qualname__ = "step_dp"
         # built once per (config, schema) by the caller, which caches the
         # wrapper on the instance — not a per-call rebuild
         return jax.jit(step)   # tpu-lint: disable=retrace-hazard
@@ -1134,7 +1191,8 @@ class GBDT:
                     self._bag_ones = replicate_global(
                         np.ones(n, np.float32), self._plan.mesh)
                 else:
-                    self._bag_ones = jnp.ones(n, dtype=jnp.float32)
+                    self._bag_ones = jnp.ones(n, dtype=jnp.float32,
+                                              device=self._row_sharding())
             bag = self._bag_ones
         dummy = jnp.zeros((), jnp.float32)
         shrink = 1.0 if self.average_output else self.learning_rate
@@ -1147,9 +1205,19 @@ class GBDT:
                                         self._fp_na_bin)
         else:
             bins_arg, nb_arg, na_arg = ts.bins, ts.num_bins_dev, ts.na_bin_dev
-        fused_spec, fused_aux = (None, None) if custom else self._fused_front()
+        rows_spec, rows_aux = ((None, None) if custom
+                               else self._grad_rows_spec())
         bt_in = ts.bins_T if self._use_bt() else dummy
-        aux_in = fused_aux if fused_spec is not None else dummy
+        over_mesh = self._row_sharding()
+        if rows_spec is not None and over_mesh is not None:
+            # (the objective's array, its copy over the mesh): laid out
+            # again only when the objective holds another array
+            held = getattr(self, "_aux_on_mesh", None)
+            if held is None or held[0] is not rows_aux:
+                held = self._aux_on_mesh = (
+                    rows_aux, jax.device_put(rows_aux, over_mesh))
+            rows_aux = held[1]
+        aux_in = rows_aux if rows_spec is not None else dummy
         args = (bins_arg, nb_arg, na_arg,
                 self.train_score, self._feature_mask(), bag,
                 grad if custom else dummy,

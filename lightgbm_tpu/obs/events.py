@@ -70,10 +70,19 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # bin matrix; decode_leaves: for each level group of the schedule
     # (ops/grow_depthwise.level_groups) the leaves its route tables hold, the
     # width of the kernels' per-row split-table decode ([32, 255] at 255
-    # leaves)
+    # leaves); allreduce_bytes_per_iter, on a data-parallel step only: the
+    # bytes one iteration hands to the cross-chip reduction, from shapes
+    # (ops/grow_depthwise.allreduce_bytes_per_tree)
     "hist_path": ({"level_kernel": str, "feature_groups": int, "route": str,
                    "front": str, "bins_T_cached": bool,
-                   "decode_leaves": list}, {}),
+                   "decode_leaves": list},
+                  {"allreduce_bytes_per_iter": int}),
+    # the row grid the trainer adopted (models/gbdt.py, once per trainer):
+    # after ingest.stream_with_recovery may have re-planned or dropped the
+    # grid Dataset.construct first published. One shard of all the rows
+    # where the trainer is not data-parallel
+    "shard_plan": ({"num_shards": int, "rows_per_shard": int,
+                    "pad_rows": int, "feature_shards": int}, {}),
     # a jitted program was built (host-side tracing/lowering observed via
     # the function's cache size; device code itself is unchanged)
     "compile": ({"what": str, "cache_size": int},

@@ -87,10 +87,35 @@ class GrowParams:
     fused_obj: tuple = None
 
 
+# device scope of every cross-chip reduction of the growers (the histogram
+# of a level, the root's, the exact leaf sums): what a chip spends starting
+# and awaiting them reads under this name in a trace
+ALLREDUCE_SCOPE = "hist_allreduce"
+
+
 def _psum(x, gp: "GrowParams"):
     if gp.axis_name:
-        return jax.lax.psum(x, gp.axis_name)
+        with jax.named_scope(ALLREDUCE_SCOPE):
+            return jax.lax.psum(x, gp.axis_name)
     return x
+
+
+def _rows(x):
+    """A float32 count channel (of a histogram, of the leaf sums) as the
+    int32 row count a tree keeps: float32 holds odd numbers only up to 2^24,
+    and a leaf of a table of a hundred million rows can hold more rows than
+    that (the model text's ``leaf_count`` was then off by one)."""
+    return jnp.round(x).astype(jnp.int32)
+
+
+def _leaf_sums_allreduce(local, gp: "GrowParams"):
+    """A shard's exact per-leaf sums ``[3, L]`` (grad, hess, rows) over all
+    shards: (G, H) float32 and the rows int32. The rows cross the chips as
+    integers: summed as float32, the shards' counts round where a leaf's
+    total is odd and above 2^24. (Within a shard the kernels still count in
+    float32: exact while one shard's part of a leaf is under 2^24 rows.)"""
+    gh = _psum(local[:2], gp)
+    return gh[0], gh[1], _psum(_rows(local[2]), gp)
 
 
 def _hist_allreduce(hist, gp: "GrowParams", f_dim: int):
@@ -109,12 +134,13 @@ def _hist_allreduce(hist, gp: "GrowParams", f_dim: int):
     fa, k = gp.feature_axis_name, gp.feature_shards
     F = hist.shape[f_dim]
     if not fa or k <= 1 or F % k != 0:
-        return jax.lax.psum(hist, gp.axis_name)
+        return _psum(hist, gp)
     blk = F // k
-    j = jax.lax.axis_index(fa)
-    sub = jax.lax.dynamic_slice_in_dim(hist, j * blk, blk, axis=f_dim)
-    sub = jax.lax.psum(sub, gp.axis_name)
-    return jax.lax.all_gather(sub, fa, axis=f_dim, tiled=True)
+    with jax.named_scope(ALLREDUCE_SCOPE):
+        j = jax.lax.axis_index(fa)
+        sub = jax.lax.dynamic_slice_in_dim(hist, j * blk, blk, axis=f_dim)
+        sub = jax.lax.psum(sub, gp.axis_name)
+        return jax.lax.all_gather(sub, fa, axis=f_dim, tiled=True)
 
 
 class TreeArrays(NamedTuple):
@@ -131,7 +157,7 @@ class TreeArrays(NamedTuple):
     split_gain: jnp.ndarray      # [L-1] f32
     leaf_value: jnp.ndarray      # [L] f32
     leaf_weight: jnp.ndarray     # [L] f32 (sum_hess)
-    leaf_count: jnp.ndarray      # [L] f32
+    leaf_count: jnp.ndarray      # [L] i32 (rows; see _rows)
     internal_value: jnp.ndarray  # [L-1] f32
     internal_weight: jnp.ndarray # [L-1] f32
     internal_count: jnp.ndarray  # [L-1] f32
@@ -168,7 +194,7 @@ def _empty_tree(L: int, B: int = 256) -> TreeArrays:
         split_feature=zi, threshold_bin=zi, default_left=jnp.zeros_like(zi, dtype=bool),
         left_child=zi, right_child=zi, split_gain=zf,
         leaf_value=jnp.zeros(L, jnp.float32), leaf_weight=jnp.zeros(L, jnp.float32),
-        leaf_count=jnp.zeros(L, jnp.float32),
+        leaf_count=jnp.zeros(L, jnp.int32),
         internal_value=zf, internal_weight=zf, internal_count=zf,
         num_leaves=jnp.int32(1),
         is_cat=jnp.zeros(max(L - 1, 1), dtype=bool),
@@ -241,7 +267,8 @@ def grow_tree(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray, c: jnp.ndarray,
     if H.pick_impl(gp.hist_impl) != "pallas":
         bins_T = None
     elif bins_T is None:
-        bins_T = bins.T
+        with jax.named_scope("bins_T"):
+            bins_T = bins.T
     hist0 = _psum(H.hist_leaf(bins, g, h, c, B, gp.hist_impl, bins_T=bins_T),
                   gp)                                                  # [3, F, B]
     g0, h0, c0 = hist0[0, 0].sum(), hist0[1, 0].sum(), hist0[2, 0].sum()
@@ -439,7 +466,8 @@ def grow_tree(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray, c: jnp.ndarray,
                 split_gain=tr.split_gain.at[t].set(best_eff.gain[l]),
                 leaf_value=tr.leaf_value.at[l].set(w_l).at[new_leaf].set(w_r),
                 leaf_weight=tr.leaf_weight.at[l].set(lh).at[new_leaf].set(rh),
-                leaf_count=tr.leaf_count.at[l].set(lc).at[new_leaf].set(rc),
+                leaf_count=tr.leaf_count.at[l].set(_rows(lc))
+                .at[new_leaf].set(_rows(rc)),
                 internal_value=tr.internal_value.at[t].set(w_p),
                 internal_weight=tr.internal_weight.at[t].set(ph),
                 internal_count=tr.internal_count.at[t].set(pc),
@@ -527,6 +555,6 @@ def grow_tree(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray, c: jnp.ndarray,
         leaf_weight=jnp.where(tree.num_leaves > 1, tree.leaf_weight,
                               tree.leaf_weight.at[0].set(h0)),
         leaf_count=jnp.where(tree.num_leaves > 1, tree.leaf_count,
-                             tree.leaf_count.at[0].set(c0)),
+                             tree.leaf_count.at[0].set(_rows(c0))),
     )
     return tree, state.leaf_id

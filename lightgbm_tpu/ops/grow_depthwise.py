@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from . import histogram as H
 from .grow import (GrowParams, TreeArrays, _empty_tree, _hist_allreduce,
-                   _psum)
+                   _leaf_sums_allreduce, _psum, _rows)
 from .split import (NEG_INF, SplitParams, SplitResult, best_split,
                     leaf_output, per_feature_gains)
 
@@ -127,8 +127,8 @@ def _apply_level_to_tree(tr: TreeArrays, parent_node, parent_right, res,
             _scatter_set(tr.leaf_weight, leaves_iota, lh, sel),
             new_leaf, rh, sel),
         leaf_count=_scatter_set(
-            _scatter_set(tr.leaf_count, leaves_iota, lc, sel),
-            new_leaf, rc, sel),
+            _scatter_set(tr.leaf_count, leaves_iota, _rows(lc), sel),
+            new_leaf, _rows(rc), sel),
         internal_value=_scatter_set(tr.internal_value, node_id, w_p, sel),
         internal_weight=_scatter_set(tr.internal_weight, node_id,
                                      lh + rh, sel),
@@ -216,6 +216,28 @@ def level_groups(num_leaves: int, max_depth: int, use_pallas: bool):
         else:
             groups.append([max_slots, n_unroll, max_levels])
     return [(w, k0, k1, min(L, 2 ** (k1 - 1))) for w, k0, k1 in groups]
+
+
+def allreduce_bytes_per_tree(num_leaves: int, max_depth: int,
+                             num_features: int, max_bin: int,
+                             use_pallas: bool) -> int:
+    """Bytes one tree of the default depthwise data-parallel grower hands to
+    the cross-chip reduction (``_hist_allreduce`` / ``_psum``), from shapes:
+    the root's ``[3, F, B]`` float32 histogram, one ``[S, 3, F, B]`` a level
+    at the level's slot width over the ceil(log2(num_leaves)) levels a
+    balanced tree takes (a deeper tree adds one full-width reduction for each
+    level of the tail), and the ``[3, L]`` exact leaf sums. Every chip sends
+    and receives that much whatever the number of chips. The voting learner
+    exchanges less and is not counted here."""
+    cell = 3 * num_features * max_bin
+    levels = max(1, math.ceil(math.log2(max(num_leaves, 2))))
+    if max_depth > 0:
+        levels = min(levels, max_depth)
+    slots = sum((min(levels, k1) - k0) * w
+                for w, k0, k1, _ in level_groups(num_leaves, max_depth,
+                                                 use_pallas)
+                if k0 < levels)
+    return 4 * (cell + slots * cell + 3 * num_leaves)
 
 
 def _run_level_schedule(state, level, L, groups):
@@ -312,7 +334,8 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     if not use_pallas:
         bins_T = None
     elif bins_T is None:
-        bins_T = bins.T
+        with jax.named_scope("bins_T"):
+            bins_T = bins.T
     with jax.named_scope("front"):
         if fused is not None:
             # fused grad+quant+hist0 front: gradients recomputed in-register
@@ -376,7 +399,7 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     state = state._replace(tree=state.tree._replace(
         leaf_value=state.tree.leaf_value.at[0].set(root_w),
         leaf_weight=state.tree.leaf_weight.at[0].set(h0),
-        leaf_count=state.tree.leaf_count.at[0].set(c0)))
+        leaf_count=state.tree.leaf_count.at[0].set(_rows(c0))))
 
     leaves_iota = jnp.arange(L, dtype=jnp.int32)
 
@@ -572,13 +595,13 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
                 # local top-2k one-hot vote, tallied across shards
                 thresh2 = jax.lax.top_k(score, k2)[0][-1]
                 votes = (score >= thresh2).astype(jnp.float32)
-                votes = jax.lax.psum(votes, gp.axis_name)
+                votes = _psum(votes, gp)
                 # deterministic global election: top-k by (votes, score-sum)
-                global_score = jax.lax.psum(score, gp.axis_name)
+                global_score = _psum(score, gp)
                 elect_key = votes * 1e12 + global_score
                 elected = jax.lax.top_k(elect_key, k)[1]       # [k] feature ids
                 sub = jnp.take(hist_pass, elected, axis=2)     # [S_pass, 3, k, B]
-                sub = jax.lax.psum(sub, gp.axis_name)
+                sub = _psum(sub, gp)
                 elected_mask = jnp.zeros(f, bool).at[elected].set(True)
                 # non-elected entries must NOT keep local (shard-divergent)
                 # values: state feeds the replicated split selection and the loop
@@ -672,22 +695,21 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
             # hist_impl would run the interpreter inside the jitted tree on TPU
             interp = jax.default_backend() == "cpu"
             if fused is not None and use_pallas:
-                sums = _psum(leaf_sums_grad_pallas(f_score, f_aux, f_bag,
-                                                   state.leaf_id, gp.fused_obj,
-                                                   L, interpret=interp), gp)
+                sums = leaf_sums_grad_pallas(f_score, f_aux, f_bag,
+                                             state.leaf_id, gp.fused_obj,
+                                             L, interpret=interp)
             elif fused is not None:
                 # XLA fallback: rebuild the exact rows the unfused path would
                 # have passed in (bit-identical f32 ops, see _grad_rows)
                 from .pallas_hist import _grad_rows
                 fg_, fh_ = _grad_rows(gp.fused_obj, f_score, f_aux)
-                sums = _psum(leaf_sums_pallas(fg_ * f_bag, fh_ * f_bag,
-                                              (f_bag > 0).astype(jnp.float32),
-                                              state.leaf_id, L,
-                                              interpret=interp), gp)
+                sums = leaf_sums_pallas(fg_ * f_bag, fh_ * f_bag,
+                                        (f_bag > 0).astype(jnp.float32),
+                                        state.leaf_id, L, interpret=interp)
             else:
-                sums = _psum(leaf_sums_pallas(g, h, c, state.leaf_id, L,
-                                              interpret=interp), gp)
-            eg, eh, ec = sums[0], sums[1], sums[2]
+                sums = leaf_sums_pallas(g, h, c, state.leaf_id, L,
+                                        interpret=interp)
+            eg, eh, ec = _leaf_sums_allreduce(sums, gp)
             w = leaf_output(eg, eh, sp)
             if sp.has_monotone:
                 w = jnp.clip(w, state.leaf_min, state.leaf_max)
@@ -800,7 +822,8 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
     if not use_pallas:
         bins_T = None
     elif bins_T is None:
-        bins_T = bins.T
+        with jax.named_scope("bins_T"):
+            bins_T = bins.T
     # quantization mirrors hist_routed exactly (histogram.py:433-436): the
     # q8 kernel on the pallas path, per-row dequantized channels elsewhere —
     # so lean and default growers see the SAME histogram numbers per impl
@@ -892,7 +915,7 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
     state = state._replace(tree=state.tree._replace(
         leaf_value=state.tree.leaf_value.at[0].set(root_w),
         leaf_weight=state.tree.leaf_weight.at[0].set(h0),
-        leaf_count=state.tree.leaf_count.at[0].set(c0)))
+        leaf_count=state.tree.leaf_count.at[0].set(_rows(c0))))
     leaves_iota = jnp.arange(L, dtype=jnp.int32)
 
     def level(st: _LeanState, SLOTS: int, lvl, l_dec: int):
@@ -1003,9 +1026,9 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
     if gp.quant:
         with jax.named_scope("leaf_renew"):
             # leaf renewal from EXACT sums (same epilogue as the default grower)
-            sums = _psum(leaf_sums_pallas(g, h, c, state.leaf_id, L,
-                                          interpret=interp), gp)
-            eg, eh, ec = sums[0], sums[1], sums[2]
+            eg, eh, ec = _leaf_sums_allreduce(
+                leaf_sums_pallas(g, h, c, state.leaf_id, L,
+                                 interpret=interp), gp)
             w = leaf_output(eg, eh, sp)
             if sp.has_monotone:
                 w = jnp.clip(w, state.leaf_min, state.leaf_max)

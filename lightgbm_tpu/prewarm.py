@@ -108,6 +108,7 @@ def step_spec(gbdt) -> Dict[str, Any]:
         # matrix both change the traced program (and the argument avals);
         # neither is fully derivable from the conf fields alone
         "fused": gbdt._fused_front()[0],
+        "rows": gbdt._grad_rows_spec()[0],
         "bt": gbdt._use_bt(),
         "conf": {k: getattr(conf, k, None) for k in _SPEC_KEYS},
     }
@@ -131,6 +132,8 @@ def step_avals(gbdt, custom: bool = False):
     k = gbdt.num_tree_per_iteration
     plan = getattr(gbdt, "_plan", None)
     S = jax.ShapeDtypeStruct
+    # [N] row arguments the trainer lays over the plan's mesh itself
+    rows = gbdt._row_sharding()
     score = S((n,) if k == 1 else (n, k), np.float32)
     sc_f = S((), np.float32)
 
@@ -148,15 +151,15 @@ def step_avals(gbdt, custom: bool = False):
         bins_aval = S((n, f), np.uint8)
     gh = score if custom else sc_f      # explicit gradients are score-shaped
     # the cached [F, N] transposed bin matrix rides along on serial Pallas
-    # trainers; the fused grad+quant+hist0 front adds the objective's aux
-    # rows (auto path only). Both fall back to the scalar dummy aval the
-    # dispatch passes when the corresponding gate is off.
+    # trainers; an objective whose gradients the step computes from
+    # (score, aux) adds its aux rows (auto path only). Both fall back to the
+    # scalar dummy aval the dispatch passes when the corresponding gate is
+    # off.
     bt = S((f, n), np.uint8) if gbdt._use_bt() else sc_f
-    fused_spec, fused_aux = (None, None) if custom else gbdt._fused_front()
-    if fused_spec is not None:
-        import jax as _jax
-        aux = _jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype),
-                                      fused_aux)
+    rows_spec, rows_aux = (None, None) if custom else gbdt._grad_rows_spec()
+    if rows_spec is not None:
+        aux = jax.tree_util.tree_map(
+            lambda a: S(a.shape, a.dtype, sharding=rows), rows_aux)
     else:
         aux = sc_f
     return (bins_aval,                  # bins
@@ -164,7 +167,7 @@ def step_avals(gbdt, custom: bool = False):
             S((f,), np.int32),          # na_bin
             score,                      # train score
             S((f,), np.bool_),          # feature mask
-            S((n,), np.float32),        # bag weights
+            S((n,), np.float32, sharding=rows),   # bag weights
             gh, gh,                     # grad/hess (dummies on auto path)
             sc_f,                       # shrink
             S((), np.int32),            # qseed
