@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import json
 import time
-from typing import Any, Dict, List, Optional, Sequence, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -156,15 +156,35 @@ class Dataset:
         self._bins_T = None
 
     @property
+    def bins_T_shape(self) -> Tuple[int, int]:
+        """(F_pad, N_pad): the shape ``bins_T`` has, or will be built to.
+        Known once the metadata is published, while the matrix still streams
+        (the window in which the background prewarm lowers its step)."""
+        from .ops.histogram import bin_axis
+        from .ops.pallas_hist import resident_shape
+        n = self.num_data if self.bins is None else self.bins.shape[0]
+        return resident_shape(n, self.num_features,
+                              bin_axis(self.max_num_bins))
+
+    @property
     def bins_T(self):
-        """Device-resident transposed bin matrix [F_used, N], built lazily on
-        first use and cached. The Pallas histogram kernels consume
-        feature-major rows; before this cache every grower call rebuilt
-        ``bins.T`` inside its traced step — a full-matrix HBM transpose per
-        tree. Invalidated by the ``bins`` setter whenever the matrix
-        changes."""
+        """Device-resident transposed bin matrix [F_pad, N_pad], built lazily
+        on first use and cached. The Pallas histogram kernels consume
+        feature-major rows, in row chunks and feature groups: built here in
+        that shape once (ops/pallas_hist.resident_shape: columns padded with
+        zeros to a multiple of 8,192, rows to the feature groups'
+        fg * n_groups) the matrix is read in place by every level pass,
+        where an [F_used, N] one was copied by a pad in each. The serial
+        Pallas trainer alone asks for it (GBDT._use_bt) and its growers
+        bring their row vectors to N_pad to match
+        (ops/histogram.resident_rows). ``bins`` stays [N, F_used]: every other
+        consumer (prediction, validation, subset, the scatter and one-hot
+        histograms, the sharded trainers) reads N rows as before, and none
+        of them pays for rows that carry no weight. Invalidated by the
+        ``bins`` setter whenever the matrix changes."""
         if self._bins_T is None:
-            self._bins_T = self.bins.T
+            from .ops.pallas_hist import resident_bins_T
+            self._bins_T = resident_bins_T(self.bins, self.bins_T_shape)
         return self._bins_T
 
     # ---- construction ----

@@ -104,11 +104,9 @@ class GBDT:
         else:
             self._has_init_score = False
 
-        # pad the bin axis to a lane-friendly width: a non-aligned [T, F, B] ->
-        # [T, F*B] reshape forces a relayout copy every histogram tile (measured
-        # 2.2x slower at B=63 vs B=64 on v5e)
-        maxb = train_set.max_num_bins
-        B = 64 if maxb <= 64 else (128 if maxb <= 128 else 256)
+        # the bin axis padded to a lane-friendly width
+        from ..ops.histogram import bin_axis
+        B = bin_axis(train_set.max_num_bins)
         from ..binning import BIN_CATEGORICAL
         meta = getattr(train_set, "bundle_meta", None)
         if meta is not None:
@@ -688,12 +686,15 @@ class GBDT:
     # the device idle between programs; the whole gradients->grow->score-update
     # chain runs as ONE jitted call) ----
     def _use_bt(self) -> bool:
-        """Whether the step feeds the Dataset's cached [F, N] transposed bin
-        matrix to the growers. Serial Pallas trainers only: the per-tree
-        ``bins.T`` rebuild inside the growers was a full-matrix HBM
-        transpose per tree; dp/fp shard the matrix and keep the old path.
-        A mesh-native row-shard plan also opts out: transposing the
-        row-sharded matrix would be an all-to-all reshard."""
+        """Whether the step feeds the Dataset's cached transposed bin matrix
+        (``Dataset.bins_T``, [F_pad, N_pad]: the kernels' shape) to the
+        growers, which then keep every row vector of a tree N_pad long
+        (ops/histogram.resident_rows): no level pass pads or slices an
+        array. Serial Pallas trainers only: the per-tree ``bins.T`` rebuild
+        inside the growers was a full-matrix HBM transpose per tree; dp/fp
+        shard the matrix and keep the old path. A mesh-native row-shard
+        plan also opts out: transposing the row-sharded matrix would be an
+        all-to-all reshard."""
         from ..ops.histogram import pick_impl
         return (not self._dp and not self._fp
                 and getattr(self, "_plan", None) is None
@@ -952,11 +953,16 @@ class GBDT:
             # zero-filled products XLA dead-code-eliminates
             fused = ((new_score, aux, bag_mask)
                      if fused_spec is not None else None)
-            tree, leaf_id, cegb_st = do_grow(
+            tree, leaf_res, cegb_st = do_grow(
                 bins, g * bag_mask, h * bag_mask,
                 (bag_mask > 0).astype(jnp.float32),
                 num_bins, na_bin, fmask, qseed * k + cls, cegb_st,
                 bt, fused)
+            # next to a resident bins_T the leaf ids are N_pad long, no leaf
+            # past N (ops/histogram.resident_rows): the score update reads
+            # them, and the step returns them, as they lie; the objective's
+            # renewal takes the N rows its labels have
+            n = new_score.shape[0]
             # average-output mode (RF) never renews: its slow path skips
             # _finish_tree's renewal too (rf.py RF._finish_tree), and the
             # L1-family renewal semantics assume an additive boosted score
@@ -967,7 +973,8 @@ class GBDT:
                     s_cls = new_score[:, cls]
                 else:
                     s_cls = jnp.take(new_score, cls, axis=1)
-                renewed = obj.renew_leaf_values(s_cls, leaf_id, gp.num_leaves)
+                renewed = obj.renew_leaf_values(s_cls, leaf_res[:n],
+                                                gp.num_leaves)
                 if renewed is not None:
                     live = jnp.arange(gp.num_leaves) < tree.num_leaves
                     tree = tree._replace(leaf_value=jnp.where(
@@ -977,10 +984,10 @@ class GBDT:
                 leaf_value=tree.leaf_value * shrink,
                 internal_value=tree.internal_value * shrink)
             with jax.named_scope("score_update"):
-                delta = take_rows(tree.leaf_value, leaf_id)
+                delta = take_rows(tree.leaf_value, leaf_res)[:n]
                 new_score = self._apply_tree_delta(new_score, delta, cls,
                                                    titer)
-            return tree, leaf_id, new_score, cegb_st
+            return tree, leaf_res, new_score, cegb_st
 
         return one_class
 
@@ -1013,8 +1020,13 @@ class GBDT:
                 reduced["allreduce_bytes_per_iter"] = k * \
                     allreduce_bytes_per_tree(gp.num_leaves, gp.max_depth,
                                              *width, pallas)
+            # what the step is handed: the cached matrix in the kernels'
+            # shape, or rows and features as they are (padded in every pass)
+            res_f, res_n = (self.train_set.bins_T_shape if use_bt
+                            else (width[0], int(self.train_set.num_data)))
             obs.emit("hist_path", front="fused" if one_kernel else "unfused",
                      bins_T_cached=bool(use_bt),
+                     resident_rows=int(res_n), resident_features=int(res_f),
                      decode_leaves=[g[3] for g in groups], **reduced,
                      **hist_path(*width, gp.hist_impl, bool(gp.quant)))
 
@@ -1648,6 +1660,8 @@ class GBDT:
                                               bundle=self._bundle_dev,
                                               forced=self._forced_dev,
                                               **qkw2)
+            # a grower handed the resident bins_T returns N_pad leaf ids
+            leaf_id = leaf_id[: ts.num_data]
             tree_dev = self._finish_tree(tree_dev, leaf_id, cls)
             self.models_dev.append(tree_dev)
             self._update_scores(tree_dev, leaf_id, cls)

@@ -67,7 +67,12 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # route_level's gathers) or "fused" (onehot: routed in its scan). front:
     # whether gradients, quantisation and the root histogram are one kernel;
     # bins_T_cached: whether the step is fed the Dataset's cached transposed
-    # bin matrix; decode_leaves: for each level group of the schedule
+    # bin matrix; resident_rows / resident_features: that matrix's columns
+    # and rows, padded once to the kernels' shape (a multiple of 8,192; the
+    # feature groups' fg * n_groups: ops/pallas_hist.resident_shape), or N
+    # and F where the step is handed no such matrix and every level pass
+    # pads its operands itself (resident_rows not a multiple of the row
+    # chunk then says so); decode_leaves: for each level group of the schedule
     # (ops/grow_depthwise.level_groups) the leaves its route tables hold, the
     # width of the kernels' per-row split-table decode ([32, 255] at 255
     # leaves); allreduce_bytes_per_iter, on a data-parallel step only: the
@@ -75,6 +80,7 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # (ops/grow_depthwise.allreduce_bytes_per_tree)
     "hist_path": ({"level_kernel": str, "feature_groups": int, "route": str,
                    "front": str, "bins_T_cached": bool,
+                   "resident_rows": int, "resident_features": int,
                    "decode_leaves": list},
                   {"allreduce_bytes_per_iter": int}),
     # the row grid the trainer adopted (models/gbdt.py, once per trainer):

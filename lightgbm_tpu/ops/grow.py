@@ -231,7 +231,8 @@ def grow_tree(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray, c: jnp.ndarray,
     ForceSplits-before-normal-growth (serial_tree_learner.cpp:456-618).
     Forced mode keeps the full [L] histogram state (the pool's evicted
     parents could not provide the forced split's cumsum). ``qseed`` drives
-    per-node feature sampling when gp.ff_bynode < 1.
+    per-node feature sampling when gp.ff_bynode < 1. ``bins_T`` and the
+    length of the returned ``leaf_id``: as grow_tree_depthwise.
     """
     n, f = bins.shape
     L, B = gp.num_leaves, gp.max_bin
@@ -259,7 +260,6 @@ def grow_tree(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray, c: jnp.ndarray,
         return jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(sp.extra_seed), base), tag)
 
-    leaf_id = jnp.zeros(n, dtype=jnp.int32)
     # pallas kernels read a transposed bin matrix: use the Dataset's cached
     # device-resident copy when the caller passes one (no per-tree N*F HBM
     # transpose), else build it once per tree (XLA CSEs it across all
@@ -269,6 +269,7 @@ def grow_tree(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray, c: jnp.ndarray,
     elif bins_T is None:
         with jax.named_scope("bins_T"):
             bins_T = bins.T
+    g, h, c, _, leaf_id = H.resident_rows(bins_T, n, L, g, h, c)
     hist0 = _psum(H.hist_leaf(bins, g, h, c, B, gp.hist_impl, bins_T=bins_T),
                   gp)                                                  # [3, F, B]
     g0, h0, c0 = hist0[0, 0].sum(), hist0[1, 0].sum(), hist0[2, 0].sum()
@@ -367,7 +368,10 @@ def grow_tree(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray, c: jnp.ndarray,
 
             # ---- partition rows (reference: DataPartition::Split,
             # data_partition.hpp:113 — here a vectorized where on leaf_id) ----
-            col = bins[:, feat].astype(jnp.int32)
+            # a row of the transposed matrix where there is one: as long as
+            # leaf_id, and read in place
+            col = (bins[:, feat] if bins_T is None
+                   else bins_T[feat]).astype(jnp.int32)
             is_na = col == na_bin[feat]
             go_right = jnp.where(is_na, ~dleft, col > thr)
             if sp.cat_features or sp.has_bundles:

@@ -317,8 +317,11 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     is passed (gp.split.has_cegb; penalties recomputed fresh each level, so the
     reference's stale-cache fixups in UpdateLeafBestSplits are unnecessary).
 
-    ``bins_T``: optional cached [F, N] transpose (Dataset.bins_T) — skips the
-    per-tree transpose on the pallas path. ``fused``: (score, aux, bag) row
+    ``bins_T``: optional cached transpose (Dataset.bins_T) — skips the
+    per-tree transpose on the pallas path. Shaped for the kernels,
+    [F_pad, N_pad], it makes every row vector of the tree N_pad long
+    (histogram.resident_rows) and ``leaf_id`` is returned so: entries past N
+    hold num_leaves, no leaf. ``fused``: (score, aux, bag) row
     inputs for the fused grad+quant+hist0 front, valid only with
     gp.fused_obj set and gp.quant on; the g/h/c arguments are then unused
     placeholders (the quantized channels and all histogram passes derive
@@ -336,7 +339,10 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     elif bins_T is None:
         with jax.named_scope("bins_T"):
             bins_T = bins.T
+    c_rows = c                      # [N]: CEGB's per-row bookkeeping
     with jax.named_scope("front"):
+        g, h, c, fused, leaf_id0 = H.resident_rows(bins_T, n, L, g, h, c,
+                                                   fused)
         if fused is not None:
             # fused grad+quant+hist0 front: gradients recomputed in-register
             # from (score, aux, bag), never materialized as [N] rows — the
@@ -378,7 +384,7 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
         cegb_on = sp.has_cegb
 
     state = _DWState(
-        leaf_id=jnp.zeros(n, dtype=jnp.int32),
+        leaf_id=leaf_id0,
         forced_ptr=jnp.full(L, -1, jnp.int32).at[0].set(
             0 if forced is not None else -1),
         vote_mask=jnp.ones((L, f), dtype=bool),
@@ -441,9 +447,10 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
                     # the bagged partition — c is the in-bag channel)
                     fresh = jnp.where(st.cegb.data_used, 0.0,
                                       st.cegb.lazy_pen[None, :])      # [N, F]
-                    fresh = fresh * (c > 0)[:, None]
+                    fresh = fresh * (c_rows > 0)[:, None]
                     lazy_cost = _psum(
-                        jax.ops.segment_sum(fresh, st.leaf_id, num_segments=L), gp)
+                        jax.ops.segment_sum(fresh, st.leaf_id[:n],
+                                            num_segments=L), gp)
                     pen = pen + sp.cegb_tradeoff * lazy_cost
 
             # ---- best split for every frontier leaf (one batched kernel) ----
@@ -543,8 +550,8 @@ def grow_tree_depthwise(bins: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
                     cegb2.feature_used, feat, jnp.ones(L, bool), sel))
             if cegb_on and sp.cegb_lazy:
                 feat_of_leaf = jnp.where(sel, feat, _OOB)
-                f_row = feat_of_leaf[st.leaf_id]                     # [N]
-                f_row = jnp.where(c > 0, f_row, _OOB)  # OOB rows never pay
+                f_row = feat_of_leaf[st.leaf_id[:n]]                 # [N]
+                f_row = jnp.where(c_rows > 0, f_row, _OOB)  # OOB rows never pay
                 cegb2 = cegb2._replace(data_used=cegb2.data_used.at[
                     jnp.arange(n), f_row].set(True, mode="drop"))
 
@@ -807,9 +814,10 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
       per-tile winners — live histogram memory is [2S, 3, ft, B] for one
       tile, chosen by GBDT to fit histogram_pool_size.
 
-    Not combined with voting/CEGB/forced-splits/ff_bynode (GBDT keeps
-    the default grower and warns). Ties across missing-direction planes of
-    different tiles may break differently from the monolithic search (both
+    ``bins_T`` and the length of the returned ``leaf_id``: as
+    grow_tree_depthwise. Not combined with voting/CEGB/forced-splits/ff_bynode
+    (GBDT keeps the default grower and warns). Ties across
+    missing-direction planes of different tiles may break differently from the monolithic search (both
     prefer the lower feature id within a plane).
     """
     n, f = bins.shape
@@ -828,6 +836,7 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
     # q8 kernel on the pallas path, per-row dequantized channels elsewhere —
     # so lean and default growers see the SAME histogram numbers per impl
     with jax.named_scope("front"):
+        g, h, c, _, leaf_id0 = H.resident_rows(bins_T, n, L, g, h, c)
         quant = (H.make_quant(g, h, c, qseed, const_hess=gp.const_hess)
                  if gp.quant else None)
         if quant is not None and not use_pallas:
@@ -873,7 +882,7 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
 
     # ---- root ----
     with jax.named_scope("front"):
-        zeros_slot = jnp.zeros(n, jnp.int32)
+        zeros_slot = jnp.zeros_like(leaf_id0)
         # root stats from one tiny exact pass (leaf renewal needs them anyway)
         from .pallas_hist import leaf_sums_pallas
         if use_pallas:
@@ -899,7 +908,7 @@ def grow_tree_depthwise_lean(bins: jnp.ndarray, g, h, c, num_bins, na_bin,
         return SplitResult(*out)
 
     state = _LeanState(
-        leaf_id=jnp.zeros(n, dtype=jnp.int32),
+        leaf_id=leaf_id0,
         rec=pad_rec(rec0),
         leaf_g=jnp.zeros(L).at[0].set(g0),
         leaf_h=jnp.zeros(L).at[0].set(h0),

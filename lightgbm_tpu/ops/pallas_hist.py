@@ -40,6 +40,11 @@ _CHUNK = 1024          # rows per grid step (onehot block [F*B, C] bf16 ~3.7MB)
 # stays at 1024 (hi/lo doubles its weight rows)
 _CHUNK_Q8 = 4096
 _ACC_ROWS_MAX = 2048   # Fg*B cap: keeps the f32 accumulator block <= ~6.3MB
+# every row chunk a wrapper below can choose divides this (_CHUNK, the 2048
+# fallbacks, _CHUNK_Q8, the 8192 of leaf_sums and take_small): a matrix and
+# row vectors padded to its multiple once (resident_shape) are tiled by every
+# kernel as they lie, and the wrappers' _pad_rows / [:n] do nothing
+_ROW_ALIGN = 8192
 
 # Master slot-width set: every Pallas level pass floors its slot count to one
 # of these widths, so the depthwise default grower and the lean grower reuse
@@ -70,6 +75,25 @@ def one_group(f: int, b: int) -> bool:
     """Every feature fits one accumulator block: the condition of the
     kernels that must see all columns (hist_level_q8, grad_quant_hist0)."""
     return f * b <= _ACC_ROWS_MAX
+
+
+def resident_shape(n: int, f: int, b: int) -> Tuple[int, int]:
+    """(F_pad, N_pad) of an [F, N] transposed bin matrix in the shape the
+    kernels tile: rows up to the feature groups' fg * n_groups (F itself where
+    one group holds every feature), columns up to the next multiple of
+    _ROW_ALIGN. What Dataset.bins_T is built to, once; a matrix of any other
+    shape is padded by the wrappers in every pass (_pad_rows)."""
+    fg, n_fg = feature_grouping(f, b)
+    return fg * n_fg, -(-n // _ROW_ALIGN) * _ROW_ALIGN
+
+
+@functools.partial(jax.jit, static_argnames=("shape",))
+def resident_bins_T(bins: jnp.ndarray, shape: Tuple[int, int]) -> jnp.ndarray:
+    """[N, F] uint8 bins -> the transposed matrix at ``shape`` = (F_pad,
+    N_pad) of resident_shape, the padding zero: bin 0 of rows that carry no
+    weight in any channel, and of features no split table names."""
+    n, f = bins.shape
+    return jnp.pad(bins.T, ((0, shape[0] - f), (0, shape[1] - n)))
 
 
 def _kernel(bins_ref, g_ref, h_ref, c_ref, slot_ref, out_ref, *,
@@ -137,7 +161,7 @@ def hist_pallas(bins_T: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     g/h/c: [N] f32 channels (zero for out-of-bag rows);
     slot: [N] i32 in [0, num_slots); rows with slot >= num_slots are dropped.
     """
-    f, n = bins_T.shape
+    f = bins_T.shape[0]
     b, s = num_bins, num_slots
 
     fg, n_fg = feature_grouping(f, b)
@@ -152,7 +176,8 @@ def hist_pallas(bins_T: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
     # padded rows carry zero channels; droppped slots (>= s) become s below
     slot = _pad_rows(slot, chunk, value=s)
     slot = jnp.minimum(slot, s)  # anything out of range masks to zero weight
-    n_chunks = bins_T.shape[1] // chunk
+    n_p = bins_T.shape[1]           # the columns the kernel reads
+    n_chunks = n_p // chunk
 
     kern = functools.partial(_kernel, fg=fg, b=b, s=s, chunk=chunk)
     out = pl.pallas_call(
@@ -175,8 +200,8 @@ def hist_pallas(bins_T: jnp.ndarray, g: jnp.ndarray, h: jnp.ndarray,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((f_pad * b, s * 5), jnp.float32),
         cost_estimate=pl.CostEstimate(
-            flops=2 * n * f_pad * b * s * 5,
-            bytes_accessed=n * (f_pad + 16) + f_pad * b * s * 20,
+            flops=2 * n_p * f_pad * b * s * 5,
+            bytes_accessed=n_p * (f_pad + 16) + f_pad * b * s * 20,
             transcendentals=0),
         interpret=interpret,
     )(bins_T, g, h, c, slot)
@@ -331,7 +356,7 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
     dequantized (count channel is exact). const_hess drops the in-kernel
     hessian channel (2-channel MXU contraction) and reconstructs it as
     count * scale_h/127 — exact for h = h_const * bag01 rows."""
-    f, n = bins_T.shape
+    f = bins_T.shape[0]
     b, s = num_bins, num_slots
     nch = _q8_nch(const_hess)
     fg, n_fg = feature_grouping(f, b)
@@ -353,7 +378,8 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
     cq = _pad_rows(cq, chunk)
     slot = _pad_rows(slot, chunk, value=s)
     slot = jnp.minimum(slot, s)
-    n_chunks = bins_T.shape[1] // chunk
+    n_p = bins_T.shape[1]
+    n_chunks = n_p // chunk
 
     kern = functools.partial(_kernel_q8, fg=fg, b=b, s=s, chunk=chunk,
                              nch=nch, swar=_swar_ok(b, interpret))
@@ -377,8 +403,8 @@ def hist_pallas_q8(bins_T: jnp.ndarray, gq: jnp.ndarray, hq: jnp.ndarray,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((f_pad * b, s * nch), jnp.int32),
         cost_estimate=pl.CostEstimate(
-            flops=2 * n * f_pad * b * s * nch,
-            bytes_accessed=n * (f_pad + 7) + f_pad * b * s * 4 * nch,
+            flops=2 * n_p * f_pad * b * s * nch,
+            bytes_accessed=n_p * (f_pad + 7) + f_pad * b * s * 4 * nch,
             transcendentals=0),
         interpret=interpret,
     )(bins_T, gq, hq, cq, slot)
@@ -583,7 +609,8 @@ def hist_routed_fused_q8(bins_T, gq, hq, cq, leaf_id, tables, na_bin,
     hq = _pad_rows(hq, chunk)
     cq = _pad_rows(cq, chunk)
     lid_p = _pad_rows(leaf_id, chunk, value=l)  # padded rows: no leaf -> the
-    n_chunks = bins_Tp.shape[1] // chunk        # decode yields feat=-1 -> drop
+    n_p = bins_Tp.shape[1]                      # decode yields feat=-1 -> drop
+    n_chunks = n_p // chunk
 
     in_specs = [
         pl.BlockSpec((f, chunk), lambda i: (0, i), memory_space=pltpu.VMEM),
@@ -615,12 +642,16 @@ def hist_routed_fused_q8(bins_T, gq, hq, cq, leaf_id, tables, na_bin,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((f * b, s * nch), jnp.int32),
-            jax.ShapeDtypeStruct((bins_Tp.shape[1],), jnp.int32),
+            jax.ShapeDtypeStruct((n_p,), jnp.int32),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=2 * n * f * b * s * nch + 2 * n * l * 16,
-            bytes_accessed=n * (f + 11) + f * b * s * 4 * nch,
+            flops=2 * n_p * f * b * s * nch + 2 * n_p * l * 16,
+            bytes_accessed=n_p * (f + 11) + f * b * s * 4 * nch,
             transcendentals=0),
+        # the leaf ids are renewed in place, a chunk read before it is
+        # written: a level loop that carries them then holds one buffer,
+        # where the compiler copied the carry ahead of every pass
+        input_output_aliases={4: 1},
         interpret=interpret,
     )(*args)
 
@@ -861,7 +892,8 @@ def grad_quant_hist0_pallas(bins_T, score, aux, bag, seed, spec,
     score_p = _pad_rows(score, chunk)
     aux_p = _pad_rows(aux, chunk)
     bag_p = _pad_rows(bag, chunk)   # padded rows: bag 0 -> zero channels
-    n_chunks = bins_Tp.shape[1] // chunk
+    n_p = bins_Tp.shape[1]
+    n_chunks = n_p // chunk
     seed_arr = jnp.asarray(seed).astype(jnp.int32).reshape(1, 1)
 
     kern = functools.partial(_grad_quant_kernel, f=f, b=b, chunk=chunk,
@@ -896,17 +928,17 @@ def grad_quant_hist0_pallas(bins_T, score, aux, bag, seed, spec,
                          memory_space=pltpu.VMEM),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((bins_Tp.shape[1],), jnp.int8),
-            jax.ShapeDtypeStruct((bins_Tp.shape[1],), jnp.int8),
-            jax.ShapeDtypeStruct((bins_Tp.shape[1],), jnp.int8),
+            jax.ShapeDtypeStruct((n_p,), jnp.int8),
+            jax.ShapeDtypeStruct((n_p,), jnp.int8),
+            jax.ShapeDtypeStruct((n_p,), jnp.int8),
             jax.ShapeDtypeStruct((8, 128), jnp.float32),
             jax.ShapeDtypeStruct((f * b, nch), jnp.int32),
         ),
         scratch_shapes=[pltpu.VMEM((2, 128), jnp.float32)],
         cost_estimate=pl.CostEstimate(
-            flops=2 * n * f * b * nch + 40 * n,
-            bytes_accessed=n * (f + 12) * 2 + 3 * n + f * b * nch * 4,
-            transcendentals=2 * n if spec[0] == "logloss" else 0),
+            flops=2 * n_p * f * b * nch + 40 * n_p,
+            bytes_accessed=n_p * (f + 12) * 2 + 3 * n_p + f * b * nch * 4,
+            transcendentals=2 * n_p if spec[0] == "logloss" else 0),
         interpret=interpret,
     )(bins_Tp, score_p, aux_p, bag_p, seed_arr)
 
@@ -1050,6 +1082,7 @@ def route_level_pallas(bins_T, leaf_id, tables, na_bin, num_slots: int,
             jax.ShapeDtypeStruct((bins_Tp.shape[1],), jnp.int32),
             jax.ShapeDtypeStruct((bins_Tp.shape[1],), jnp.int32),
         ),
+        input_output_aliases={1: 1},    # as hist_routed_fused_q8
         interpret=interpret,
     )(*args)
     return slot[:n], lid2[:n]

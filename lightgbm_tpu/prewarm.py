@@ -150,12 +150,13 @@ def step_avals(gbdt, custom: bool = False):
     else:
         bins_aval = S((n, f), np.uint8)
     gh = score if custom else sc_f      # explicit gradients are score-shaped
-    # the cached [F, N] transposed bin matrix rides along on serial Pallas
-    # trainers; an objective whose gradients the step computes from
+    # the cached transposed bin matrix, [F_pad, N_pad] in the kernels'
+    # shape, rides along on serial Pallas trainers; an objective whose
+    # gradients the step computes from
     # (score, aux) adds its aux rows (auto path only). Both fall back to the
     # scalar dummy aval the dispatch passes when the corresponding gate is
     # off.
-    bt = S((f, n), np.uint8) if gbdt._use_bt() else sc_f
+    bt = S(ts.bins_T_shape, np.uint8) if gbdt._use_bt() else sc_f
     rows_spec, rows_aux = (None, None) if custom else gbdt._grad_rows_spec()
     if rows_spec is not None:
         aux = jax.tree_util.tree_map(
