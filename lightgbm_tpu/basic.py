@@ -499,6 +499,7 @@ class Dataset:
                         "set (per-feature gain multipliers cannot apply to "
                         "merged bundle columns)")
             return None
+        from . import obs
         from .efb import plan_bundles
         # monotone-constrained features must keep their own columns: the
         # bundle candidate plane does not implement direction filtering
@@ -523,11 +524,18 @@ class Dataset:
             # GLOBAL sample size, not this host's slice of it
             kw["sample_cnt"] = (int(plan_sample_cnt) if plan_sample_cnt
                                 else max(int(sample_bins.shape[0]), 1))
-        return plan_bundles(sample_bins, mappers,
+        meta = plan_bundles(sample_bins, mappers,
                             max_conflict_rate=conf.max_conflict_rate,
                             sparse_threshold=conf.sparse_threshold,
                             seed=conf.data_random_seed, exclude=excl,
                             reduce_fn=reduce_fn, **kw)
+        merged = ([int(meta.num_bins[i]) for i in np.flatnonzero(
+            meta.is_bundle)] if meta is not None else [])
+        obs.emit("efb_plan", columns_in=int(len(mappers)),
+                 columns_out=int(meta.num_columns if meta is not None
+                                 else len(mappers)),
+                 bundles=len(merged), largest_bundle_bins=max(merged, default=0))
+        return meta
 
     _EFB_PLAN_SAMPLE = 50_000   # plan_bundles' own default sample size
 

@@ -12,6 +12,7 @@ DART (dart.py), GOSS (goss.py), RF (rf.py).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -708,13 +709,13 @@ class GBDT:
         None). Every auto-gradient step of such an objective computes its
         gradients from (score, aux): closed over by obj.get_gradients the
         rows would be a literal of the traced program, [N] floats of it
-        (588 MB at 147 M rows), and the persistent compile cache would key
-        on the labels. Whether the grower fuses them into the root pass is
-        _fused_front's to say."""
+        (588 MB at 147 M rows; [N, K] for softmax's one-hot), and the
+        persistent compile cache would key on the labels. Whether the grower
+        fuses them into the root pass is _fused_front's to say."""
         obj = self.objective
-        if obj is None or self.num_tree_per_iteration != 1:
+        if obj is None:
             return None, None
-        return obj.fused_grad_spec() or (None, None)
+        return obj.grad_rows_spec() or (None, None)
 
     def _row_sharding(self):
         """Sharding of the step's [N] row arguments under a row-shard plan
@@ -753,7 +754,8 @@ class GBDT:
             return cached
         res = (None, None)
         gp = self.gp
-        if (self.config.grow_policy == "depthwise"
+        if (self.num_tree_per_iteration == 1
+                and self.config.grow_policy == "depthwise"
                 and gp.quant and gp.lean_ft <= 0
                 and not self._dp and not self._fp
                 and getattr(self, "_plan", None) is None
@@ -939,40 +941,35 @@ class GBDT:
         def one_class(new_score, cegb_st, grad, hess, cls, bins, num_bins,
                       na_bin, fmask, bag_mask, shrink, qseed, titer,
                       bt=None, aux=None):
-            """Grow and apply one class tree; cls may be a Python int
-            (unrolled small-k path) or a traced i32 (scan path)."""
+            """Grow and apply one class tree. With k > 1 the score and the
+            gradients are class-major [K, N] (a class's rows contiguous) and
+            cls is the class scan's traced i32."""
             if k == 1:
                 g, h = grad, hess
-            elif isinstance(cls, int):
-                g, h = grad[:, cls], hess[:, cls]
             else:
-                g = jnp.take(grad, cls, axis=1)
-                h = jnp.take(hess, cls, axis=1)
+                g, h = grad[cls], hess[cls]
             # fused front: the grower recomputes this class' gradients
             # in-register from (score, aux); g/h stay tracer dummies whose
             # zero-filled products XLA dead-code-eliminates
             fused = ((new_score, aux, bag_mask)
                      if fused_spec is not None else None)
-            tree, leaf_res, cegb_st = do_grow(
-                bins, g * bag_mask, h * bag_mask,
-                (bag_mask > 0).astype(jnp.float32),
-                num_bins, na_bin, fmask, qseed * k + cls, cegb_st,
-                bt, fused)
+            with jax.named_scope("class_tree") if k > 1 \
+                    else contextlib.nullcontext():
+                tree, leaf_res, cegb_st = do_grow(
+                    bins, g * bag_mask, h * bag_mask,
+                    (bag_mask > 0).astype(jnp.float32),
+                    num_bins, na_bin, fmask, qseed * k + cls, cegb_st,
+                    bt, fused)
             # next to a resident bins_T the leaf ids are N_pad long, no leaf
             # past N (ops/histogram.resident_rows): the score update reads
             # them, and the step returns them, as they lie; the objective's
             # renewal takes the N rows its labels have
-            n = new_score.shape[0]
+            n = new_score.shape[-1]
             # average-output mode (RF) never renews: its slow path skips
             # _finish_tree's renewal too (rf.py RF._finish_tree), and the
             # L1-family renewal semantics assume an additive boosted score
             if obj is not None and not self.average_output:
-                if k == 1:
-                    s_cls = new_score
-                elif isinstance(cls, int):
-                    s_cls = new_score[:, cls]
-                else:
-                    s_cls = jnp.take(new_score, cls, axis=1)
+                s_cls = new_score if k == 1 else new_score[cls]
                 renewed = obj.renew_leaf_values(s_cls, leaf_res[:n],
                                                 gp.num_leaves)
                 if renewed is not None:
@@ -986,7 +983,7 @@ class GBDT:
             with jax.named_scope("score_update"):
                 delta = take_rows(tree.leaf_value, leaf_res)[:n]
                 new_score = self._apply_tree_delta(new_score, delta, cls,
-                                                   titer)
+                                                   titer, axis=0)
             return tree, leaf_res, new_score, cegb_st
 
         return one_class
@@ -1030,35 +1027,51 @@ class GBDT:
                      decode_leaves=[g[3] for g in groups], **reduced,
                      **hist_path(*width, gp.hist_impl, bool(gp.quant)))
 
+        if k > 1:
+            # how the step runs its K class trees: one grower program under
+            # a scan over the classes, scores and gradients class-major
+            # inside it; labels_arg: whether the objective's rows reach the
+            # step as an argument or are a literal of its program
+            obs.emit("multiclass", num_class=int(self.num_class),
+                     trees_per_iter=int(k), class_loop="scan",
+                     score_layout="class_major_in_step",
+                     labels_arg=bool(custom or rows_spec is not None))
+
         def step(bins, num_bins, na_bin, score, fmask, bag_mask, grad, hess,
                  shrink, qseed, titer, cegb_st, bins_t, aux):
             bt = bins_t if use_bt else None
+            if k > 1:
+                # K class trees: the step works class-major, [K, N], so a
+                # class tree reads and updates contiguous rows; the trainer's
+                # [N, K] state is turned once on the way in and once out
+                score = score.T
+                if custom:
+                    grad, hess = grad.T, hess.T
             if not custom and fused_spec is None:
                 with jax.named_scope("front"), jax.named_scope("grad"):
                     if rows_spec is not None:
                         # the objective's rows are the argument ``aux``
                         from ..ops.pallas_hist import _grad_rows
                         grad, hess = _grad_rows(rows_spec, score, aux)
+                    elif k > 1:
+                        grad, hess = obj.get_gradients(score.T)
+                        grad, hess = grad.T, hess.T
                     else:
                         grad, hess = obj.get_gradients(score)
             # else fused front: the grower derives gradients from
             # (score, aux) in-register — the full-N g/h arrays are never
             # materialized (two HBM round-trips fewer per iteration)
-            if k <= 8:
-                # small k: Python-unrolled class trees (static cls indexing)
-                trees = []
-                new_score = score
-                for cls in range(k):
-                    tree, leaf_id, new_score, cegb_st = one_class(
-                        new_score, cegb_st, grad, hess, cls, bins, num_bins,
-                        na_bin, fmask, bag_mask, shrink, qseed, titer,
-                        bt, aux)
-                    trees.append((tree, leaf_id))
+            if k == 1:
+                tree, leaf_id, new_score, cegb_st = one_class(
+                    score, cegb_st, grad, hess, 0, bins, num_bins, na_bin,
+                    fmask, bag_mask, shrink, qseed, titer, bt, aux)
+                trees = [(tree, leaf_id)]
             else:
-                # large k (VERDICT r4 weak #4): ONE grower compilation scanned
-                # over the class axis — the reference's per-class loop inside a
-                # single TrainOneIter (gbdt.cpp:401) without per-class dispatch
-                # or k unrolled copies of the grower program
+                # ONE grower program scanned over the class axis — the
+                # reference's per-class loop inside a single TrainOneIter
+                # (gbdt.cpp:401), every class tree from the gradients of the
+                # scores as the iteration found them, without k copies of
+                # the grower in the module
                 def body(carry, cls):
                     new_score, cegb_c = carry
                     tree, leaf_id, new_score, cegb_c = one_class(
@@ -1066,8 +1079,11 @@ class GBDT:
                         na_bin, fmask, bag_mask, shrink, qseed, titer,
                         bt, aux)
                     return (new_score, cegb_c), (tree, leaf_id)
-                (new_score, cegb_st), trees = jax.lax.scan(
+                (new_score, cegb_st), (stacked, lids) = jax.lax.scan(
                     body, (score, cegb_st), jnp.arange(k, dtype=jnp.int32))
+                new_score = new_score.T
+                trees = [(jax.tree.map(lambda a, i=i: a[i], stacked), lids[i])
+                         for i in range(k)]
             # non-finite guard: one fused reduce — the flag rides the same
             # async queue as the leaf counts, so fatal/clip detection costs
             # zero extra host syncs (reference analog: the CHECK macros on
@@ -1079,15 +1095,9 @@ class GBDT:
                         a, nan=0.0, posinf=_NF_CLIP, neginf=-_NF_CLIP),
                         -_NF_CLIP, _NF_CLIP)
                 new_score = _san(new_score)
-                if k <= 8:
-                    trees = [(t._replace(leaf_value=_san(t.leaf_value),
-                                         internal_value=_san(t.internal_value)),
-                              lid) for t, lid in trees]
-                else:
-                    st, lids = trees
-                    trees = (st._replace(leaf_value=_san(st.leaf_value),
-                                         internal_value=_san(st.internal_value)),
-                             lids)
+                trees = [(t._replace(leaf_value=_san(t.leaf_value),
+                                     internal_value=_san(t.internal_value)),
+                          lid) for t, lid in trees]
             return trees, new_score, cegb_st, ok
 
         if self._dp:
@@ -1101,16 +1111,15 @@ class GBDT:
         # wrapper on the instance — not a per-call rebuild
         return jax.jit(step)   # tpu-lint: disable=retrace-hazard
 
-    def _apply_tree_delta(self, score, delta, cls, titer):
+    def _apply_tree_delta(self, score, delta, cls, titer, axis=1):
         """Fold one finished class tree's per-row delta into the score.
-        Boosting adds; RF overrides with the running average. cls is a
-        Python int on the unrolled path, a traced i32 under scan."""
+        Boosting adds; RF overrides with the running average. ``axis`` is
+        the score's class axis: 1 in the trainer's [N, K] state, 0 inside
+        the fused step, where cls is the class scan's traced i32."""
         if self.num_tree_per_iteration == 1:
             return score + delta
-        if isinstance(cls, int):
-            return score.at[:, cls].add(delta)
-        col = jnp.take(score, cls, axis=1) + delta
-        return jax.lax.dynamic_update_index_in_dim(score, col, cls, 1)
+        col = jnp.take(score, cls, axis=axis) + delta
+        return jax.lax.dynamic_update_index_in_dim(score, col, cls, axis)
 
     def _dp_bins(self):
         """Row-sharded [N_pad, F] bins for the data-parallel step.
@@ -1296,22 +1305,6 @@ class GBDT:
                 base_delay=0.05, max_delay=1.0,
                 should_retry=faults.is_device_fault,
                 name="fused_step dispatch")
-        k = self.num_tree_per_iteration
-        if k > 8:
-            # scan path returns class-stacked TreeArrays; unstack in ONE
-            # dispatch (per-field host slicing would cost k * n_fields
-            # host round-trips)
-            stacked, lids = trees
-            unst = getattr(self, "_unstack_fn", None)
-            if unst is None:
-                def _unstack(st, li):
-                    return tuple(
-                        (jax.tree.map(lambda a, i=i: a[i], st), li[i])
-                        for i in range(k))
-                # lazily built ONCE and cached on the instance; later calls
-                # reuse the wrapper, so its trace cache persists
-                unst = self._unstack_fn = jax.jit(_unstack)   # tpu-lint: disable=retrace-hazard
-            trees = list(unst(stacked, lids))
         return trees, new_score, cegb_out, ok
 
     def _podify_args(self, args):

@@ -57,19 +57,15 @@ class RF(GBDT):
         # no shrinkage in RF (rf.hpp); leaf values used as-is
         return tree_dev
 
-    def _apply_tree_delta(self, score, delta, cls, titer):
+    def _apply_tree_delta(self, score, delta, cls, titer, axis=1):
         """Running average over the titer trees seen so far
         (rf.hpp TrainOneIter), replacing boosting's additive update in the
         fused step."""
-        k = self.num_tree_per_iteration
-        if k == 1:
+        if self.num_tree_per_iteration == 1:
             return (score * (titer - 1.0) + delta) / titer
-        if isinstance(cls, int):
-            prev = score[:, cls] * (titer - 1.0)
-            return score.at[:, cls].set((prev + delta) / titer)
-        col = (jnp.take(score, cls, axis=1) * (titer - 1.0) + delta) / titer
+        col = (jnp.take(score, cls, axis=axis) * (titer - 1.0) + delta) / titer
         import jax
-        return jax.lax.dynamic_update_index_in_dim(score, col, cls, 1)
+        return jax.lax.dynamic_update_index_in_dim(score, col, cls, axis)
 
     def _apply_valid_delta(self, score, vdelta, cls: int):
         """Valid scores are running averages too (rf.hpp TrainOneIter)."""

@@ -62,6 +62,15 @@ class ObjectiveFunction:
         RenewTreeOutput, regression_objective.hpp). Returns None if not needed."""
         return None
 
+    def grad_rows_spec(self):
+        """(spec, aux_rows) where the gradients are a function of the score
+        and ONE per-row constant the fused step can take as an argument
+        (ops/pallas_hist._grad_rows computes them from it), else None. What
+        the kernels can replay in-register (fused_grad_spec) is such a
+        function; an objective over all K scores of a row (softmax) is one
+        too, though no kernel replays it."""
+        return self.fused_grad_spec()
+
     def fused_grad_spec(self):
         """Static spec for the fused grad+quant+hist kernel front, or None.
 
@@ -342,6 +351,14 @@ class MulticlassSoftmax(ObjectiveFunction):
             grad = grad * self.weight[:, None]
             hess = hess * self.weight[:, None]
         return grad, hess
+
+    def grad_rows_spec(self):
+        # the step computes the K class rows from (score, label) itself:
+        # closed over by get_gradients the [N, K] one-hot would be a literal
+        # of the traced program, and the compile cache would key on the labels
+        if self.weight is not None:
+            return None
+        return ("softmax", int(self.num_class)), self.label_int
 
     def convert_output(self, score):
         return jax.nn.softmax(score, axis=-1)

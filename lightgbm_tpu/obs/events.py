@@ -83,6 +83,20 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
                    "resident_rows": int, "resident_features": int,
                    "decode_leaves": list},
                   {"allreduce_bytes_per_iter": int}),
+    # the fused step of a trainer with K > 1 trees an iteration was built
+    # (models/gbdt.py): class_loop says how the K class trees run ("scan":
+    # one grower program scanned over the classes), score_layout where the
+    # scores are class-major ("class_major_in_step": [K, N] inside the step,
+    # [N, K] in the trainer's state), labels_arg whether the objective's rows
+    # are an argument of the step (false: a literal of its program)
+    "multiclass": ({"num_class": int, "trees_per_iter": int,
+                    "class_loop": str, "score_layout": str},
+                   {"labels_arg": bool}),
+    # Dataset.construct planned its bundles (basic.Dataset._plan_efb): the
+    # used columns before and after, how many of the columns out hold two
+    # members or more, and the widest such column's bins
+    "efb_plan": ({"columns_in": int, "columns_out": int, "bundles": int,
+                  "largest_bundle_bins": int}, {}),
     # the row grid the trainer adopted (models/gbdt.py, once per trainer):
     # after ingest.stream_with_recovery may have re-planned or dropped the
     # grid Dataset.construct first published. One shard of all the rows

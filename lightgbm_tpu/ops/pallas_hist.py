@@ -762,7 +762,8 @@ def _grad_rows(spec, score, aux):
     ("l2",) for unweighted RegressionL2 (grad = score - label, hess = 1) or
     ("logloss", sigmoid, lw_pos, lw_neg) for unweighted Binary. Ops and
     association order match the objective code exactly so the f32 results
-    are bit-identical."""
+    are bit-identical. ("softmax", K) is the step's alone, not a kernel's
+    (objectives.MulticlassSoftmax.grad_rows_spec)."""
     kind = spec[0]
     if kind == "l2":
         return score - aux, jnp.ones_like(score)
@@ -773,6 +774,15 @@ def _grad_rows(spec, score, aux):
         resp = 1.0 / (1.0 + jnp.exp(t * sigmoid * score))
         grad = -t * resp * sigmoid * lw
         hess = sigmoid * sigmoid * resp * (1.0 - resp) * lw
+        return grad, hess
+    if kind == "softmax":
+        # MulticlassSoftmax.get_gradients class-major: score [K, N], aux the
+        # [N] i32 labels -> grad/hess [K, N], a class's rows contiguous
+        k = spec[1]
+        prob = jax.nn.softmax(score, axis=0)
+        onehot = (jnp.arange(k, dtype=aux.dtype)[:, None] == aux[None, :])
+        grad = prob - onehot.astype(jnp.float32)
+        hess = (k / (k - 1.0)) * prob * (1.0 - prob)
         return grad, hess
     raise ValueError(f"unsupported fused gradient spec: {spec!r}")
 

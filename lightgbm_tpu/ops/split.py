@@ -448,15 +448,20 @@ def best_split(hist: jnp.ndarray, num_bins: jnp.ndarray, na_bin: jnp.ndarray,
 
     # ---- EFB virtual-feature plane (efb.py candidate identity) ----
     if p.has_bundles and bundle is not None:
-        bs1 = (bundle.range_start - 1)[None, None, :, :]       # [1,1,F,B]
-        be1 = bundle.range_end[None, None, :, :]
-        pe1 = bundle.prefix_end[None, None, :, :]
-        cum_start = jnp.take_along_axis(
-            cum, jnp.broadcast_to(jnp.maximum(bs1, 0), cum.shape), axis=-1)
-        cum_end = jnp.take_along_axis(
-            cum, jnp.broadcast_to(be1, cum.shape), axis=-1)
-        cum_pe = jnp.take_along_axis(
-            cum, jnp.broadcast_to(jnp.maximum(pe1, 0), cum.shape), axis=-1)
+        pe1 = bundle.prefix_end[None, None, :, :]              # [1,1,F,B]
+
+        def at(pos):
+            """cum[l, c, f, pos[f, j]]: the position tables are per column,
+            not per leaf, so the lookup is a one-hot contraction over the
+            bin axis (exact: one term of each sum is not zero), where a
+            gather over [L, 3, F, B] walks the frontier element by element
+            (64 to 129 ms a level pass on the v5e: PERF.md section 6, PR 34)."""
+            sel = (pos[:, :, None] == jnp.arange(b, dtype=pos.dtype))
+            return jnp.einsum("lcfb,fjb->lcfj", cum, sel.astype(cum.dtype),
+                              precision=jax.lax.Precision.HIGHEST)
+        cum_start = at(jnp.maximum(bundle.range_start - 1, 0))
+        cum_end = at(bundle.range_end)
+        cum_pe = at(jnp.maximum(bundle.prefix_end, 0))
         # prefix_end == range_start-1 encodes the empty prefix (t == default
         # with default bin 0): gather clamps to a valid index, mask to zero
         prefix = jnp.where((pe1 >= bundle.range_start[None, None, :, :]),
