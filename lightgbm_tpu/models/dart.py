@@ -79,7 +79,8 @@ class DART(GBDT):
             leaf = P.route_bins(
                 tree_dev.split_feature, tree_dev.threshold_bin,
                 tree_dev.default_left, tree_dev.left_child, tree_dev.right_child,
-                tree_dev.num_leaves, bins, na_bin, max_steps)
+                tree_dev.num_leaves, bins, na_bin, max_steps,
+                **self._subset_nodes(tree_dev))
             delta = take_small(tree_dev.leaf_value, leaf) * sign
             if delta.shape[0] != score.shape[0]:
                 delta = delta[: score.shape[0]]   # row-shard padding rows

@@ -1544,33 +1544,45 @@ class GBDT:
             # device from its own replica (zero-copy) instead of on every
             # chip — where the Mosaic lookup below could not be partitioned
             tree_dev = jax.tree.map(lambda a: a.addressable_data(0), tree_dev)
-        # with telemetry on, each walk's step count (a device scalar)
-        steps = [] if obs.enabled() else None
+        # with telemetry on, each walk's step count (a device scalar) and path
+        steps, paths = ([], []) if obs.enabled() else (None, None)
+        # a trainer on the Pallas kernels hands the walk each validation
+        # set's feature-major matrix (Dataset.bins_T, built on first use and
+        # kept) beside its rows: route_bins then walks in one Mosaic kernel
+        # where the shapes allow (P.walk_path), and in XLA where they do not
+        from ..ops.histogram import pick_impl
+        pallas = pick_impl(self.gp.hist_impl) == "pallas"
+        subset = self._subset_nodes(tree_dev)
         # host span and device scope share the name; the walk and the lookup
         # run as scoped programs, so nothing Booster.predict runs carries it
         with obs.span("valid_score"):
             for i, vs in enumerate(self.valid_sets):
+                bins_T = vs.bins_T if pallas else None
                 leaf = P.route_bins(
                     tree_dev.split_feature, tree_dev.threshold_bin,
                     tree_dev.default_left, tree_dev.left_child,
                     tree_dev.right_child, tree_dev.num_leaves, vs.bins,
                     vs.na_bin_dev, max_steps, scope="valid_score",
-                    steps_out=steps)
+                    steps_out=steps, bins_T=bins_T, **subset)
+                if steps is not None:
+                    paths.append(P.walk_path(bins_T, tree_dev.split_feature,
+                                             subset.get("is_cat")))
                 vdelta = take_small(tree_dev.leaf_value, leaf,
                                     scope="valid_score") - bias
                 self.valid_scores[i] = self._apply_valid_delta(
                     self.valid_scores[i], vdelta, cls)
             if steps:
-                # (iteration, valid set, steps) of the walks whose event is
-                # still to come; made on first use, as the lagged queues of
-                # _grow_and_update are. This iteration's walks are queued
-                # behind the step: reading their counts now would hold the
-                # host until they have run, so only the earlier ones go out
+                # (iteration, valid set, steps, path) of the walks whose
+                # event is still to come; made on first use, as the lagged
+                # queues of _grow_and_update are. This iteration's walks are
+                # queued behind the step: reading their counts now would hold
+                # the host until they have run, so only the earlier ones go
+                # out
                 it_no = self.iter_ + 1
                 q = self.__dict__.setdefault("_valid_walks", [])
-                for i, s in enumerate(steps):
+                for i, (s, path) in enumerate(zip(steps, paths)):
                     s.copy_to_host_async()
-                    q.append((it_no, i, s))
+                    q.append((it_no, i, s, path))
                 self._emit_valid_walks(before=it_no)
 
     def _emit_valid_walks(self, before: Optional[int] = None) -> None:
@@ -1580,9 +1592,21 @@ class GBDT:
         when the walk that made it has long run."""
         q = getattr(self, "_valid_walks", None)
         while q and (before is None or q[0][0] < before):
-            it_no, vset, steps = q.pop(0)
+            it_no, vset, steps, path = q.pop(0)
             obs.emit("valid_walk", steps=int(steps), iteration=it_no,
-                     valid_set=vset)
+                     valid_set=vset, path=path)
+
+    def _subset_nodes(self, tree_dev) -> dict:
+        """``route_bins``'s ``is_cat`` / ``cat_mask`` for a tree of this
+        trainer. Given where its grower can split on a subset of a column's
+        bins (a categorical feature, an EFB bundle's member: ``cat_mask``
+        holds the bins that go left), so that every walk of the tree decides
+        those nodes as the grower routed them; left out elsewhere, where the
+        numerical walk's program is what runs."""
+        sp = self.gp.split
+        if sp.cat_features or sp.has_bundles:
+            return {"is_cat": tree_dev.is_cat, "cat_mask": tree_dev.cat_mask}
+        return {}
 
     def _apply_valid_delta(self, score, vdelta, cls: int):
         if self.num_tree_per_iteration == 1:
@@ -1735,7 +1759,8 @@ class GBDT:
             leaf = P.route_bins(
                 tree_dev.split_feature, tree_dev.threshold_bin,
                 tree_dev.default_left, tree_dev.left_child, tree_dev.right_child,
-                tree_dev.num_leaves, ts.bins, ts.na_bin_dev, max_steps)
+                tree_dev.num_leaves, ts.bins, ts.na_bin_dev, max_steps,
+                **self._subset_nodes(tree_dev))
             delta = take_small(tree_dev.leaf_value, leaf)
             if delta.shape[0] != self.train_score.shape[0]:
                 delta = delta[: self.train_score.shape[0]]   # shard padding
@@ -1747,7 +1772,8 @@ class GBDT:
                 vleaf = P.route_bins(
                     tree_dev.split_feature, tree_dev.threshold_bin,
                     tree_dev.default_left, tree_dev.left_child, tree_dev.right_child,
-                    tree_dev.num_leaves, vs.bins, vs.na_bin_dev, max_steps)
+                    tree_dev.num_leaves, vs.bins, vs.na_bin_dev, max_steps,
+                    **self._subset_nodes(tree_dev))
                 vdelta = take_small(tree_dev.leaf_value, vleaf)
                 if k == 1:
                     self.valid_scores[i] = self.valid_scores[i] - vdelta
@@ -1810,7 +1836,8 @@ class GBDT:
             leaf = P.route_bins(
                 tree_dev.split_feature, tree_dev.threshold_bin,
                 tree_dev.default_left, tree_dev.left_child, tree_dev.right_child,
-                tree_dev.num_leaves, bins, self.train_set.na_bin_dev, max_steps)
+                tree_dev.num_leaves, bins, self.train_set.na_bin_dev, max_steps,
+                **self._subset_nodes(tree_dev))
             delta = take_small(tree_dev.leaf_value, leaf)
             if delta.shape[0] != out.shape[0]:
                 delta = delta[: out.shape[0]]   # row-shard padding rows

@@ -55,8 +55,11 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     # ``steps`` is the number of steps the walk took before every row was on
     # a leaf (ops/predict.route_bins), a device scalar read one iteration
     # late; ``iteration`` is 1-based as train_iter's, ``valid_set`` the
-    # set's index. One event per tree: k per iteration with k classes.
-    "valid_walk": ({"steps": int, "iteration": int, "valid_set": int}, {}),
+    # set's index; ``path`` the program that walked: "kernel"
+    # (ops/pallas_hist.walk_tree) or "xla" (ops/predict.walk_path chooses).
+    # One event per tree: k per iteration with k classes.
+    "valid_walk": ({"steps": int, "iteration": int, "valid_set": int,
+                    "path": str}, {}),
     # the fused step of the default depthwise grower was built: the path its
     # level passes take at this width (ops/histogram.hist_path). level_kernel
     # "hist_level_q8" routes and accumulates in one launch; "hist_leaf_q8" /

@@ -1157,3 +1157,129 @@ def take_small_pallas(table: jnp.ndarray, idx: jnp.ndarray,
     call = (_take_call(l, n_pad, chunk, interpret) if scope is None else
             _scoped_take(scope, l, n_pad, chunk, interpret))
     return call(table.astype(jnp.float32), idx_p)[:n]
+
+
+# ---------------------------------------------------------------------------
+# whole-tree walk: a chunk of rows through every level of a FINISHED tree in
+# one kernel, its bins read once. The decisions of ops/predict.route_bins
+# (NumericalDecision, tree.h:240) made as _route_chunk makes a level's: the
+# node one-hot against the tree's node tables on the MXU, an F-way select for
+# the bin column, where the XLA walk pays six per-row gathers a step
+# ---------------------------------------------------------------------------
+
+# the widths the walk was compiled and timed at (PERF.md, PR 35): the select
+# over 128 feature rows (route_level's width) and a node one-hot of
+# num_leaves = 1,024 both fit VMEM at the chunk below
+WALK_MAX_FEATURES = 128
+WALK_MAX_NODES = 1023
+
+
+def _walk_tabs(split_feature, threshold_bin, default_left, left_child,
+               right_child, na_bin) -> jnp.ndarray:
+    """A finished tree's node tables as _decode_leaf's operand: [16, M_pad]
+    bf16, a column a node, in _route_tabs's rows and byte split (feat, thr,
+    dleft, left child, right child, -, -, the split feature's missing bin).
+    A child pointer is a node (>= 0) or ``~leaf`` (down to -(M + 1)): stored
+    plus M + 1, so that it fits the decode's [-1, 65534] and the kernel takes
+    the offset off again."""
+    m = split_feature.shape[0]
+    unused = jnp.full((m,), -1, jnp.int32)
+    na = jnp.take(na_bin, jnp.maximum(split_feature, 0))
+    v = jnp.stack([split_feature, threshold_bin, default_left,
+                   left_child + (m + 1), right_child + (m + 1), unused,
+                   unused, na]).astype(jnp.int32) + 1              # [8, M]
+    v = _pad_rows(v, _TAB_LANES)
+    return jnp.concatenate([v & 0xFF, (v >> 8) & 0xFF]).astype(jnp.bfloat16)
+
+
+def _walk_tree_kernel(start_ref, bins_ref, tabs_ref, leaf_out, steps_out, *,
+                      f: int, n: int, off: int, chunk: int, max_steps: int):
+    """One row-chunk from the root to its leaves.
+
+    start_ref: [1] i32 in SMEM, the pointer a row starts on (0, or -1 for a
+    tree of one leaf); bins_ref [F, C] uint8; tabs_ref [16, M_pad] bf16
+    (_walk_tabs); leaf_out [C] i32; steps_out [1] i32 in SMEM, the most steps
+    a chunk has taken. The pointer is ops/predict._walk's: a node, or ~leaf
+    once the row is parked; rows past ``n`` start parked, so the padding
+    neither walks nor counts."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        steps_out[0] = 0
+
+    # Mosaic has no direct uint8 -> f32 cast: hop through int32, once a chunk
+    bins_f = bins_ref[:].astype(jnp.int32).astype(jnp.float32)    # [F, C]
+    iota_f = jax.lax.broadcasted_iota(jnp.int32, (f, chunk), 0) \
+        .astype(jnp.float32)
+    row = i * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+    ptr0 = jnp.where(row < n, start_ref[0], -1)                   # [1, C]
+
+    def rows_still_moving(carry):
+        ptr, step = carry
+        return (step < max_steps) & (jnp.max(ptr) >= 0)
+
+    def body(carry):
+        ptr, step = carry
+        tv, _ = _decode_leaf(ptr, tabs_ref, chunk)   # a parked row: all -1
+        feat, thr, dleft = tv[0:1], tv[1:2], tv[2:3]
+        left, right, nav = tv[3:4], tv[4:5], tv[7:8]
+        colv = jnp.sum(jnp.where(iota_f == feat, bins_f, 0.0), axis=0,
+                       keepdims=True)
+        # all-f32 mask arithmetic, as _route_chunk (Mosaic's i1 selects)
+        is_na = jnp.where(colv == nav, 1.0, 0.0)
+        gl_num = jnp.where(colv <= thr, 1.0, 0.0)
+        go_left = is_na * dleft + (1.0 - is_na) * gl_num
+        nxt = go_left * left + (1.0 - go_left) * right - float(off)
+        return jnp.where(ptr >= 0, nxt.astype(jnp.int32), ptr), step + 1
+
+    ptr, steps = jax.lax.while_loop(rows_still_moving, body,
+                                    (ptr0, jnp.int32(0)))
+    leaf_out[:] = (-1 - jnp.minimum(ptr, -1)).reshape(chunk)  # ~ptr, leaves
+    steps_out[0] = jnp.maximum(steps_out[0], steps)
+
+
+def walk_tree(split_feature, threshold_bin, default_left, left_child,
+              right_child, num_leaves, bins_T, na_bin, n: int, max_steps: int,
+              chunk: int = _CHUNK_Q8,
+              interpret: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """ops/predict.route_bins's walk of a numerical tree in one kernel:
+    (leaf [n] i32, steps i32 scalar), both equal to the XLA walk's to the
+    bit. ``bins_T``: [F_pad, N_pad] uint8, the first ``n`` columns the rows
+    (Dataset.bins_T; any N_pad >= n), at most WALK_MAX_FEATURES rows; the
+    tree arrays [M], M <= WALK_MAX_NODES. Every chunk stops when none of its
+    rows is on an internal node, and ``steps`` is the most any chunk took:
+    what the XLA walk counts. ``chunk``: 500 k x 28 rows through 255
+    leaves read 1.99 / 1.27 / 0.94 / 0.80 ms at 1024 / 2048 / 4096 / 8192
+    rows a chunk on the v5e (197.5 ms in XLA); 4096 is the widest that was
+    also compiled at both caps."""
+    f = bins_T.shape[0]
+    m = split_feature.shape[0]
+    assert f <= WALK_MAX_FEATURES and m <= WALK_MAX_NODES
+    tabs = _walk_tabs(split_feature, threshold_bin, default_left, left_child,
+                      right_child, na_bin)
+    bins_Tp = _pad_rows(bins_T, chunk)
+    n_p = bins_Tp.shape[1]
+    start = jnp.where(num_leaves > 1, 0, -1).astype(jnp.int32).reshape(1)
+    kern = functools.partial(_walk_tree_kernel, f=f, n=n, off=m + 1,
+                             chunk=chunk, max_steps=max_steps)
+    leaf, steps = pl.pallas_call(
+        kern,
+        name="walk_tree",
+        grid=(n_p // chunk,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((f, chunk), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec(tabs.shape, lambda i: (0, 0), memory_space=pltpu.VMEM),
+        ],
+        out_specs=(
+            pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((n_p,), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
+        ),
+        interpret=interpret,
+    )(start, bins_Tp, tabs)
+    return leaf[:n], steps[0]

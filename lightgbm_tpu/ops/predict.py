@@ -6,7 +6,10 @@ of vectorized gathers over the flat tree arrays — every row advances one level
 per iteration; finished rows park on their leaf (pointer < 0 is a leaf, encoded
 ~leaf_index, matching the reference's child encoding). The binned walk ends
 when no row is left on an internal node, so it takes as many steps as the
-deepest leaf a row reached; ``max_steps`` is only its ceiling.
+deepest leaf a row reached; ``max_steps`` is only its ceiling. A caller that
+also holds the rows feature-major (validation scoring of a trainer on the
+Pallas kernels) gets the same walk as one Mosaic kernel, where the shapes
+allow (``walk_path``).
 """
 from __future__ import annotations
 
@@ -21,7 +24,7 @@ from ..utils.timer import scoped_jit
 def route_bins(split_feature, threshold_bin, default_left, left_child, right_child,
                num_leaves, bins, na_bin, max_steps: int,
                is_cat=None, cat_mask=None, scope: str = None,
-               steps_out: list = None):
+               steps_out: list = None, bins_T=None):
     """Leaf index for each row of a *binned* matrix. bins: [N, F] uint8/int32.
 
     The walk stops as soon as every row is on a leaf (the predicate is
@@ -45,14 +48,46 @@ def route_bins(split_feature, threshold_bin, default_left, left_child, right_chi
     ``scope`` names the device scope of a call that is dispatched on its own
     (validation scoring during training passes ``valid_score``): the walk is
     then the program ``jit_route_bins_<scope>``, every op under the scope
-    (``utils.timer.scoped_jit``); what ``Booster.predict`` runs stays bare."""
-    walk = _WALK if scope is None else _scoped_walk(scope)
-    leaf, steps = walk(split_feature, threshold_bin, default_left, left_child,
-                       right_child, num_leaves, bins, na_bin,
-                       max_steps=max_steps, is_cat=is_cat, cat_mask=cat_mask)
+    (``utils.timer.scoped_jit``); what ``Booster.predict`` runs stays bare.
+
+    ``bins_T``: the same rows feature-major, [F_pad, N_pad] uint8
+    (``Dataset.bins_T``), from a caller that holds them and trains on the
+    Pallas kernels. Where ``walk_path`` then says ``"kernel"``, the whole
+    walk is one Mosaic kernel over ``bins_T`` (ops/pallas_hist.walk_tree;
+    interpreted on the CPU, where only a forced ``histogram_impl=pallas``
+    brings a caller here): the same leaves and the same step count, to the
+    bit, under the same program name. Everywhere else the XLA walk below."""
+    if walk_path(bins_T, split_feature, is_cat) == "kernel":
+        leaf, steps = _kernel_walk(scope)(
+            split_feature, threshold_bin, default_left, left_child,
+            right_child, num_leaves, bins_T, na_bin, n=bins.shape[0],
+            max_steps=max_steps, interpret=jax.default_backend() == "cpu")
+    else:
+        walk = _WALK if scope is None else _scoped_walk(scope)
+        leaf, steps = walk(split_feature, threshold_bin, default_left,
+                           left_child, right_child, num_leaves, bins, na_bin,
+                           max_steps=max_steps, is_cat=is_cat,
+                           cat_mask=cat_mask)
     if steps_out is not None:
         steps_out.append(steps)
     return leaf
+
+
+def walk_path(bins_T, split_feature, is_cat=None) -> str:
+    """Which program ``route_bins`` runs, read off its arguments' shapes:
+    ``"kernel"`` (ops/pallas_hist.walk_tree) when the caller handed the rows
+    feature-major, they are few enough for the kernel's select
+    (WALK_MAX_FEATURES, 128: Epsilon's 2,000 columns are not), the tree's
+    node tables fit VMEM beside a chunk (WALK_MAX_NODES, 1,023: num_leaves
+    up to 1,024) and no node is categorical (the kernel decodes no bin
+    membership: a tree with ``is_cat`` walks in XLA, never numerically);
+    ``"xla"`` otherwise."""
+    from .pallas_hist import WALK_MAX_FEATURES, WALK_MAX_NODES
+    if (bins_T is not None and is_cat is None
+            and bins_T.shape[0] <= WALK_MAX_FEATURES
+            and split_feature.shape[0] <= WALK_MAX_NODES):
+        return "kernel"
+    return "xla"
 
 
 def _walk(split_feature, threshold_bin, default_left, left_child, right_child,
@@ -100,6 +135,28 @@ _WALK = jax.jit(_walk, static_argnames=("max_steps",))
 @lru_cache(maxsize=None)
 def _scoped_walk(scope: str):
     return scoped_jit(_walk, scope, static_argnames=("max_steps",))
+
+
+def _walk_kernel(*tree_and_rows, n: int, max_steps: int, interpret: bool):
+    from .pallas_hist import walk_tree
+    return walk_tree(*tree_and_rows, n=n, max_steps=max_steps,
+                     interpret=interpret)
+
+
+# the kernel walk's programs carry the XLA walk's names: the trace's readers
+# sum ``jit_route_bins*`` modules whichever walk ran
+_walk_kernel.__name__ = _walk_kernel.__qualname__ = "route_bins"
+_KERNEL_STATIC = ("n", "max_steps", "interpret")
+_KERNEL_WALK = jax.jit(_walk_kernel, static_argnames=_KERNEL_STATIC)
+
+
+@lru_cache(maxsize=None)
+def _scoped_kernel_walk(scope: str):
+    return scoped_jit(_walk_kernel, scope, static_argnames=_KERNEL_STATIC)
+
+
+def _kernel_walk(scope: str = None):
+    return _KERNEL_WALK if scope is None else _scoped_kernel_walk(scope)
 
 
 def route_raw(split_feature, threshold_real, default_left, left_child, right_child,

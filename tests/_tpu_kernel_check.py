@@ -19,7 +19,9 @@ import jax
 import jax.numpy as jnp
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))   # _trees
 
+from _trees import level_order_children  # noqa: E402
 from lightgbm_tpu.ops import histogram as H  # noqa: E402
 from lightgbm_tpu.ops import pallas_hist as PH  # noqa: E402
 
@@ -290,24 +292,76 @@ def check_fused_level():
                                        f"const_hess={const_hess}")
 
 
+def _level_order_tree(rng, num_leaves, f, b):
+    """Flat arrays of a tree grown level by level, thresholds in the middle
+    half of the bins so that rows spread over the leaves. 255 leaves: depth
+    8, as HIGGS's trees."""
+    m = num_leaves - 1
+    lc, rc = level_order_children(num_leaves)
+    return tuple(jnp.asarray(a) for a in (
+        rng.randint(0, f, size=m).astype(np.int32),
+        rng.randint(b // 4, 3 * b // 4, size=m).astype(np.int32),
+        rng.rand(m) < 0.5, np.asarray(lc, np.int32), np.asarray(rc, np.int32),
+        np.int32(num_leaves)))
+
+
+def _ms_a_call(fn, reps=10):
+    """Host clock around ``reps`` calls queued back to back and one wait:
+    the device's time a call once the queue hides the dispatches."""
+    import time
+    jax.block_until_ready(fn())
+    t0 = time.perf_counter()
+    outs = [fn() for _ in range(reps)]
+    jax.block_until_ready(outs)
+    return (time.perf_counter() - t0) / reps * 1e3
+
+
+def check_walk_tree(n=500_000):
+    """The whole-tree walk kernel against the XLA walk at the validation
+    set's size (500 k x 28, 255 leaves, depth 8; missing bins on every third
+    feature): leaves and step count to the bit, and both walks' time."""
+    from lightgbm_tpu.ops import predict as P
+    rng = np.random.RandomState(8)
+    tree = _level_order_tree(rng, L, F, B)
+    bins = jnp.asarray(rng.randint(0, B, size=(n, F)).astype(np.uint8))
+    na_bin = jnp.asarray(np.where(np.arange(F) % 3 == 0, B - 1, 256)
+                         .astype(np.int32))
+    bins_T = PH.resident_bins_T(bins, PH.resident_shape(n, F, B))
+    assert P.walk_path(bins_T, tree[0]) == "kernel"
+    ref_steps, out_steps = [], []
+    ref = P.route_bins(*tree, bins, na_bin, L - 1, steps_out=ref_steps)
+    out = P.route_bins(*tree, bins, na_bin, L - 1, steps_out=out_steps,
+                       bins_T=bins_T)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+    assert int(ref_steps[0]) == int(out_steps[0]) == 8
+    assert len(np.unique(np.asarray(out))) > L // 2   # rows spread wide
+    xla = _ms_a_call(lambda: P.route_bins(*tree, bins, na_bin, L - 1))
+    kern = _ms_a_call(lambda: P.route_bins(*tree, bins, na_bin, L - 1,
+                                           bins_T=bins_T))
+    print(f"walk_tree n={n} f={F} leaves={L} steps=8: kernel {kern:.3f} ms "
+          f"a call, XLA walk {xla:.3f} ms a call", flush=True)
+
+
 CHECKS = (check_hist_pallas, check_hist_pallas_q8, check_route_level,
           check_take_small, check_leaf_sums, check_front,
-          check_leaf_sums_grad, check_fused_level)
+          check_leaf_sums_grad, check_fused_level, check_walk_tree)
 
 
-def run_all():
-    """Run every check on the current (TPU) backend; returns the names."""
-    for fn in CHECKS:
+def run_all(names=()):
+    """Run every check (or those named) on the current (TPU) backend;
+    returns the names."""
+    checks = [fn for fn in CHECKS if not names or fn.__name__ in names]
+    for fn in checks:
         fn()
         print(f"{fn.__name__} OK", flush=True)
-    return [fn.__name__ for fn in CHECKS]
+    return [fn.__name__ for fn in checks]
 
 
 def main():
     if jax.default_backend() != "tpu":
         print(f"NO_TPU backend={jax.default_backend()}")
         return 3
-    run_all()
+    run_all(sys.argv[1:])
     print("TPU_KERNELS_OK")
     return 0
 

@@ -476,6 +476,7 @@ def test_valid_walk_event_counts_the_steps_of_each_walk(monkeypatch):
     assert len(depths) == 3 and max(depths) <= 6
     for e, depth in zip(walks, depths):
         assert 1 <= e["steps"] <= depth
+        assert e["path"] == "xla"   # the CPU's default trainer: no kernel
     assert asked == [True] * 3 and bst._gbdt._valid_walks == []
     del asked[:]
     obs.reset()

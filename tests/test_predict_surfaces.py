@@ -234,3 +234,234 @@ def test_rollback_on_a_row_sharded_mesh_takes_back_the_tree():
     bst.rollback_one_iter()
     want = before - tree.leaf_value[leaf][: len(before)]
     np.testing.assert_array_equal(np.asarray(g.train_score), want)
+
+
+# ---- the walk as one Mosaic kernel over the feature-major matrix (PR 35) ----
+from _trees import level_order_children
+from lightgbm_tpu.ops import pallas_hist as PH
+
+
+def _level_order(num_leaves, rng):
+    return _tree(*level_order_children(num_leaves), rng)
+
+
+def _feature_major(bins, f_pad=None):
+    """``Dataset.bins_T`` of the rows: [F_pad, N_pad] uint8, zero padding."""
+    n, f = bins.shape
+    f_res, n_pad = PH.resident_shape(n, f, _B)
+    return PH.resident_bins_T(jnp.asarray(bins), (f_pad or f_res, n_pad))
+
+
+def _route_both(t, bins, na_bin, max_steps, f_pad=None):
+    """(leaves, steps) of the XLA walk and of the kernel walk."""
+    assert "is_cat" not in t
+    tree = [jnp.asarray(t[k]) for k in (
+        "split_feature", "threshold_bin", "default_left", "left_child",
+        "right_child", "num_leaves")]
+    out = []
+    for bins_T in (None, _feature_major(bins, f_pad)):
+        steps = []
+        assert P.walk_path(bins_T, tree[0]) == (
+            "xla" if bins_T is None else "kernel")
+        leaf = P.route_bins(*tree, jnp.asarray(bins), jnp.asarray(na_bin),
+                            max_steps, steps_out=steps, bins_T=bins_T)
+        out.append((np.asarray(leaf), int(steps[0])))
+    return out
+
+
+_KERNEL_CASES = {
+    # name: (tree, rows, F_pad of bins_T or None = F, max_steps or None)
+    "depth8_255_leaves": (lambda r: _level_order(255, r), 9000, None, None),
+    "depth8_complete": (lambda r: _balanced(8, r), 5000, None, None),
+    "chain": (lambda r: _chain(40, r), 300, None, None),
+    "chain_cut_short": (lambda r: _chain(12, r), 300, None, 3),
+    "one_leaf": (_one_leaf, 300, None, None),
+    "31_leaves": (lambda r: _level_order(31, r), 700, None, None),
+    "1023_leaves": (lambda r: _level_order(1023, r), 4500, None, None),
+    "rows_past_a_chunk_features_below_f_pad": (
+        lambda r: _level_order(63, r), PH._CHUNK_Q8 + 905, 8, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+@pytest.mark.parametrize("missing", ["default_left", "default_right", "none"])
+def test_kernel_walk_equals_the_xla_walk(case, missing):
+    """Leaf indices and step count of ``walk_tree`` (interpreted) equal the
+    XLA walk's exactly: over tree shapes and table lengths, rows and feature
+    rows that do not fill the kernel's blocks (the padding walks nowhere and
+    is cut), and rows on the missing bin sent either way or, with ``na_bin``
+    256, nowhere in particular."""
+    make, n, f_pad, max_steps = _KERNEL_CASES[case]
+    rng = np.random.RandomState(11)
+    t = make(rng)
+    bins, na_bin = _bins(rng, n)
+    if missing == "none":
+        na_bin = np.full(_F, 256, np.int32)
+    else:
+        t["default_left"][:] = missing == "default_left"
+    if case.startswith("chain"):
+        t["default_left"][:] = False      # _chain's: bins[0] runs to the end
+        bins[0] = _B - 2
+    if max_steps is None:
+        max_steps = max(int(t["num_leaves"]) - 1, 1)
+    (want, want_steps), (leaf, steps) = _route_both(t, bins, na_bin,
+                                                    max_steps, f_pad)
+    np.testing.assert_array_equal(leaf, want)
+    assert steps == want_steps
+    static, moved = _static_walk(t, bins, na_bin, max_steps)
+    np.testing.assert_array_equal(leaf, static)
+    assert steps == moved
+    if case == "one_leaf":
+        assert steps == 0 and not leaf.any()
+    if case == "chain" and missing != "none":
+        assert steps == 39 and leaf[0] == 39
+
+
+def test_categorical_tree_takes_the_xla_walk(monkeypatch):
+    """The kernel decodes no bin membership: a tree handed over with
+    ``is_cat`` walks in XLA, ``bins_T`` or not, and decides its categorical
+    nodes by membership."""
+    def no_kernel(*a, **kw):
+        raise AssertionError("the kernel walk was asked for a categorical tree")
+    monkeypatch.setattr(P, "_kernel_walk", no_kernel)
+    rng = np.random.RandomState(5)
+    t = _balanced(3, rng, cat_nodes=(0, 2, 5))
+    bins, na_bin = _bins(rng)
+    tree = [jnp.asarray(t[k]) for k in (
+        "split_feature", "threshold_bin", "default_left", "left_child",
+        "right_child", "num_leaves")]
+    bins_T = _feature_major(bins)
+    assert P.walk_path(bins_T, tree[0], jnp.asarray(t["is_cat"])) == "xla"
+    steps = []
+    leaf = P.route_bins(*tree, jnp.asarray(bins), jnp.asarray(na_bin), 7,
+                        is_cat=jnp.asarray(t["is_cat"]),
+                        cat_mask=jnp.asarray(t["cat_mask"]), steps_out=steps,
+                        bins_T=bins_T)
+    want, moved = _static_walk(t, bins, na_bin, 7)
+    np.testing.assert_array_equal(np.asarray(leaf), want)
+    assert int(steps[0]) == moved == 3
+
+
+@pytest.mark.parametrize("f_pad,nodes,cat,want", [
+    (28, 254, False, "kernel"),            # HIGGS, 255 leaves
+    (14, 255, False, "kernel"),            # Covertype's bundled columns
+    (128, 1023, False, "kernel"),          # both caps
+    (None, 254, False, "xla"),             # no feature-major matrix handed in
+    (2016, 254, False, "xla"),             # Epsilon: 2,000 columns
+    (129, 254, False, "xla"),
+    (28, 1024, False, "xla"),              # num_leaves 1,025: past the table
+    (28, 254, True, "xla"),                # categorical nodes
+])
+def test_walk_path_reads_the_shapes(f_pad, nodes, cat, want):
+    bins_T = None if f_pad is None else jax.ShapeDtypeStruct(
+        (f_pad, 8192), jnp.uint8)
+    sf = jax.ShapeDtypeStruct((nodes,), jnp.int32)
+    is_cat = jax.ShapeDtypeStruct((nodes,), jnp.bool_) if cat else None
+    assert P.walk_path(bins_T, sf, is_cat) == want
+
+
+@pytest.fixture
+def clean_obs():
+    """Telemetry as the suite has it (off, empty) before and after."""
+    from lightgbm_tpu import obs
+    obs.reset()
+    obs.configure(enabled=False, metrics_out="")
+    yield
+    obs.reset()
+    obs.configure(enabled=False, metrics_out="")
+
+
+def _train_valid_walks(histogram_impl):
+    """Three iterations with one validation set, telemetry on: (booster,
+    validation Dataset, its metric by iteration, the ``valid_walk`` events)."""
+    from lightgbm_tpu import obs
+    rng = np.random.RandomState(4)
+    X = rng.randn(900, 6)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(np.float64)
+    X[rng.rand(*X.shape) < 0.05] = np.nan
+    Xv = rng.randn(333, 6)
+    yv = (Xv[:, 0] + Xv[:, 1] * Xv[:, 2] > 0).astype(np.float64)
+    Xv[rng.rand(*Xv.shape) < 0.05] = np.nan
+    params = {"objective": "binary", "verbosity": -1, "num_leaves": 15,
+              "min_data_in_leaf": 5, "metric": "binary_logloss",
+              "telemetry": True, "histogram_impl": histogram_impl,
+              "use_quantized_grad": False}
+    obs.reset()
+    ds = lgb.Dataset(X, label=y, params=params)
+    vs = ds.create_valid(Xv, label=yv)
+    res = {}
+    bst = lgb.train(params, ds, 3, valid_sets=[vs], evals_result=res,
+                    verbose_eval=False)
+    walks = [e for e in obs.EVENTS.snapshot() if e["type"] == "valid_walk"]
+    return bst, vs, res["valid_0"]["binary_logloss"], walks
+
+
+def test_trainer_on_the_cpu_takes_the_xla_walk(clean_obs):
+    """The default histogram implementation on the CPU is not the Pallas
+    one: the trainer hands the walk no feature-major matrix (and builds
+    none), so validation is scored by the program it always was."""
+    _, vs, _, walks = _train_valid_walks("auto")
+    assert [e["path"] for e in walks] == ["xla"] * 3
+    assert vs._bins_T is None
+
+
+def test_trainer_on_pallas_scores_validation_through_the_kernel(clean_obs,
+                                                                monkeypatch):
+    """``histogram_impl=pallas`` (interpreted here): every walk is the
+    kernel's, and the validation metric of every iteration equals, to the
+    bit, that of the same training with the choice held to the XLA walk."""
+    bst, vs, kernel_loss, walks = _train_valid_walks("pallas")
+    assert [e["path"] for e in walks] == ["kernel"] * 3
+    assert vs._bins_T is not None and vs._bins_T.shape[0] == 6
+    for e, t in zip(walks, bst.trees):
+        assert 1 <= e["steps"] <= t.max_depth
+    monkeypatch.setattr(P, "walk_path", lambda *a, **kw: "xla")
+    _, _, xla_loss, walks = _train_valid_walks("pallas")
+    assert [e["path"] for e in walks] == ["xla"] * 3
+    assert kernel_loss == xla_loss
+
+
+# ---- in-training walks of trees with bin-subset nodes (PR 35) ---------------
+
+def _subset_problem(kind, rng, n):
+    """Rows whose label needs a bin-subset split: a categorical column, or
+    eight one-hot columns that EFB bundles into one."""
+    c = rng.randint(0, 8, n)
+    num = rng.randn(n, 3)
+    y = (np.isin(c, [1, 5, 7]) ^ (num[:, 1] > 0)).astype(np.float64)
+    if kind == "categorical":
+        return np.column_stack([c, num]), y
+    return np.column_stack([num, np.eye(8)[c]]), y
+
+
+@pytest.mark.parametrize("boosting", ["gbdt", "dart"])
+@pytest.mark.parametrize("kind", ["categorical", "bundled"])
+def test_training_scores_validation_by_bin_membership(kind, boosting):
+    """The metric the trainer reports on a validation set is that of the
+    model's own predictions there, where trees split on a subset of a
+    column's bins (the walk is handed ``is_cat`` / ``cat_mask``; it decided
+    those nodes by threshold before), and a rollback takes back exactly
+    what the iteration added."""
+    rng = np.random.RandomState(0)
+    X, y = _subset_problem(kind, rng, 3000)
+    Xv, yv = _subset_problem(kind, rng, 800)
+    params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+              "metric": "binary_logloss", "min_data_in_leaf": 5,
+              "boosting": boosting, "drop_rate": 0.5, "drop_seed": 3}
+    ds = lgb.Dataset(X, label=y, params=params,
+                     categorical_feature=[0] if kind == "categorical" else "auto")
+    res = {}
+    bst = lgb.train(params, ds, 6, valid_sets=[ds.create_valid(Xv, label=yv)],
+                    evals_result=res, verbose_eval=False)
+    g = bst._gbdt
+    assert (ds.bundle_meta is not None) == (kind == "bundled")
+    assert any(bool(np.asarray(t.is_cat).any()) for t in g.models_dev)
+    p = bst.predict(Xv)
+    want = -np.mean(yv * np.log(p) + (1 - yv) * np.log(1 - p))
+    assert res["valid_0"]["binary_logloss"][-1] == pytest.approx(want, rel=1e-5)
+    assert want < 0.45
+    if boosting == "gbdt":
+        raw = bst.predict(Xv, raw_score=True, num_iteration=5)
+        bst.rollback_one_iter()
+        np.testing.assert_allclose(np.asarray(g.valid_scores[0]), raw,
+                                   rtol=1e-5, atol=1e-6)
