@@ -296,14 +296,12 @@ def stream_encode_upload(raw, mappers, meta, *, width: int,
                     ).set_max(depth + 1)
                     obs.METRICS.counter("ingest_chunks",
                                         "chunks through the pipeline").inc()
+                    owner = {} if shard is None else {"shard": int(shard)}
                     obs.emit("ingest_chunk", chunk=int(ci), rows=int(rows),
                              encode_s=float(enc_dt), h2d_s=float(h2d_dt),
-                             commit_s=float(dt), depth=int(depth))
-                    if shard is not None:
-                        obs.emit("mesh_shard_commit", shard=int(shard),
-                                 rows=int(rows), bytes=int(rows * width),
-                                 chunk=int(ci), h2d_s=float(h2d_dt),
-                                 commit_s=float(dt))
+                             commit_s=float(dt), depth=int(depth),
+                             bytes=int(rows * width),
+                             thread=threading.current_thread().name, **owner)
             except BaseException as e:
                 _fail(e)
 

@@ -382,8 +382,6 @@ class GBDT:
                 # otherwise, with no diff to debug from
                 from ..parallel.fence import mesh_preflight
                 mesh_preflight(config, train_set, plan)
-            if not quiet:
-                self._emit_hist_allreduce_probe()
         if not quiet:
             # the row grid this trainer ADOPTED: Dataset.construct may have
             # re-planned or dropped the grid it first published after a
@@ -1141,45 +1139,6 @@ class GBDT:
             return None
         # keyed by device id string — the label obs.memory.sample() uses
         return {str(d.id): i for i, d in enumerate(self._mesh.devices.flat)}
-
-    def _emit_hist_allreduce_probe(self) -> None:
-        """One timed histogram-shaped psum over the data mesh at setup.
-
-        The in-step psum runs inside the fused jit where per-op wall time is
-        invisible from the host, so the `hist_allreduce` event records a
-        host-timed probe of the SAME collective on the same mesh with the
-        real histogram shape [3, F, max_bin] f32 — the cost model input for
-        PERF_NOTES' psum-vs-allgather table."""
-        if not obs.enabled():
-            return
-        try:
-            import time as _time
-
-            from jax.sharding import PartitionSpec as PS
-
-            from ..parallel.mesh import replicate
-            mesh = self._mesh
-            axis = mesh.axis_names[0]
-            f = int(getattr(self.train_set, "_num_features_used", None)
-                    or self.train_set.num_features or 1)
-            shape = (3, f, int(self.gp.max_bin))
-            x = replicate(jnp.ones(shape, jnp.float32), mesh)
-            # one-shot probe per trainer: the wrapper is built, timed, and
-            # dropped here by design  # tpu-lint: disable=retrace-hazard
-            fn = jax.jit(jax.shard_map(
-                lambda a: jax.lax.psum(a, axis), mesh=mesh,
-                in_specs=(PS(),), out_specs=PS(), check_vma=False))
-            fn(x).block_until_ready()   # compile outside the timing
-            t0 = _time.perf_counter()
-            fn(x).block_until_ready()   # tpu-lint: disable=host-sync-in-jit
-            dt = _time.perf_counter() - t0
-            obs.emit("hist_allreduce",
-                     shards=int(mesh.devices.size),
-                     bytes=int(np.prod(shape)) * 4, psum_s=float(dt))
-        # measurement-only best-effort path: the training psum has its own
-        # recovery in _fused_step, a failed probe must never block training
-        except Exception as e:   # tpu-lint: disable=swallowed-device-error
-            log.debug("hist_allreduce probe failed: %s", e)
 
     def _fused_step(self, grad, hess):
         custom = grad is not None

@@ -21,8 +21,11 @@ counters the serving tests use).
 """
 from __future__ import annotations
 
+import collections
 import os
+import re
 import threading
+import time
 from typing import Any, Dict, List, Optional
 
 from ..utils import log
@@ -48,7 +51,7 @@ class _State:
         # configure call) start enabled; configure_from_config re-reads the
         # env anyway, so this is just the pre-configure default
         self.enabled = bool(_env_enabled())
-        self.listening = False      # the jax.monitoring listener is in place
+        self.listening = False      # jax's listeners and the gc hook are in
         self.metrics_out = ""
         self.lock = threading.Lock()
 
@@ -68,25 +71,99 @@ def configure(enabled: Optional[bool] = None,
         if metrics_out is not None:
             _STATE.metrics_out = str(metrics_out)
         if _STATE.enabled and not _STATE.listening:
-            # once per process (jax keeps listeners for good); silent while
-            # telemetry is off
+            # once per process (jax keeps listeners for good, gc its
+            # callbacks); all of them silent while telemetry is off
+            import gc
+
             import jax
             jax.monitoring.register_event_duration_secs_listener(
                 _on_jax_duration)
+            jax.monitoring.register_event_listener(_on_jax_event)
+            gc.callbacks.append(_on_gc)
             _STATE.listening = True
 
 
+# ---- what jax says while it builds or loads a program -----------------------
+# All of it arrives on the thread that asked for the program, in this order:
+# jaxpr_trace_duration (fun_name "step"; a jit traced inside it first),
+# jaxpr_to_mlir_module_duration ("jit(step)"), then inside the
+# backend_compile_duration interval cache_hits with its two durations, or
+# cache_misses where the executable was built and written, or neither where
+# the program has no cache key (cache off, or built under the cache's
+# thresholds), and last backend_compile_duration itself ("jit(step)"), which
+# is the program_load. A program that jit serves from memory says nothing.
+
 _PROGRAM_LOAD_EVENT = "/jax/core/compile/backend_compile_duration"
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s"}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_CACHE_DURATIONS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
+_TRANSFORMED = re.compile(r"\w+\((.*)\)")
 
 
-def _on_jax_duration(event: str, duration: float, **_kw: Any) -> None:
-    """One executable built, or read from the persistent cache: jax reports
-    both under this name, on the thread that asked for the program, so the
-    innermost span open there is the host code that paid for it."""
-    if event != _PROGRAM_LOAD_EVENT or not _STATE.enabled:
+def program_name(fun_name: str) -> str:
+    """jax's ``fun_name`` as the device trace names the module, less its
+    ``jit_`` prefix: ``jit(step)`` and ``step`` -> ``step``, ``jit(<lambda>)``
+    -> ``_lambda_`` (mlir.sanitize_name's characters)."""
+    m = _TRANSFORMED.fullmatch(fun_name)
+    return re.sub(r"[^\w.-]", "_", m.group(1) if m else fun_name)
+
+
+class _Compiling(threading.local):
+    """What jax has said on this thread since its last program_load."""
+
+    def __init__(self) -> None:
+        self.clear()
+
+    def clear(self) -> None:
+        self.cache = "off"
+        self.cache_s: Dict[str, float] = {}     # retrieval_s, saved_s
+        # trace_s / lower_s -> program -> seconds
+        self.stage_s: Dict[str, Dict[str, float]] = {
+            field: {} for field in _STAGE_EVENTS.values()}
+
+
+_COMPILING = _Compiling()
+
+
+def _on_jax_event(event: str, **_kw: Any) -> None:
+    word = _CACHE_EVENTS.get(event)
+    if word is not None and _STATE.enabled:
+        _COMPILING.cache = word
+
+
+def _on_jax_duration(event: str, duration: float, fun_name: str = "",
+                     **_kw: Any) -> None:
+    if not _STATE.enabled:
         return
+    said = _COMPILING
+    if event == _PROGRAM_LOAD_EVENT:
+        _program_load(program_name(fun_name), float(duration), said)
+        said.clear()
+    elif event in _STAGE_EVENTS:
+        by_name = said.stage_s[_STAGE_EVENTS[event]]
+        name = program_name(fun_name)
+        by_name[name] = by_name.get(name, 0.0) + float(duration)
+    elif event in _CACHE_DURATIONS:
+        said.cache_s[_CACHE_DURATIONS[event]] = float(duration)
+
+
+def _program_load(program: str, duration: float, said: _Compiling) -> None:
+    """One executable built, or read from the persistent cache: jax reports
+    both under one name, on the thread that asked for the program, so the
+    innermost span open there is the host code that paid for it."""
     where = tracing.current_span() or "none"
-    fields: Dict[str, Any] = {"span": where, "duration_s": float(duration)}
+    fields: Dict[str, Any] = {
+        "span": where, "duration_s": duration, "program": program,
+        "cache": said.cache, "thread": threading.current_thread().name}
+    fields.update(said.cache_s)
+    for field, by_name in said.stage_s.items():
+        if program in by_name:
+            fields[field] = by_name[program]
     it = tracing.current_iteration()
     if it is not None:
         it.programs_loaded += 1
@@ -94,7 +171,38 @@ def _on_jax_duration(event: str, duration: float, **_kw: Any) -> None:
     emit("program_load", **fields)
     METRICS.counter("programs_loaded",
                     "executables built or read from the compile cache",
-                    span=where).inc()
+                    span=where, cache=said.cache).inc()
+
+
+# ---- the collector's long passes --------------------------------------------
+# gc runs its callbacks wherever the interpreter stood, perhaps inside
+# EventLog.emit with its lock held: the hook takes no lock. It leaves the
+# record in _GC_PAUSES (deque.append is atomic) and the next emit() writes it.
+
+_GC_OPEN = threading.local()        # .gen2: (start ts, clock, annotation)
+_GC_PAUSES: "collections.deque" = collections.deque()
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """A generation-2 collection as a ``gc_gen2`` range on the profiler's
+    clock and a ``gc_pause`` event; younger generations are not looked at."""
+    if info.get("generation") != 2 or not _STATE.enabled:
+        return
+    if phase == "start":
+        import jax
+        note = jax.profiler.TraceAnnotation("gc_gen2")
+        note.__enter__()
+        _GC_OPEN.gen2 = (time.time(), time.perf_counter(), note)
+        return
+    opened = getattr(_GC_OPEN, "gen2", None)
+    if opened is None:              # telemetry came on inside the pass
+        return
+    _GC_OPEN.gen2 = None
+    start_ts, t0, note = opened
+    note.__exit__(None, None, None)
+    _GC_PAUSES.append({"generation": 2, "start_ts": start_ts,
+                       "duration_s": time.perf_counter() - t0,
+                       "collected": int(info.get("collected", 0))})
 
 
 def configure_from_config(conf) -> None:
@@ -121,6 +229,11 @@ def emit(etype: str, **fields: Any) -> None:
     for the static check over call sites)."""
     if not _STATE.enabled:
         return
+    while _GC_PAUSES:
+        try:
+            EVENTS.emit("gc_pause", **_GC_PAUSES.popleft())
+        except IndexError:          # another thread wrote it
+            break
     EVENTS.emit(etype, **fields)
     if flight.FLIGHT.active:
         flight.FLIGHT.note_event(etype, fields)
@@ -132,6 +245,7 @@ def reset() -> None:
     concurrent configure can't observe a half-reset plane."""
     with _STATE.lock:
         EVENTS.clear()
+        _GC_PAUSES.clear()
         METRICS.clear()
         slo.TRACKER.reset()
         slo.FRESHNESS.reset()

@@ -44,13 +44,42 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
                     "lagged_iteration": int, "spans": dict,
                     "programs_loaded": int}),
     # a span closed outside any train_iter (dataset_construct, train_setup,
-    # finalize): the span record of a run that no profiler watches
-    "span": ({"name": str, "duration_s": _NUM}, {}),
+    # finalize, the prewarm thread's): the span record of a run that no
+    # profiler watches. ``ts`` is its end and ``start_ts`` its start on the
+    # same clock; ``thread`` the thread it ran on; ``parent`` the span that
+    # enclosed it there or, for a thread's outermost span, the one open on
+    # the thread that started it (dataset_construct for prewarm_worker)
+    "span": ({"name": str, "duration_s": _NUM},
+             {"start_ts": _NUM, "thread": str, "parent": str}),
     # jax built an executable or read one from the persistent cache
     # (backend_compile_duration); ``span`` is the innermost span open on the
     # calling thread ("none" outside all), ``iteration`` the train_iter it
-    # fell into
-    "program_load": ({"span": str, "duration_s": _NUM}, {"iteration": int}),
+    # fell into, ``thread`` that thread's name; ``program`` jax's fun_name as
+    # the device trace names the module, less its jit_ prefix; ``cache``
+    # "hit" (read: ``retrieval_s`` the read, ``saved_s`` the build it stood
+    # for less the read), "miss" (built and written) or "off" (no cache key:
+    # the cache disabled, or the build under its thresholds); ``trace_s`` /
+    # ``lower_s`` the tracing and the lowering of the same program on the
+    # same thread since its last program_load, which no cache saves
+    "program_load": ({"span": str, "duration_s": _NUM},
+                     {"iteration": int, "program": str, "cache": str,
+                      "retrieval_s": _NUM, "saved_s": _NUM,
+                      "trace_s": _NUM, "lower_s": _NUM, "thread": str}),
+    # what one compiled step holds on ONE device beside the process's
+    # residents (prewarm.aot_compile_step, from Compiled.memory_analysis();
+    # a sharded executable's analysis is one device's, ``devices`` how many
+    # it spans): arguments, outputs, the temporaries no allocator statistic
+    # shows before the step runs, the bytes outputs share with donated
+    # arguments, the code. No event where the backend gives no analysis
+    "step_memory": ({"what": str, "argument_bytes": int, "output_bytes": int,
+                     "temp_bytes": int, "alias_bytes": int,
+                     "generated_code_bytes": int, "devices": int}, {}),
+    # a generation-2 pass of Python's collector (obs._on_gc; younger
+    # generations are not recorded): ``start_ts`` its start on ts's clock,
+    # ``collected`` the objects it freed. Written by the next emit(), so
+    # ``ts`` is later than start_ts + duration_s
+    "gc_pause": ({"generation": int, "duration_s": _NUM, "collected": int},
+                 {"start_ts": _NUM}),
     # validation scoring walked one finished tree over one validation set:
     # ``steps`` is the number of steps the walk took before every row was on
     # a leaf (ops/predict.route_bins), a device scalar read one iteration
@@ -169,18 +198,14 @@ EVENT_SCHEMAS: Dict[str, Tuple[Dict[str, Any], Dict[str, Any]]] = {
     "fleet_publish": ({"model": str, "version": int, "replicas": int},
                       {"duration_s": _NUM}),
     # one chunk made it through the three-stage ingest pipeline
-    # (ingest.py): per-stage durations + queue depth observed at commit
+    # (ingest.py): per-stage durations + queue depth observed at commit;
+    # ``bytes`` the encoded chunk's, ``thread`` the committing thread, and on
+    # a sharded ingest ``shard`` the row shard whose donated accumulator on
+    # its own chip took the chunk
     "ingest_chunk": ({"chunk": int, "rows": int},
                      {"encode_s": _NUM, "h2d_s": _NUM, "commit_s": _NUM,
-                      "depth": int}),
-    # a chunk was committed into its owning row shard's donated accumulator
-    # (mesh-native sharded ingest, ingest.py): shard id + payload size
-    "mesh_shard_commit": ({"shard": int, "rows": int, "bytes": int},
-                          {"chunk": int, "h2d_s": _NUM, "commit_s": _NUM}),
-    # host-timed probe of the histogram psum over the data mesh (the in-step
-    # psum is fused inside the jitted tree grower where per-op wall time is
-    # invisible; the probe runs the same collective/shape at trainer setup)
-    "hist_allreduce": ({"shards": int, "bytes": int, "psum_s": _NUM}, {}),
+                      "depth": int, "shard": int, "bytes": int,
+                      "thread": str}),
     # background AOT compile lifecycle (prewarm.py): started -> compiled ->
     # adopted, or skipped/miss/error with a reason; duration_s is the
     # compile time (compiled/error), or the join-barrier wait (adopted)

@@ -7,7 +7,8 @@ enabled — a log2 latency histogram ``span_seconds{span=<name>}`` in the
 metrics registry, and (3) the in-memory span record: each thread keeps the
 stack of its open spans, a span closed inside a ``train_iter`` adds its self
 time (its own seconds less its children's) to that iteration's ``spans``,
-and one closed outside any iteration emits a ``span`` event.
+and one closed outside any iteration emits a ``span`` event that says when
+it started, on which thread and under which span.
 :func:`current_span` is what a listener on the same thread (the
 ``program_load`` counter in ``obs/__init__``) asks for the innermost name.
 
@@ -75,13 +76,16 @@ def current_iteration() -> Optional[IterationSpans]:
 
 
 @contextlib.contextmanager
-def span(name: str, block_on=None, step_num=None):
+def span(name: str, block_on=None, step_num=None, parent=None):
     """Timed scope: TIMER accumulation + TraceAnnotation + the thread's span
     stack, and with telemetry on the latency histogram and the span record
     (module docstring). ``step_num`` makes the scope one iteration of a loop:
     a ``StepTraceAnnotation`` that yields the :class:`IterationSpans` its
-    children fill. The disabled path adds a clock read and two list
-    operations over a bare ``TIMER.scope``."""
+    children fill. ``parent`` names the span that caused this one where the
+    thread has none open: a worker's outermost span is handed what
+    :func:`current_span` said on the thread that started it. The disabled
+    path adds a clock read and two list operations over a bare
+    ``TIMER.scope``."""
     from . import METRICS, emit, enabled
     stack = _stack()
     frame = [name, 0.0]                 # name, seconds of closed children
@@ -89,6 +93,7 @@ def span(name: str, block_on=None, step_num=None):
     outer = current_iteration()
     if step_num is not None:
         _TL.iteration = IterationSpans(step_num)
+    start_ts = time.time() if enabled() else None
     t0 = time.perf_counter()
     try:
         with TIMER.scope(name, block_on=block_on, step_num=step_num):
@@ -98,6 +103,7 @@ def span(name: str, block_on=None, step_num=None):
         del stack[stack.index(frame):]  # and whatever leaked above it
         if stack:
             stack[-1][1] += dt
+            parent = stack[-1][0]
         if step_num is not None:
             _TL.iteration = outer
         if enabled():
@@ -106,7 +112,12 @@ def span(name: str, block_on=None, step_num=None):
             if step_num is None and outer is not None:
                 outer.spans[name] = outer.spans.get(name, 0.0) + dt - frame[1]
             elif step_num is None:      # an iteration's event is the loop's
-                emit("span", name=name, duration_s=dt)
+                fields = {"thread": threading.current_thread().name}
+                if start_ts is not None:
+                    fields["start_ts"] = start_ts
+                if parent is not None:
+                    fields["parent"] = parent
+                emit("span", name=name, duration_s=dt, **fields)
 
 
 def record_span(name: str, seconds: float) -> None:

@@ -184,18 +184,40 @@ def aot_compile_step(gbdt, fn=None, tag: str = "cold",
     default; ``custom=True`` builds the explicit-gradient step GOSS/RF
     dispatch). Returns (jit wrapper, Compiled executable, seconds). ``tag``
     labels the compile event cold/warm so the bench can split the two
-    without guessing."""
+    without guessing. The spans ``prewarm_build`` / ``prewarm_lower`` /
+    ``prewarm_compile`` name the three stages on whichever thread runs them
+    (``program_load.span`` of the step reads ``prewarm_compile``)."""
     if fn is None:
-        fn = gbdt._build_fused_step(custom=custom)
+        with obs.span("prewarm_build"):
+            fn = gbdt._build_fused_step(custom=custom)
     t0 = time.perf_counter()
-    compiled = fn.lower(*step_avals(gbdt, custom=custom)).compile()
+    with obs.span("prewarm_lower"):
+        lowered = fn.lower(*step_avals(gbdt, custom=custom))
+    with obs.span("prewarm_compile"):
+        compiled = lowered.compile()
     dt = time.perf_counter() - t0
     if obs.enabled():
         # cache_size 0: AOT compilation does not enter the wrapper's
         # dispatch cache (the whole reason adoption hands over `compiled`)
         obs.emit("compile", what="fused_step_aot", cache_size=0,
                  duration_s=float(dt), key=tag)
+        _emit_step_memory(compiled)
     return fn, compiled, dt
+
+
+def _emit_step_memory(compiled) -> None:
+    """The ``step_memory`` event of an executable already in hand (one
+    device's bytes); nothing where the backend gives no analysis."""
+    mem = compiled.memory_analysis()
+    if mem is None:
+        return
+    obs.emit("step_memory", what="fused_step_aot",
+             argument_bytes=int(mem.argument_size_in_bytes),
+             output_bytes=int(mem.output_size_in_bytes),
+             temp_bytes=int(mem.temp_size_in_bytes),
+             alias_bytes=int(mem.alias_size_in_bytes),
+             generated_code_bytes=int(mem.generated_code_size_in_bytes),
+             devices=len(compiled.runtime_executable().local_devices()))
 
 
 # below this the encode/upload window is far shorter than the compile it
@@ -235,39 +257,46 @@ def maybe_start(conf, dataset) -> Optional[PrewarmHandle]:
         log.debug("AOT prewarm skipped: %s", reason)
         return None
     handle = PrewarmHandle()
+    # the span that causes the worker's: open on THIS thread (the worker's
+    # own stack starts empty), dataset_construct when construct() calls
+    started_under = obs.tracing.current_span()
 
     def _worker():
-        t0 = time.perf_counter()
-        try:
-            # chaos point: a failed background compile must degrade to
-            # compile-at-dispatch (adoption miss), never break training
-            faults.fault_point("prewarm_compile")
-            # lazy import: basic imports this module lazily from construct,
-            # so there is no cycle at import time
-            from .basic import booster_class
-            from .objectives import create_objective
-            cls = booster_class(conf.boosting)
-            # GOSS (grad-dependent bagging) and RF (constant explicit
-            # gradients) dispatch the custom-gradient step; gbdt/dart the
-            # auto one. The flag travels with the handle so adopt() can
-            # refuse to hand a custom executable to an auto dispatch.
-            custom = bool(getattr(cls, "_needs_grad_for_bag", False)
-                          or getattr(cls, "average_output", False))
-            objective = create_objective(conf.objective, conf)
-            g = cls(conf, dataset, objective, metrics=[], quiet=True)
-            handle.spec = step_spec(g)
-            fn, compiled, _ = aot_compile_step(g, tag="cold", custom=custom)
-            handle.result.update(fn=fn, compiled=compiled, custom=custom,
-                                 duration_s=time.perf_counter() - t0)
-            if tele:
-                obs.emit("aot_prewarm", phase="compiled",
-                         duration_s=float(handle.result["duration_s"]))
-        except BaseException as e:   # surfaced as a miss at adoption time
-            handle.result["error"] = e
-            if tele:
-                obs.emit("aot_prewarm", phase="error",
-                         reason=str(e)[:200],
-                         duration_s=time.perf_counter() - t0)
+        with obs.span("prewarm_worker", parent=started_under):
+            t0 = time.perf_counter()
+            try:
+                # chaos point: a failed background compile must degrade to
+                # compile-at-dispatch (adoption miss), never break training
+                faults.fault_point("prewarm_compile")
+                with obs.span("prewarm_trainer"):
+                    # lazy import: basic imports this module lazily from
+                    # construct, so there is no cycle at import time
+                    from .basic import booster_class
+                    from .objectives import create_objective
+                    cls = booster_class(conf.boosting)
+                    # GOSS (grad-dependent bagging) and RF (constant
+                    # explicit gradients) dispatch the custom-gradient step;
+                    # gbdt/dart the auto one. The flag travels with the
+                    # handle so adopt() can refuse to hand a custom
+                    # executable to an auto dispatch.
+                    custom = bool(getattr(cls, "_needs_grad_for_bag", False)
+                                  or getattr(cls, "average_output", False))
+                    objective = create_objective(conf.objective, conf)
+                    g = cls(conf, dataset, objective, metrics=[], quiet=True)
+                    handle.spec = step_spec(g)
+                fn, compiled, _ = aot_compile_step(g, tag="cold",
+                                                   custom=custom)
+                handle.result.update(fn=fn, compiled=compiled, custom=custom,
+                                     duration_s=time.perf_counter() - t0)
+                if tele:
+                    obs.emit("aot_prewarm", phase="compiled",
+                             duration_s=float(handle.result["duration_s"]))
+            except BaseException as e:   # surfaced as a miss at adoption
+                handle.result["error"] = e
+                if tele:
+                    obs.emit("aot_prewarm", phase="error",
+                             reason=str(e)[:200],
+                             duration_s=time.perf_counter() - t0)
 
     th = threading.Thread(target=_worker, daemon=True, name="aot-prewarm")
     handle._thread = th

@@ -185,6 +185,76 @@ def test_ingest_and_prewarm_events_emitted():
     assert depth is not None
 
 
+def test_ingest_chunk_says_bytes_and_thread_and_no_shard_unsharded():
+    obs.configure(enabled=True)
+    ds = _dataset(ingest_chunk_rows=512, prewarm=0).construct()
+    chunks = [e for e in obs.EVENTS.snapshot() if e["type"] == "ingest_chunk"]
+    assert len(chunks) == -(-N // 512)
+    width = int(ds.bins.shape[1])
+    for e in chunks:
+        assert e["bytes"] == e["rows"] * width
+        assert e["thread"] == "ingest-commit" and "shard" not in e
+    assert not any(e["type"] == "mesh_shard_commit"
+                   for e in obs.EVENTS.snapshot())
+
+
+WORKER_SPANS = ("prewarm_trainer", "prewarm_build", "prewarm_lower",
+                "prewarm_compile")
+
+
+def test_prewarm_workers_life_is_spans():
+    """The worker's four stages nest under prewarm_worker on its own thread,
+    in order, cover it, and the step's program_load falls into
+    prewarm_compile; prewarm_worker's parent is the span that was open where
+    the thread was started."""
+    _train(prewarm=1, rounds=2, telemetry=1)
+    ev = obs.EVENTS.snapshot()
+    spans = {e["name"]: e for e in ev if e["type"] == "span"
+             and e["name"].startswith("prewarm_")}
+    assert set(spans) == set(WORKER_SPANS) | {"prewarm_worker"}
+    worker = spans["prewarm_worker"]
+    assert worker["parent"] == "dataset_construct"
+    assert all(e["thread"] == "aot-prewarm" for e in spans.values())
+    stages = [spans[n] for n in WORKER_SPANS]
+    assert all(e["parent"] == "prewarm_worker" for e in stages)
+    starts = [e["start_ts"] for e in stages]
+    assert starts == sorted(starts) and starts[0] >= worker["start_ts"]
+    assert all(e["ts"] <= worker["ts"] for e in stages)
+    assert sum(e["duration_s"] for e in stages) <= worker["duration_s"]
+    step = [e for e in ev if e["type"] == "program_load"
+            and e["thread"] == "aot-prewarm" and e["program"] == "step"]
+    assert len(step) == 1 and step[0]["span"] == "prewarm_compile"
+    assert step[0]["trace_s"] > 0 and step[0]["lower_s"] > 0
+    assert not any(e["type"] == "program_load" and e["span"] == "none"
+                   and e["thread"] == "aot-prewarm" for e in ev)
+    # the accepted events are as they were
+    cold = [e for e in ev if e["type"] == "compile"
+            and e.get("what") == "fused_step_aot"]
+    assert len(cold) == 1 and cold[0]["duration_s"] >= (
+        spans["prewarm_lower"]["duration_s"]
+        + spans["prewarm_compile"]["duration_s"]) * 0.99
+
+
+@pytest.mark.parametrize("telemetry", [1, 0])
+def test_step_memory_once_per_aot_compile_never_when_off(telemetry):
+    bst = _train(prewarm=1, rounds=2, telemetry=telemetry)
+    mem = [e for e in obs.EVENTS.snapshot() if e["type"] == "step_memory"]
+    if not telemetry:
+        assert len(obs.EVENTS) == 0
+        return
+    assert len(mem) == 1
+    m = mem[0]
+    assert m["what"] == "fused_step_aot" and m["devices"] == 1
+    assert m["argument_bytes"] >= N * F and m["temp_bytes"] > 0
+    assert m["output_bytes"] > 0 and m["alias_bytes"] >= 0
+    # a second AOT compile of the same step (bench.py's warm one) is a
+    # second event; the step dispatched by jit emits none
+    prewarm.aot_compile_step(bst._gbdt, tag="warm")
+    _train(prewarm=0, rounds=1, telemetry=1)
+    assert sum(e["type"] == "step_memory"
+               for e in obs.EVENTS.snapshot()) == 2
+
+
 def test_pipeline_error_propagates():
     bad = X.copy()
     ds = lgb.Dataset(bad, label=Y.copy(),

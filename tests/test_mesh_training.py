@@ -129,8 +129,9 @@ def test_builtin_objective_close_across_shards(data):
 
 
 def test_mesh_shard_commit_telemetry(data):
-    """Sharded ingest emits one mesh_shard_commit per committed chunk, and
-    every shard id in [0, 8) appears."""
+    """Sharded ingest emits one ingest_chunk per committed chunk that names
+    the shard whose accumulator took it, and every shard id in [0, 8)
+    appears."""
     from lightgbm_tpu import obs
     X, y = data
     obs.configure(enabled=True)
@@ -139,11 +140,12 @@ def test_mesh_shard_commit_telemetry(data):
         ds = lgb.Dataset(X, label=y, params={"num_shards": 8, "verbose": -1})
         ds.construct()
         ev = [e for e in obs.EVENTS.snapshot()
-              if e["type"] == "mesh_shard_commit"]
-        assert ev, "no mesh_shard_commit events from sharded construct"
+              if e["type"] == "ingest_chunk"]
+        assert ev, "no ingest_chunk events from sharded construct"
         shards = {e["shard"] for e in ev}
         assert shards == set(range(8))
         assert all(e["rows"] > 0 and e["bytes"] > 0 for e in ev)
+        assert all(e["thread"] == "ingest-commit" for e in ev)
         assert sum(e["rows"] for e in ev) == N
     finally:
         obs.configure(enabled=False)

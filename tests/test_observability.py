@@ -433,8 +433,8 @@ def test_program_load_names_the_span_it_fell_into():
     assert rec.programs_loaded == len(inside)
     assert loads[-1]["span"] == "none" and "iteration" not in loads[-1]
     by_span = sum(
-        obs.METRICS.counter("programs_loaded", "", span=s).value
-        for s in {e["span"] for e in loads})
+        obs.METRICS.counter("programs_loaded", "", span=s, cache=c).value
+        for s, c in {(e["span"], e["cache"]) for e in loads})
     assert by_span == len(plain)
 
 
