@@ -619,9 +619,8 @@ def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
         from .pallas_hist import (hist_pallas, hist_pallas_q8,
                                   hist_routed_fused_q8)
         interp = jax.default_backend() == "cpu"
-        bt = bins_T if bins_T is not None else bins.T
-        path = hist_path(bins.shape[1], num_bins, impl, quant is not None)
-        if path["route"] == "fused":
+        bt, fused = _pallas_route(bins, bins_T, num_bins, quant)
+        if fused:
             # single-feature-group data: route + histogram in ONE kernel
             # (one bins read per level instead of two, no [N] slot
             # round-trip; measured 8.3 ms/level for the separate route pass
@@ -650,6 +649,40 @@ def hist_routed(bins, g, h, c, leaf_id, tables, na_bin, num_slots, num_bins,
             return hist[:, :, :bins.shape[1]], lid2
     return hist_routed_onehot(bins, g, h, c, leaf_id, tables, na_bin,
                               num_slots, num_bins)
+
+
+def _pallas_route(bins, bins_T, num_bins: int, quant):
+    """How a Pallas level pass routes its rows, for ``hist_routed`` and
+    ``route_only`` alike: over which matrix (the resident ``bins_T``, else
+    ``bins.T``), and whether inside its one histogram kernel (``hist_path``
+    route "fused") or in ``route_rows`` ahead of the grouped kernel.
+    Returns (bt, fused)."""
+    bt = bins_T if bins_T is not None else bins.T
+    path = hist_path(bins.shape[1], num_bins, "pallas", quant is not None)
+    return bt, path["route"] == "fused"
+
+
+def route_only(bins, leaf_id, tables: RouteTables, na_bin, num_slots: int,
+               num_bins: int, impl="auto", bins_T=None, quant=None):
+    """``hist_routed``'s new leaf ids without its histograms, for a level
+    whose children's histograms nothing reads (device scope ``route``),
+    routed as that pass routes (``_pallas_route``): where it routes inside
+    its one kernel, the ``route_level`` kernel over the same matrix, every
+    column as that kernel reads them, through the same ``_route_chunk``;
+    elsewhere ``route_rows``."""
+    impl = pick_impl(impl)
+    if impl != "pallas":
+        return route_rows(bins, None, leaf_id, tables, na_bin, num_slots,
+                          impl)[1]
+    bt, fused = _pallas_route(bins, bins_T, num_bins, quant)
+    if not fused:
+        return route_rows(bins, bt, leaf_id, tables, na_bin, num_slots,
+                          impl)[1]
+    from .pallas_hist import route_level_pallas
+    with jax.named_scope("route"):
+        return route_level_pallas(
+            bt, leaf_id, tables, na_bin, num_slots,
+            interpret=jax.default_backend() == "cpu")[1]
 
 
 def route_rows(bins, bins_T, leaf_id, tables: RouteTables, na_bin,

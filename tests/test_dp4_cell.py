@@ -54,16 +54,18 @@ def test_rehearsal_over_four_shards_is_correct(telemetry):
 
 
 @pytest.mark.parametrize("leaves,pallas,slots", [
-    (255, True, 6 * 32 + 2 * 127),     # the cell: 9.62 MB a tree
+    (255, True, 6 * 32 + 2 * 127),     # the cell: 6.88 MB a tree
     (255, False, 1 + 2 + 4 + 8 + 16 + 32 + 64 + 127),
     (15, False, 1 + 2 + 4 + 7),
 ])
 def test_allreduce_bytes_per_tree(leaves, pallas, slots):
     """The root's histogram, one of the level's slot width for each level of
-    a balanced tree, the leaf sums: float32 of [3, 28, 64] cells."""
+    a balanced tree (``slots`` over all of them) but its last, the leaf sums:
+    float32 of [3, 28, 64] cells. The last level fills the budget at its
+    full width, ``leaves // 2`` slots, and builds no histograms."""
     assert grow_depthwise.allreduce_bytes_per_tree(
         leaves, -1, 28, 64, pallas) == 4 * (
-            (1 + slots) * 3 * 28 * 64 + 3 * leaves)
+            (1 + slots - leaves // 2) * 3 * 28 * 64 + 3 * leaves)
 
 
 def test_a_shard_left_out_of_the_reduction_is_not_correct(monkeypatch,

@@ -299,8 +299,10 @@ def test_training_lowering_count_unchanged_by_telemetry(tmp_path):
 # every named_scope the step's traced body opens, and the Pallas kernels of
 # the forced-Pallas path on the CPU
 STEP_SCOPES = {"front", "split_search", "apply_level", "route_hist",
-               "leaf_renew", "score_update"}
-STEP_KERNELS = {"grad_quant_hist0", "hist_level_q8", "leaf_sums_grad"}
+               "route_only", "leaf_renew", "score_update"}
+# route_level: the router of a level that ends the tree (scope route_only)
+STEP_KERNELS = {"grad_quant_hist0", "hist_level_q8", "leaf_sums_grad",
+                "route_level"}
 # children of train_iter, by name (docs/OBSERVABILITY.md)
 ITER_SPANS = {"callbacks_before", "boosting", "prewarm_adopt", "step_dispatch",
               "valid_score", "finished_check", "eval", "metric", "callbacks",

@@ -132,14 +132,18 @@ def test_decode_leaf_is_table_lookup(l):
 # ---------------------------------------------------------------------------
 # the level kernel against the XLA router followed by the leaf kernel
 
-def _level_tables(s, categorical, thr255=False):
+def _level_tables(s, categorical, thr255=False, last=False):
     """One level over 2s + 2 leaves: the first s may split (one in five does
     not), one child to slot i and the other to the sentinel s (its histogram
-    is the parent's less its sibling's), the right child a new leaf."""
+    is the parent's less its sibling's), the right child a new leaf.
+    ``last``: the level that fills a budget of 2s + 1 leaves (255 at s =
+    127): s + 1 leaves hold rows, the first s split, their right children
+    the leaves s + 1 to 2s."""
     r = np.random.default_rng(100 + s)
-    l = 2 * s + 2
+    l = 2 * s + 1 if last else 2 * s + 2
     splits = np.arange(l) < s
-    feat = np.where(splits & (r.random(l) < 0.8), r.integers(0, F, size=l), -1)
+    feat = np.where(splits & (last | (r.random(l) < 0.8)),
+                    r.integers(0, F, size=l), -1)
     left_small = r.random(l) < 0.5
     own = np.where(splits, np.arange(l), s)
     i32 = lambda a: jnp.asarray(a, dtype=jnp.int32)
@@ -151,31 +155,39 @@ def _level_tables(s, categorical, thr255=False):
     thr = r.integers(0, B - 1, size=l)
     if thr255:      # a byte past int8: no bin is above it, every row goes left
         thr[::3] = 255
+    new_leaf = (s + 1 if last else s) + np.arange(l)
     return hg.RouteTables(
         i32(feat), i32(thr),
-        i32(r.integers(0, 2, size=l)), i32(np.minimum(s + np.arange(l), l - 1)),
+        i32(r.integers(0, 2, size=l)), i32(np.minimum(new_leaf, l - 1)),
         i32(np.where(left_small, own, s)), i32(np.where(left_small, s, own)),
         **cat)
 
 
-@pytest.mark.parametrize("tabs", ["all_leaves", "live_leaves", "thr255"])
+@pytest.mark.parametrize("tabs", ["all_leaves", "live_leaves", "thr255",
+                                  "last_level"])
 @pytest.mark.parametrize("categorical", [False, True])
 @pytest.mark.parametrize("s", [32, 127])
 @pytest.mark.parametrize("const_hess", [False, True])
 def test_hist_level_q8_equals_route_then_leaf(rows, const_hess, s,
                                               categorical, tabs):
     """``hist_routed_fused_q8`` is ``route_level`` (XLA gathers) followed by
-    ``hist_pallas_q8`` on its slots: histograms and new leaf ids, exactly.
+    ``hist_pallas_q8`` on its slots: histograms and new leaf ids, exactly;
+    and its leaf ids are the ``route_level`` kernel's over the same matrix
+    (what a level that builds no histograms runs: histogram.route_only).
     Half of the features have a missing-value bin, and rows in it meet both
     default directions. ``live_leaves``: the kernel gets the tables cut to
     the s leaves that hold rows (what the grower hands a shallow level),
-    the reference the whole tables; ``thr255``: thresholds of 255."""
+    the reference the whole tables; ``thr255``: thresholds of 255;
+    ``last_level``: the level that fills a budget of 2s + 1 leaves (a
+    255-leaf tree's at s = 127), rows on its s + 1 live leaves."""
     q = _quant(rows, const_hess)
     hq, ch = hg._q8_h_arg(q)
-    tables = _level_tables(s, categorical, thr255=tabs == "thr255")
+    last = tabs == "last_level"
+    tables = _level_tables(s, categorical, thr255=tabs == "thr255",
+                           last=last)
     l = s if tabs == "live_leaves" else tables.feat.shape[0]
     r = np.random.default_rng(s)
-    lid = r.integers(0, l, size=N)
+    lid = r.integers(0, s + 1 if last else l, size=N)
     na = np.where(np.arange(F) % 2 == 0, B - 1, -1)
     # a row in its split feature's missing bin, under either default
     feat, bins = np.asarray(tables.feat), np.asarray(rows["bins"])
@@ -200,6 +212,10 @@ def test_hist_level_q8_equals_route_then_leaf(rows, const_hess, s,
         const_hess=ch, interpret=True)
     np.testing.assert_array_equal(np.asarray(lid2), np.asarray(lid_ref))
     np.testing.assert_array_equal(np.asarray(hist), np.asarray(ref))
+    _, lid_routed = ph.route_level_pallas(
+        rows["bins_T"], lid, jax.tree.map(lambda a: a[:l], tables), na_bin,
+        s, interpret=True)
+    np.testing.assert_array_equal(np.asarray(lid_routed), np.asarray(lid2))
 
 
 # ---------------------------------------------------------------------------
