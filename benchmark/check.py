@@ -29,6 +29,12 @@ The numbers (see PERF.md for the readings each limit was set from):
                    larger of that node's and the median node's best gain
   split_regret_last  the same of the last tree followed, under a limit that
                    leaves room for its growth with the tree's index
+  hessian_shortfall  the most that a child of a split taken lies under
+                   ``min_sum_hessian_in_leaf`` by the reference's exact
+                   hessians, as a share of that minimum, over the followed
+                   trees. The program holds the minimum to its quantised
+                   sums (``precision``), so a hair is sound, and such a split
+                   keeps its gain in the regrets (``reference._taken``)
   auc_gap          worst iteration: |AUC the run reported - float64 rank AUC
                    of the reference's prediction with the run's trees|
 """
@@ -38,14 +44,15 @@ from benchmark import data, reference
 
 
 def tree_numbers(walk, tree):
-    """The per-tree numbers from a followed walk and the tree it followed."""
+    """The per-tree numbers from a followed walk and the tree it followed
+    (``hessian_shortfall`` where the walk reports one)."""
     bias = walk["bias"]
     ref = walk["leaf_value"] - bias
     got = np.asarray(tree["leaf_value"], np.float64) - bias
     scale = np.maximum(np.abs(ref), np.median(np.abs(ref)))
     best = walk["best"]
     regret = (best - walk["chosen"]) / np.maximum(best, np.median(best))
-    return {
+    out = {
         "off_grid": walk["off_grid"],
         "leaf_count_gap": float(np.abs(
             np.asarray(tree["leaf_count"], np.float64)
@@ -53,6 +60,9 @@ def tree_numbers(walk, tree):
         "leaf_value_gap": float(np.max(np.abs(got - ref) / scale)),
         "split_regret": float(regret.mean()) if len(regret) else 0.0,
     }
+    if "hessian_shortfall" in walk:
+        out["hessian_shortfall"] = walk["hessian_shortfall"]
+    return out
 
 
 def bin_numbers(walk, bounds, cfg, n_rows):
@@ -80,21 +90,25 @@ def combine(per_tree):
            "split_regret": per_tree[0]["split_regret"]}
     if len(per_tree) > 1:
         out["split_regret_last"] = per_tree[-1]["split_regret"]
+    if all("hessian_shortfall" in n for n in per_tree):
+        out["hessian_shortfall"] = worst("hessian_shortfall")
     return out
 
 
-def follow_trees(seed, cfg, n_train, bounds, trees, which, block_rows):
+def follow_trees(seed, cfg, n_train, bounds, trees, which, block_rows,
+                 ref=reference):
     """Follows ``trees[i]`` for i in ``which`` and applies the others up to
-    the last of them."""
-    rows = reference.Rows(seed, cfg, n_train, bounds, block_rows)
+    the last of them, by the reference module ``ref`` (``reference_wide``
+    for a matrix of thousands of columns)."""
+    rows = ref.Rows(seed, cfg, n_train, bounds, block_rows)
     numbers, bins = [], None
     for i in range(max(which) + 1):
         if i in which:
-            walk = reference.walk_tree(rows, cfg, bounds, tree=trees[i])
+            walk = ref.walk_tree(rows, cfg, bounds, tree=trees[i])
             numbers.append(tree_numbers(walk, trees[i]))
             bins = bins or bin_numbers(walk, bounds, cfg, n_train)
         else:
-            reference.apply_tree(rows, trees[i], bounds)
+            ref.apply_tree(rows, trees[i], bounds)
     return dict(combine(numbers), **bins)
 
 
@@ -123,7 +137,8 @@ def judge(numbers, limits):
     return checks
 
 
-def check_cell(cell, seed, produced, n_train, n_valid, block_rows):
+def check_cell(cell, seed, produced, n_train, n_valid, block_rows,
+               ref=reference):
     cfg, traffic = cell["cfg"], cell["traffic"]
     trees = reference.parse_model(produced["model_text"])
     done = int(produced["iterations"])
@@ -140,7 +155,7 @@ def check_cell(cell, seed, produced, n_train, n_valid, block_rows):
         numbers["bin_count_gap"] = float("nan")  # more bins than can be read
     else:
         numbers.update(follow_trees(seed, cfg, n_train, produced["bounds"],
-                                    trees, which, block_rows))
+                                    trees, which, block_rows, ref))
     if n_valid:
         aucs = produced["auc"][:done]
         if len(aucs) != done or len(trees) < len(aucs):

@@ -5,7 +5,7 @@ of its own and is judged by the tiled reference (``reference_wide.py``):
 int8), the same faults (``coarse_bins``, ``thin_sample``, ``half``), the same
 sound readings (``int8``, ``exact``), held to the cell's limits by the run's
 own comparison; no validation set, so no ``stale_valid``. One fault more,
-for the number ``check_wide`` adds: ``lax_minimum``, a tree grown under half
+for the number ``hessian_shortfall``: ``lax_minimum``, a tree grown under half
 the configuration's ``min_sum_hessian_in_leaf`` and followed under the whole.
 
     python3 -m benchmark.control_wide --workload <name> --seeds 1,2,3
@@ -28,7 +28,7 @@ SOUND = control.SOUND
 def read_seed(cell, seed, n_train, block_rows, trees=2,
               modes=HAS_TO_FAIL + SOUND):
     """{mode: numbers} for one seed."""
-    from benchmark import check, check_wide, reference_wide as R
+    from benchmark import check, reference_wide as R
     cfg = cell["cfg"]
     gen = importlib.import_module("benchmark." + cfg["generator"])
     max_bin = int(cfg["params"]["max_bin"])
@@ -50,8 +50,8 @@ def read_seed(cell, seed, n_train, block_rows, trees=2,
         if mode in modes:
             walks, ts = grow(bounds, trees, quant_bits=bits, quant_seed=seed)
             out[mode] = dict(
-                check_wide.combine([check_wide.tree_numbers(walks[i], ts[i])
-                                    for i in which]),
+                check.combine([check.tree_numbers(walks[i], ts[i])
+                               for i in which]),
                 **check.bin_numbers(walks[0], bounds, cfg, n_train))
     for mode, made in (
             ("coarse_bins", lambda: R.quantile_bounds(sample, max_bin // 4)),
@@ -66,8 +66,8 @@ def read_seed(cell, seed, n_train, block_rows, trees=2,
     for mode, how in (("half", {"half": True}), ("lax_minimum", {"cfg": lax})):
         if mode in modes:
             _, ts = grow(bounds, **how)
-            out[mode] = check_wide.follow_trees(seed, cfg, n_train, bounds,
-                                                ts, [0], block_rows)
+            out[mode] = check.follow_trees(seed, cfg, n_train, bounds, ts,
+                                           [0], block_rows, ref=R)
     return out
 
 

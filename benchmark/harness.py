@@ -6,6 +6,14 @@ per-layer metric is a file of its own, found by the name in
 ``metrics/<metric>.py`` (a reader: ``read(ctx)`` returns the value or None),
 ``limits/<workload>.json`` (the limits ``correct`` is held to). Adding a
 cell, a configuration or a metric adds files and entries and edits none.
+
+A configuration's generator module (``generator`` in its file) gives
+``seed_key``, ``to_host``, ``BLOCK_ROWS`` and ``VALID_STREAM``, and may give
+``dataset_fields(key, cfg, n_rows, first_block=0, rows=BLOCK_ROWS)``: a dict
+of ``lgb.Dataset``'s own keyword arguments among ``DATASET_FIELDS`` (query
+groups, weights, init scores, categorical columns) for the same rows as
+``to_host``; without it ``lgb.Dataset`` gets the matrix, labels and params
+alone. The check gets every validation metric the run reported.
 """
 import gc
 import importlib
@@ -22,6 +30,7 @@ HERE = os.path.join(ROOT, "benchmark")
 STATE = os.path.join(ROOT, ".bench_state")   # native build, traces; ignored
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 PREFAULT_THREADS = 6
+DATASET_FIELDS = ("group", "weight", "init_score", "categorical_feature")
 
 
 def _json(path):
@@ -82,6 +91,26 @@ def prefault(shape):
             j.result()
         pool.shutdown()
     return buf, wait
+
+
+def dataset_fields(gen, key, cfg, n_rows, first_block=0, rows=None):
+    """The generator's ``lgb.Dataset`` keyword arguments for the rows
+    ``to_host`` makes with the same arguments; {} where it defines none.
+    A key outside ``DATASET_FIELDS``, or query groups that do not cover the
+    rows, is an error before anything is constructed."""
+    make = getattr(gen, "dataset_fields", None)
+    if make is None:
+        return {}
+    fields = dict(make(key, cfg, n_rows, first_block,
+                       rows=rows or gen.BLOCK_ROWS))
+    unknown = sorted(set(fields) - set(DATASET_FIELDS))
+    if unknown:
+        raise ValueError(f"{gen.__name__}.dataset_fields gave {unknown}; "
+                         f"lgb.Dataset takes {list(DATASET_FIELDS)}")
+    if "group" in fields and int(sum(fields["group"])) != n_rows:
+        raise ValueError(f"{gen.__name__}.dataset_fields: query groups sum "
+                         f"to {int(sum(fields['group']))} rows, not {n_rows}")
+    return fields
 
 
 def train_params(cfg, traffic, trace, extra=None):
@@ -190,20 +219,26 @@ def run_cell(cell, seed, seconds, trace, t0, rehearse=None, spans=None,
         spans["harness.prefault_wait_s"] = time.perf_counter() - t
     X, y = data.to_host(key, cfg, n_train, rows=block_rows, out=out)
     del out, train_buffer
+    fields = dataset_fields(data, key, cfg, n_train, rows=block_rows)
     Xv = yv = None
+    vfields = {}
     if n_valid:
         Xv, yv = data.to_host(key, cfg, n_valid, data.VALID_STREAM,
                               rows=block_rows)
+        # the validation set takes its categorical columns from its reference
+        vfields = {k: v for k, v in dataset_fields(
+            data, key, cfg, n_valid, data.VALID_STREAM,
+            rows=block_rows).items() if k != "categorical_feature"}
     spans["harness.gen_s"] = time.perf_counter() - t
 
     if trace:
         obs.configure(enabled=True)
     params = train_params(cfg, traffic, trace, rehearse.get("params"))
     t = time.perf_counter()
-    ds = lgb.Dataset(X, label=y, params=params).construct()
+    ds = lgb.Dataset(X, label=y, params=params, **fields).construct()
     spans["ingest.construct_s"] = time.perf_counter() - t
-    valid = ds.create_valid(Xv, label=yv) if n_valid else None
-    del X, y, Xv, yv                      # the raw matrices are not kept
+    valid = ds.create_valid(Xv, label=yv, **vfields) if n_valid else None
+    del X, y, Xv, yv, fields, vfields     # the raw data is not kept
     gc.collect()
 
     trace_dir = None
@@ -235,6 +270,8 @@ def run_cell(cell, seed, seconds, trace, t0, rehearse=None, spans=None,
         "feature_map": (None if ds.feature_map is None
                         else [int(i) for i in ds.feature_map]),
         "auc": list(evals.get("valid_0", {}).get("auc", [])),
+        "valid_metrics": {k: [float(v) for v in vs] for k, vs in
+                          evals.get("valid_0", {}).items()},
         "iterations": len(win.iter_end),
     }
     ctx = types.SimpleNamespace(

@@ -1,6 +1,7 @@
 """Fused route+histogram level passes per iteration: events of the kernel
-``hist_level_q8`` inside the step. A balanced 255-leaf tree takes eight;
-later, less balanced trees take more."""
+``hist_level_q8`` inside the step. A balanced 255-leaf tree takes seven
+(its last level routes its rows in ``route_level`` alone and builds no
+histogram); later, less balanced trees take more."""
 from benchmark import scopes
 
 

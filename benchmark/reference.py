@@ -299,6 +299,30 @@ def _best(gain):
     return flat[np.arange(s), idx], idx // gain.shape[2], idx % gain.shape[2]
 
 
+def _taken(exact, feat, tbin, num_bins, min_data, min_hess):
+    """(gain, shortfall) per slot of the splits taken at (feat, tbin), from
+    the exact sums. The program holds ``min_sum_hessian_in_leaf`` to the sums
+    of its quantised hessians, which lie a rounding's noise beside the exact
+    ones, so a child it let through at the minimum can read a hair under it
+    here (at 1.2 M rows x 255 leaves the minimum of 100 binds at leaves of
+    ~500 rows; at 52.5 M rows from about the twelfth tree on). Such a split
+    keeps its gain and its shortfall, (minimum - the smaller child's
+    hessian) / minimum, is a number of its own under a limit of its own; the
+    gain is nan, as in ``_split_table``, only where the rows' count or the
+    bin forbids the split, which no rounding moves."""
+    sl = np.arange(len(feat))
+    gl, hl, cl = (exact[k][sl, feat, tbin] for k in ("gl", "hl", "cl"))
+    gt, ht, ct = (exact[k][sl] for k in ("gt", "ht", "ct"))
+    gr, hr, cr = gt - gl, ht - hl, ct - cl
+    ok = ((cl >= min_data) & (cr >= min_data)
+          & (tbin < np.asarray(num_bins)[feat] - 1))
+    with np.errstate(divide="ignore", invalid="ignore"):
+        gain = gl * gl / hl + gr * gr / hr - gt * gt / ht
+    short = np.maximum(0.0, min_hess - np.minimum(hl, hr)) / max(min_hess,
+                                                                 1e-300)
+    return np.where(ok, gain, np.nan), short
+
+
 def _pow2(n):
     return 1 << max(0, int(n - 1).bit_length())
 
@@ -313,8 +337,10 @@ def walk_tree(rows, cfg, bounds, tree=None, quant_bits=None, quant_seed=0,
     best splits; with ``quant_bits`` the splits are chosen from histograms of
     gradients quantised to that many bits (the control). ``half`` leaves
     every other row out of the step (a planted fault).
-    Returns per-node gains (best, chosen, both from exact histograms) and
-    per-leaf reference sums, and the tree walked."""
+    Returns per-node gains (best, chosen, both from exact histograms; the
+    chosen split's gain from the unmasked sums, ``_taken``), per-leaf
+    reference sums, the tree walked, and ``hessian_shortfall``: the largest
+    shortfall over the splits taken."""
     f = int(cfg["num_features"])
     par = cfg["params"]
     lr = float(par["learning_rate"])
@@ -340,7 +366,7 @@ def walk_tree(rows, cfg, bounds, tree=None, quant_bits=None, quant_seed=0,
     slot, leaf = rows.start(half)
 
     follow = tree is not None
-    out = {"best": [], "chosen": [], "level": [], "off_grid": 0,
+    out = {"best": [], "chosen": [], "level": [], "short": [], "off_grid": 0,
            "leaf_g": {}, "leaf_h": {}, "leaf_c": {}}
     # the slots of the level at hand: the tree's node ids when following,
     # (parent node, side) links when growing (None for the root)
@@ -390,8 +416,7 @@ def walk_tree(rows, cfg, bounds, tree=None, quant_bits=None, quant_seed=0,
             do_split = np.zeros(n_slots, bool)
             do_split[order[:budget]] = True
             do_split &= own_gain > 0
-        sl = np.arange(n_slots)
-        chosen_gain = exact["gain"][sl, feat, tbin]
+        chosen_gain, short = _taken(exact, feat, tbin, num_bins, *leaf_min)
         final = (not follow) and n_leaves + int(do_split.sum()) >= num_leaves
         rows_tab = np.zeros((s_pad, f + 5), np.float32)
         nxt = []                 # next level's slots
@@ -415,8 +440,11 @@ def walk_tree(rows, cfg, bounds, tree=None, quant_bits=None, quant_seed=0,
                 rows_tab[s, f] = NBINS
                 close(lid, total, s, 0)
                 continue
-            out["best"].append(best_gain[s])
+            # a split taken a hair under the minimum can beat every split
+            # the exact sums allow: no regret, and none below zero
+            out["best"].append(np.fmax(best_gain[s], chosen_gain[s]))
             out["chosen"].append(chosen_gain[s])
+            out["short"].append(short[s])
             out["level"].append(level)
             rows_tab[s, feat[s]] = 1.0
             rows_tab[s, f] = tbin[s]
@@ -475,6 +503,7 @@ def walk_tree(rows, cfg, bounds, tree=None, quant_bits=None, quant_seed=0,
         "leaf_count": c, "leaf_value": ref_value, "bias": bias,
         "num_leaves": n_l, "tree": tree if follow else grown,
         "root_bin_count": out.get("root_bin_count"),
+        "hessian_shortfall": float(max(out["short"], default=0.0)),
     }
 
 

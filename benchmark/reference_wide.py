@@ -11,8 +11,9 @@ pieces it is 115 M numbers a level). The rows come from the generator the
 configuration names (``cfg["generator"]``). What does not depend on the
 width is taken from ``benchmark/reference.py`` as it stands: the model
 text's parser, binning, gradients, the control's quantiser, the bfloat16
-pieces, the routing table's lookup, the leaf-value lookup. Nothing of
-``lightgbm_tpu`` is imported.
+pieces, the routing table's lookup, the leaf-value lookup, the gain and
+hessian shortfall of a split taken (``_taken``). Nothing of ``lightgbm_tpu``
+is imported.
 """
 import importlib
 from concurrent.futures import ThreadPoolExecutor
@@ -23,8 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark import reference as R
-from benchmark.reference import (NBINS, SUB, grown_to_tree,  # noqa: F401
-                                 parse_model, quantile_bounds)
+from benchmark.reference import (NBINS, SUB, _taken,  # noqa: F401
+                                 grown_to_tree, parse_model, quantile_bounds)
 
 TILE = 256           # features per histogram tile
 PAD_BIN = 255        # bin of the columns that pad the last tile: in no bin
@@ -169,30 +170,6 @@ def _split_table(tiles, n_slots, n_chan, const_hess, num_bins, min_data,
            for k in ("gain", "gl", "hl", "cl", "cnt")}
     out.update(gt=totals[0], ht=totals[1], ct=totals[2])
     return out
-
-
-def _taken(exact, feat, tbin, num_bins, min_data, min_hess):
-    """(gain, shortfall) per slot of the splits taken at (feat, tbin), from
-    the exact sums. The program holds ``min_sum_hessian_in_leaf`` to the sums
-    of its quantised hessians, which lie a rounding's noise beside the exact
-    ones, so a child it let through at the minimum can read a hair under it
-    here (at 1.2 M rows x 255 leaves the minimum of 100 binds at leaves of
-    ~500 rows; at HIGGS's 52.5 M it never does). Such a split keeps its gain
-    and its shortfall, (minimum - the smaller child's hessian) / minimum, is
-    a number of its own under a limit of its own; the gain is nan, as in
-    ``_tile_table``, only where the rows' count or the bin forbids the split,
-    which no rounding moves."""
-    sl = np.arange(len(feat))
-    gl, hl, cl = (exact[k][sl, feat, tbin] for k in ("gl", "hl", "cl"))
-    gt, ht, ct = exact["gt"], exact["ht"], exact["ct"]
-    gr, hr, cr = gt - gl, ht - hl, ct - cl
-    ok = ((cl >= min_data) & (cr >= min_data)
-          & (tbin < np.asarray(num_bins)[feat] - 1))
-    with np.errstate(divide="ignore", invalid="ignore"):
-        gain = gl * gl / hl + gr * gr / hr - gt * gt / ht
-    short = np.maximum(0.0, min_hess - np.minimum(hl, hr)) / max(min_hess,
-                                                                 1e-300)
-    return np.where(ok, gain, np.nan), short
 
 
 # -------------------------------------------------------------------- a walk

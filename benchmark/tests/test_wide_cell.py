@@ -196,13 +196,13 @@ def test_a_hair_under_the_hessian_minimum_keeps_its_gain():
     hessian is 99.98 under a minimum of 100 (the program's quantised sum read
     100 or more), the rows' count forbids the third threshold."""
     import numpy as np
-    from benchmark import reference_wide as W
+    from benchmark import reference as R
     exact = {"gl": np.array([[[-30.0, -20.0, 5.0]]]),
              "hl": np.array([[[99.98, 150.0, 399.0]]]),
              "cl": np.array([[[500.0, 800.0, 2000.0]]]),
              "gt": np.array([10.0]), "ht": np.array([400.0]),
              "ct": np.array([2000.0])}
-    at = lambda b: W._taken(exact, np.array([0]), np.array([b]), [4],
+    at = lambda b: R._taken(exact, np.array([0]), np.array([b]), [4],
                             1, 100.0)
     gain, short = at(0)
     assert gain[0] == pytest.approx(900 / 99.98 + 1600 / 300.02 - 0.25)
@@ -218,7 +218,7 @@ def test_a_tree_grown_under_a_laxer_minimum_fails_the_shortfall():
     splits keep their gains (no nan in the regret) and the shortfall says by
     how much; followed under its own minimum it reads 0."""
     import numpy as np
-    from benchmark import check, check_wide, reference_wide as W
+    from benchmark import check, reference_wide as W
     cell = harness.load_cell(CELL)
     cfg = dict(cell["cfg"], num_features=40, signal=dict(
         cell["cfg"]["signal"], first=3, every=8))
@@ -234,7 +234,8 @@ def test_a_tree_grown_under_a_laxer_minimum_fails_the_shortfall():
     tree = W.grown_to_tree(walk, bounds)
     assert walk["hessian_shortfall"] == 0.0 and tree["num_leaves"] == 7
     for c, want in ((lax, True), (strict, False)):
-        numbers = check_wide.follow_trees(7, c, n, bounds, [tree], [0], block)
+        numbers = check.follow_trees(7, c, n, bounds, [tree], [0], block,
+                                     ref=W)
         assert np.isfinite(numbers["split_regret"])
         verdict = check.judge(numbers, cell["limits"])
         assert verdict["hessian_shortfall"]["ok"] is want
